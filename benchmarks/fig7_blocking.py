@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from benchmarks.common import emit, time_fn
 from repro.core import GemmDescriptor, autotune, engine, plan_gemm, use
+from repro.core.config import resolve_interpret
 
 SHAPES = [(640, 640), (320, 320), (896, 384), (2048, 272), (160, 1184),
           (80, 80)]
@@ -69,8 +70,9 @@ def run():
         with use(backend="pallas") as cfg:
             model_plan = engine.plan_for(d)
             tuned_plan, timed = autotune.search(
-                gemm_execute, d, cfg.machine, (a, b), {},
-                interpret=cfg.interpret, budget=AUTOTUNE_BUDGET)
+                gemm_execute, d, cfg.machine_model, (a, b), {},
+                interpret=resolve_interpret(cfg.interpret),
+                budget=AUTOTUNE_BUDGET)
             model_us = time_fn(functools.partial(gemm, plan=model_plan), a, b)
             if tuned_plan is None:  # every candidate failed: model only
                 emit(f"fig7/measured/{m}x{n}", model_us,

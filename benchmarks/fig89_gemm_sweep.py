@@ -37,7 +37,8 @@ from benchmarks.common import emit, time_fn
 from repro.core import GemmDescriptor, engine, plan_gemm, matmul, backend
 from repro.core import refit as refit_lib
 from repro.core.autotune import TuningCache
-from repro.core.config import get_config as get_engine_config
+from repro.core.config import get_config as get_engine_config, \
+    resolve_interpret
 from repro.kernels.gemm import gemm
 from repro.kernels.transpose import transpose
 
@@ -145,13 +146,15 @@ def run(smoke: bool = False):
 
     # Measured winners -> a real tuning-cache file, so the CI smoke run
     # can exercise ``tools/tune.py refit`` on genuine timing data.
-    machine = get_engine_config().machine
+    machine = get_engine_config().machine_model
+    interpret = resolve_interpret(get_engine_config().interpret)
     if os.path.exists(TUNING_JSON):
         os.unlink(TUNING_JSON)  # a cache instance lazy-loads: start clean
     tcache = TuningCache(TUNING_JSON)
     for plan_f, plan_m, us_f, us_m in _pairs(measured):
         win, us = (plan_f, us_f) if us_f <= us_m else (plan_m, us_m)
-        tcache.store(machine.tuning_key, win.desc, win, us, interpret=True)
+        tcache.store(machine.tuning_key, win.desc, win, us,
+                     interpret=interpret)
     emit("fig89_refit/cache", 0,
          f"wrote={TUNING_JSON};entries={len(measured) // 2}")
 

@@ -1,7 +1,7 @@
 """Mesh-aware expert dispatch: gathered vs distributed step time (§14).
 
 The comm-charged planner (DESIGN.md §14) arbitrates two executions of the
-same expert-parallel grouped GEMM on an 8-way model mesh:
+same expert-parallel grouped GEMM on a model mesh:
 
   * gathered     — all-gather the expert weights, every shard runs the
                    full expert set over its token slice (XLA moves the
@@ -16,18 +16,15 @@ the dominant wire cost — records what the planner chose, and writes the
 whole table to ``BENCH_mesh.json`` (step time, per-strategy comm bytes,
 collective and kernel launches per shard, cross-strategy max error).
 
-The measurement needs 8 devices, so ``run()`` re-executes this module in
-a **subprocess** with ``--xla_force_host_platform_device_count=8`` —
-forcing the host platform device count must happen before jax
-initialises, and must never leak into the parent process.
+The suite runs in this process on the devices JAX has — one ``model``
+axis over all of them, whose size must divide the configs' 8 experts and
+token groups — so it never starts a process that would need the chip.
+Each result records the platform, device kind and device count.
 """
 import json
-import os
-import subprocess
 import sys
 
 MESH_JSON = "BENCH_mesh.json"
-DEVICES = 8
 
 # (label, nt, e, cap, k, n): "weights_heavy" keeps the token stream tiny
 # against 8 big k*n expert panels — gathered walks all 8 panels per shard
@@ -43,28 +40,7 @@ SMOKE_CONFIGS = [
 ]
 
 
-def run(smoke: bool = False):
-    """Parent entry: re-exec this module on a host-count-forced mesh."""
-    env = dict(os.environ)
-    flag = f"--xla_force_host_platform_device_count={DEVICES}"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
-    # The child resolves ``repro``/``benchmarks`` the same way the parent
-    # did, wherever it was launched from (check.sh sets PYTHONPATH=src;
-    # a bare ``python -m benchmarks.mesh_overlap`` may not have).
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    extra = os.pathsep.join((os.path.join(root, "src"), root))
-    env["PYTHONPATH"] = (extra + os.pathsep + env["PYTHONPATH"]
-                         if env.get("PYTHONPATH") else extra)
-    cmd = [sys.executable, "-m", "benchmarks.mesh_overlap", "--child"]
-    if smoke:
-        cmd.append("--smoke")
-    proc = subprocess.run(cmd, env=env)
-    if proc.returncode:
-        raise RuntimeError(
-            f"mesh_overlap child failed with code {proc.returncode}")
-
-
-def _child(smoke: bool) -> None:
+def run(smoke: bool = False) -> None:
     import dataclasses
 
     import jax
@@ -78,21 +54,21 @@ def _child(smoke: bool) -> None:
     from repro.launch.mesh import make_test_mesh
     from repro.runtime.shardlib import use_mesh
 
-    ndev = len(jax.devices())
-    assert ndev == DEVICES, (
-        f"child expected {DEVICES} forced host devices, got {ndev}")
+    devices = jax.devices()
+    ndev = len(devices)
 
     rng = np.random.default_rng(0)
     iters, warmup = (2, 1) if smoke else (5, 2)
     configs = SMOKE_CONFIGS if smoke else CONFIGS
-    out = {"devices": ndev, "mode": "smoke" if smoke else "full",
-           "configs": {}}
+    out = {"devices": ndev, "platform": devices[0].platform,
+           "device_kind": devices[0].device_kind,
+           "mode": "smoke" if smoke else "full", "configs": {}}
 
-    with use_mesh(make_test_mesh(1, DEVICES)):
+    with use_mesh(make_test_mesh(1, ndev)):
         for label, nt, e, cap, k, n in configs:
             desc = GroupedGemmDescriptor(
                 t=nt * e * cap, k=k, n=n, num_experts=e,
-                mesh=MeshSpec("model", DEVICES))
+                mesh=MeshSpec("model", ndev))
             chosen = plan_grouped(desc)
             x4 = jnp.asarray(rng.standard_normal((nt, e, cap, k)),
                              jnp.float32)
@@ -148,7 +124,4 @@ def _child(smoke: bool) -> None:
 
 
 if __name__ == "__main__":
-    if "--child" in sys.argv:
-        _child("--smoke" in sys.argv)
-    else:
-        run("--smoke" in sys.argv)
+    run("--smoke" in sys.argv)

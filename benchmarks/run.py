@@ -30,9 +30,8 @@ warm — so every table is per-phase, not cumulative.
             (BENCH_quant.json)
   mesh    — mesh-aware expert dispatch (DESIGN.md §14): gathered vs
             distributed (all_to_all) grouped-GEMM step time, comm bytes
-            and launches-per-shard on a host-count-forced 8-device mesh
-            (BENCH_mesh.json; runs in a subprocess so the forced device
-            count never leaks into this process)
+            and launches-per-shard on a mesh of this process's devices
+            (BENCH_mesh.json)
 
 ``--smoke`` is the CI job (interpret mode): it runs the fig89 sweep plus
 the grouped, flash, train, serve, quant and mesh suites at reduced size,
@@ -61,6 +60,8 @@ def main() -> None:
                     help="reduced-size CI run of the GEMM sweep "
                          "(fused path end-to-end)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (table1_throughput, fig1_scaling, fig23_bandwidth,
                             fig45_alignment, fig7_blocking, fig89_gemm_sweep,
                             flash_fused, grouped_fused, mesh_overlap,

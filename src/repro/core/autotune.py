@@ -309,9 +309,11 @@ def search(execute, desc: KernelDescriptor, machine: MachineModel,
     """Time the top-``budget`` candidates; return (winner, timed_count).
 
     The winner carries ``plan_source="autotuned"`` and is persisted to
-    ``tuning_cache`` when one is given.  A candidate whose build or run
-    raises is skipped; if every candidate fails the caller falls back to
-    the analytical tier (winner ``None``).
+    ``tuning_cache`` when one is given.  Under the interpreter a
+    candidate whose build or run raises is skipped, and if every candidate
+    fails the caller falls back to the analytical tier (winner ``None``).
+    Compiled (on a chip), a failure raises: skipping a kernel the chip's
+    compiler refused would hide it behind whichever plan still builds.
     """
     candidates = candidate_plans(desc, machine, top_k=budget)
     # A forced execution-path override (config.fused="on"/"off") makes the
@@ -334,7 +336,9 @@ def search(execute, desc: KernelDescriptor, machine: MachineModel,
     for plan in candidates:
         try:
             t = _time_plan(execute, desc, plan, operands, interpret, kw)
-        except Exception as e:  # build/run failure: skip this candidate
+        except Exception as e:
+            if not interpret:
+                raise
             warnings.warn(f"autotune candidate failed for {desc.family}: {e}")
             continue
         timed += 1

@@ -43,10 +43,12 @@ from .machine import MachineModel, DEFAULT_MACHINE
 # The flattening/predication machinery lives in the schedule layer
 # (DESIGN.md §9); re-exported here for compatibility — plans *produce*
 # schedules, so blocking is the schedule layer's only upstream.
-from .schedule import (DecodeTileSchedule, FlashTileSchedule,  # noqa: F401
-                       GroupedTileSchedule, TileSchedule, ceil_div,
-                       flash_tile_schedule, flatten_regions, plan_launches,
-                       round_up)
+from .schedule import (LANES, DecodeTileSchedule,  # noqa: F401
+                       FlashTileSchedule, GroupedTileSchedule, TileSchedule,
+                       ceil_div, flash_bwd_vmem_need, flash_tile_schedule,
+                       flash_vmem_need, flatten_regions,
+                       grouped_bwd_vmem_need, matmul_vmem_need,
+                       plan_launches, round_up, sublanes, vmem_fits)
 
 # ---------------------------------------------------------------------------
 # Palette
@@ -183,7 +185,12 @@ class BlockingPlan:
         desc = self.desc
         if desc.mesh is not None and self.comm is not None:
             desc = mesh_local_desc(desc, self.comm)
-        return flatten_regions(desc.m, desc.n, desc.k, self.bk, self.regions)
+        # Rows of A, C and the output share window origins: align them to
+        # the narrowest of those dtypes' register tiles.
+        row_align = max(8 * max(1, 4 // desc.a_wire_itemsize),
+                        sublanes(desc.out_dtype))
+        return flatten_regions(desc.m, desc.n, desc.k, self.bk, self.regions,
+                               row_align=row_align)
 
     def validate(self):
         """Every C element covered exactly once (tested by hypothesis)."""
@@ -384,17 +391,31 @@ def fused_legal(desc: GemmDescriptor,
     The fused kernel stages the whole per-batch-element operands (plus the
     output and the accumulator scratch) in VMEM and slides tile windows
     over them in-kernel, so it is only legal when they all fit.  Batch is a
-    grid dimension — only one batch slice is resident at a time.
+    grid dimension — only one batch slice is resident at a time.  The
+    bytes are those the kernel asks Mosaic for
+    (:func:`~repro.core.schedule.matmul_vmem_need`), at the largest
+    padded extents and accumulator any schedule of this GEMM can have.
     """
-    out_sz = jnp.dtype(desc.out_dtype).itemsize
-    need = (desc.m * desc.k * desc.a_wire_itemsize
-            + desc.k * desc.n * desc.b_wire_itemsize)
-    if desc.quant is not None:
-        # staged scale operands: sa (m, 1) + sb (1, n), f32
-        need += (desc.m + desc.n) * 4
-    need += desc.m * desc.n * out_sz * (2 if desc.accumulate else 1)
-    need += ACC_BUDGET_ELEMS * 4  # accumulator scratch upper bound
-    return need <= machine.vmem_bytes
+    quant = desc.quant is not None
+    need = matmul_vmem_need(
+        _row_bound(desc.m), round_up(desc.n, LANES), round_up(desc.k, LANES),
+        a_isz=desc.a_wire_itemsize, b_isz=desc.b_wire_itemsize,
+        out_isz=jnp.dtype(desc.out_dtype).itemsize,
+        acc=(ACC_BUDGET_ELEMS // LANES, LANES), layout=desc.layout,
+        accumulate=desc.accumulate, row_scales=quant,
+        col_rows=int(quant) + int(desc.epilogue in BIAS_EPILOGUES))
+    return vmem_fits(need, machine.vmem_bytes)
+
+
+def _row_bound(extent: int) -> int:
+    """Upper bound of a staged row extent: schedules pad rows to at most
+    the widest sublane tile (32 rows of a 1-byte dtype)."""
+    return round_up(extent, 32)
+
+
+def _edge_bound(extent: int, align: int) -> int:
+    """Largest tile edge :func:`_tile_candidates` offers along ``extent``."""
+    return min(_TILE_HI, round_up(extent, align))
 
 
 def plan_gemm(desc: GemmDescriptor,
@@ -534,8 +555,11 @@ def _corner_block(rows, cols, shapes) -> Tuple[int, int]:
 # frozen plan.  These replace the hardcoded constants the kernel wrappers
 # used to carry (block_q=512, bm=128/bk=512/bn=256, bt=256).
 
+_TILE_HI = 1024
+
+
 def _tile_candidates(extent: int, align: int, lo: int = 64,
-                     hi: int = 1024) -> List[int]:
+                     hi: int = _TILE_HI) -> List[int]:
     """Aligned power-of-two tile edges covering [lo, hi], clipped to extent.
 
     An edge >= extent collapses to the aligned cover of extent itself, so
@@ -572,7 +596,7 @@ class FlashPlan:
         (delegates to the schedule layer, DESIGN.md §10)."""
         d = self.desc
         return flash_tile_schedule(d.sq, d.sk, self.block_q, self.block_k,
-                                   d.causal)
+                                   d.causal, row_align=sublanes(d.dtype))
 
     def predicted_seconds(self, machine: MachineModel = DEFAULT_MACHINE) -> float:
         """Cost-model estimate under ``machine`` (see
@@ -589,10 +613,16 @@ def flash_fused_legal(desc: FlashDescriptor,
     whole in VMEM (clamped ragged windows need element-granular origins,
     which BlockSpec block indices cannot express) and slides tile windows
     over them in-kernel; legal only when they fit next to the per-tile
-    score/carry scratch."""
-    isz = jnp.dtype(desc.dtype).itemsize
-    need = (2 * desc.sq + 2 * desc.sk) * desc.d * isz  # q + out + k + v
-    return need <= machine.vmem_bytes // 2
+    score/carry scratch — the bytes the kernel asks Mosaic for
+    (:func:`~repro.core.schedule.flash_vmem_need`), at the largest tiles
+    and padded extents any plan of this descriptor can have, with the
+    LSE column the training forward drains."""
+    need = flash_vmem_need(
+        _row_bound(desc.sq), _row_bound(desc.sk), desc.d,
+        isz=jnp.dtype(desc.dtype).itemsize,
+        bq=_edge_bound(desc.sq, 32), bk=_edge_bound(desc.sk, LANES),
+        lse=True)
+    return vmem_fits(need, machine.vmem_bytes)
 
 
 def _predict_flash_seconds(desc: FlashDescriptor, bq: int, bk: int,
@@ -752,7 +782,10 @@ class GroupedGemmPlan:
         d = self.local_desc
         return GroupedTileSchedule(
             t=d.t, k=d.k, n=d.n, num_experts=d.num_experts,
-            bm=min(self.bm, d.t), bk=min(self.bk, d.k), bn=min(self.bn, d.n))
+            bm=min(self.bm, d.t), bk=min(self.bk, d.k), bn=min(self.bn, d.n),
+            row_align=max(sublanes(d.dtype),
+                          8 * max(1, 4 // getattr(d, "x_wire_itemsize",
+                                                  4))))
 
     def predicted_seconds(self, machine: MachineModel = DEFAULT_MACHINE) -> float:
         comm_s = 0.0
@@ -770,18 +803,20 @@ def grouped_fused_legal(desc: GroupedGemmDescriptor,
     The fused kernel stages the whole token block and output in VMEM
     (clamped row windows need element-granular origins, which BlockSpec
     block indices cannot express) plus one double-buffered expert weight
-    panel; legal only when they all fit.
+    panel; legal only when they all fit — the bytes the kernel asks
+    Mosaic for (:func:`~repro.core.schedule.matmul_vmem_need`), at the
+    largest tiles and padded extents any plan of this descriptor can have.
     """
     isz = jnp.dtype(desc.dtype).itemsize
-    x_sz = getattr(desc, "x_wire_itemsize", isz)
-    w_sz = getattr(desc, "w_wire_itemsize", isz)
-    need = desc.t * desc.k * x_sz + desc.t * desc.n * isz
-    need += 2 * desc.k * desc.n * w_sz  # double-buffered expert panel
-    if getattr(desc, "quant", None) is not None:
-        # staged scale operands: sx (t, 1) whole + one sw expert row, f32
-        need += (desc.t + desc.n) * 4
-    need += ACC_BUDGET_ELEMS * 4       # accumulator scratch upper bound
-    return need <= machine.vmem_bytes
+    quant = getattr(desc, "quant", None) is not None
+    need = matmul_vmem_need(
+        _row_bound(desc.t), round_up(desc.n, LANES), round_up(desc.k, LANES),
+        a_isz=getattr(desc, "x_wire_itemsize", isz),
+        b_isz=getattr(desc, "w_wire_itemsize", isz), out_isz=isz,
+        acc=(_edge_bound(desc.t, 32), _edge_bound(desc.n, LANES)),
+        row_scales=quant,
+        col_rows=int(quant) + int(desc.epilogue in BIAS_EPILOGUES))
+    return vmem_fits(need, machine.vmem_bytes)
 
 
 def _predict_grouped_seconds(desc: GroupedGemmDescriptor, bm: int, bk: int,
@@ -987,13 +1022,14 @@ def flash_bwd_fused_legal(desc: FlashBwdDescriptor,
     """Can this flash backward run as one scheduled ``pallas_call``?
 
     The backward walk stages one batch-head slice of q/k/v/o/do plus the
-    dq/dk/dv outputs (dk/dv accumulated fp32) and the staged LSE row."""
-    isz = jnp.dtype(desc.dtype).itemsize
-    need = (3 * desc.sq + 2 * desc.sk) * desc.d * isz  # q/o/do + k/v
-    need += desc.sq * desc.d * isz                     # dq
-    need += 2 * desc.sk * desc.d * 4                   # dk/dv, fp32 RMW
-    need += desc.sq * 4                                # lse row
-    return need <= machine.vmem_bytes // 2
+    dq/dk/dv outputs (fp32) and the staged LSE row
+    (:func:`~repro.core.schedule.flash_bwd_vmem_need`, at the largest
+    tiles and padded extents any plan can have)."""
+    need = flash_bwd_vmem_need(
+        _row_bound(desc.sq), _row_bound(desc.sk), desc.d,
+        isz=jnp.dtype(desc.dtype).itemsize,
+        bq=_edge_bound(desc.sq, 32), bk=_edge_bound(desc.sk, LANES))
+    return vmem_fits(need, machine.vmem_bytes)
 
 
 def plan_flash_bwd(desc: FlashBwdDescriptor,
@@ -1015,15 +1051,15 @@ def grouped_bwd_fused_legal(desc: GroupedGemmBwdDescriptor,
 
     dgrad and wgrad share one launch: x, dy and dx stage whole, the expert
     panel double-buffers, and dW (plus db for biased epilogues) stages
-    whole in fp32 for read-modify-write accumulation."""
-    isz = jnp.dtype(desc.dtype).itemsize
-    need = desc.t * (2 * desc.k + desc.n) * isz      # x, dx, dy
-    need += 2 * desc.k * desc.n * isz                # double-buffered panel
-    need += desc.num_experts * desc.k * desc.n * 4   # dW, fp32 RMW
-    if desc.epilogue in BIAS_EPILOGUES:
-        need += desc.num_experts * desc.n * 4        # db, fp32
-    need += ACC_BUDGET_ELEMS * 4
-    return need <= machine.vmem_bytes
+    whole in fp32 for read-modify-write accumulation
+    (:func:`~repro.core.schedule.grouped_bwd_vmem_need`, at the largest
+    tiles and padded extents any plan can have)."""
+    need = grouped_bwd_vmem_need(
+        _row_bound(desc.t), round_up(desc.k, LANES), round_up(desc.n, LANES),
+        experts=desc.num_experts, isz=jnp.dtype(desc.dtype).itemsize,
+        acc=(_edge_bound(desc.t, 32), _edge_bound(desc.k, LANES)),
+        with_db=desc.epilogue in BIAS_EPILOGUES)
+    return vmem_fits(need, machine.vmem_bytes)
 
 
 def plan_grouped_bwd(desc: GroupedGemmBwdDescriptor,

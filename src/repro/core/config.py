@@ -6,11 +6,16 @@ serves a request (generated SME kernel vs vendor BLAS).  Ours has more:
   * ``backend``   — "xla" (dot_general, the vendor-BLAS analogue; default
                     in CPU containers) or "pallas" (the paper's engine:
                     descriptor → plan → generated kernel);
-  * ``interpret`` — run Pallas kernels in interpret mode (the CPU
-                    correctness path) or compiled (TPU hardware);
+  * ``interpret`` — ``None`` (the default) derives it from the platform
+                    at dispatch (:func:`resolve_interpret`): compiled on a
+                    TPU, interpreted on the CPU test backend.  ``False``
+                    pins compiled kernels (chip-compile tests on the CPU);
+                    ``True`` is refused while a TPU is present;
   * ``machine``   — the :class:`~repro.core.machine.MachineModel` that
                     parameterizes every tile planner (the "Table I"
-                    constants, or a microbench-calibrated model);
+                    constants, or a microbench-calibrated model); ``None``
+                    (the default) picks the model of the first device's
+                    ``device_kind`` (:attr:`EngineConfig.machine_model`);
   * ``autotune``  — let ``engine.dispatch`` time the top-K candidate
                     tilings empirically instead of trusting the model
                     (DESIGN.md §7); ``autotune_budget`` caps K;
@@ -61,8 +66,10 @@ import os
 import threading
 from typing import Optional
 
+import jax
+
 from .descriptor import QuantSpec, resolve_quant
-from .machine import DEFAULT_MACHINE, MachineModel, get_machine
+from .machine import MachineModel, get_machine, machine_for_device
 
 BACKENDS = ("xla", "pallas")
 FUSED_MODES = ("auto", "on", "off")
@@ -73,8 +80,8 @@ class EngineConfig:
     """One immutable snapshot of the engine's ambient configuration."""
 
     backend: str = "xla"
-    interpret: bool = True
-    machine: MachineModel = DEFAULT_MACHINE
+    interpret: Optional[bool] = None
+    machine: Optional[MachineModel] = None
     # Empirical plan search (DESIGN.md §7).  ``tuning_cache`` is a JSON
     # file path; empty string means "no cache" (``replace`` treats None as
     # "leave unchanged", so "" is the explicit off switch).
@@ -113,6 +120,14 @@ class EngineConfig:
             raise ValueError(f"quant must be None or a QuantSpec, "
                              f"got {self.quant!r}")
 
+    @property
+    def machine_model(self) -> MachineModel:
+        """The configured model, else the one for the first device
+        (:func:`~repro.core.machine.machine_for_device`)."""
+        if self.machine is not None:
+            return self.machine
+        return machine_for_device(jax.devices()[0])
+
     def replace(self, **kw) -> "EngineConfig":
         kw = {k: v for k, v in kw.items() if v is not None}
         if isinstance(kw.get("machine"), str):
@@ -126,6 +141,20 @@ class EngineConfig:
                     self, **{k: v for k, v in kw.items() if k != "quant"},
                     quant=None)
         return dataclasses.replace(self, **kw)
+
+
+def resolve_interpret(setting: Optional[bool]) -> bool:
+    """Whether Pallas kernels run in interpret mode: ``setting`` when
+    given, else whether JAX's default backend is something other than a
+    TPU.  A TPU is never timed through the interpreter: asking for
+    interpret mode while one is present raises."""
+    on_tpu = jax.default_backend() == "tpu"
+    if setting is None:
+        return not on_tpu
+    if setting and on_tpu:
+        raise ValueError("interpret mode is the CPU test backend's; "
+                         "a TPU is present, so kernels run compiled")
+    return setting
 
 
 def _env_default() -> EngineConfig:

@@ -46,7 +46,7 @@ import warnings
 from typing import Any, Callable, Dict, Iterable, List, Optional
 
 from . import autotune as _autotune
-from .config import get_config
+from .config import get_config, resolve_interpret
 from .descriptor import KernelDescriptor
 from .jit_cache import GLOBAL_KERNEL_CACHE, LruCache
 from .machine import MachineModel
@@ -97,7 +97,10 @@ _autotune_timings: Dict[str, int] = {}
 # fused GEMM path reports exactly 1 where the multi-launch path reports
 # one per plan region.  Counted at trace/execute time, so a jit-compiled
 # repeat call (which never re-enters Python) does not re-count.
+# ``_fused_launches`` counts the share that ran a fused single-launch
+# lowering, so a run can show which lowering it dispatched.
 _launches: Dict[str, int] = {}
+_fused_launches: Dict[str, int] = {}
 
 # Explicit collectives issued per family (DESIGN.md §14): the distributed
 # mesh strategies report the payload bytes and collective launches they
@@ -129,11 +132,15 @@ def _note_timings(family: str, n: int):
         _autotune_timings[family] = _autotune_timings.get(family, 0) + n
 
 
-def count_launches(family: str, n: int = 1):
+def count_launches(family: str, n: int = 1, *, fused: bool = False):
     """Family executors call this once per execute() with the number of
-    kernel launches they are about to emit (``stats()["…"]["launches"]``)."""
+    kernel launches they are about to emit (``stats()["…"]["launches"]``),
+    and ``fused=True`` when they run a fused single-launch lowering
+    (also counted under ``"launches_fused"``)."""
     with _plan_calls_lock:
         _launches[family] = _launches.get(family, 0) + n
+        if fused:
+            _fused_launches[family] = _fused_launches.get(family, 0) + n
 
 
 def count_comm(family: str, nbytes: int, launches: int = 1):
@@ -188,8 +195,9 @@ def _resolve_plan(desc: KernelDescriptor, cfg, *,
                   interpret: Optional[bool] = None) -> Any:
     """Plan-cache lookup; a miss walks the three tiers (DESIGN.md §7)."""
     fam = get_family(desc.family)
-    machine = machine or cfg.machine
-    interpret = cfg.interpret if interpret is None else interpret
+    machine = machine or cfg.machine_model
+    interpret = resolve_interpret(cfg.interpret if interpret is None
+                                  else interpret)
     kw = kw or {}
     # Timing needs concrete operands: under jit tracing (or from plan_for,
     # which has no operands) the autotune tier is unavailable.
@@ -277,13 +285,15 @@ def dispatch(desc: KernelDescriptor, *operands, plan: Any = None,
     ``plan=None`` resolves via tuned-cache → autotune → analytical-model
     (DESIGN.md §7), behind the plan cache; an explicit plan (benchmark
     sweeps, tests pinning tile sizes) bypasses all of it.  ``interpret``
-    defaults from the ambient config — no per-call plumbing.
+    defaults from the ambient config — no per-call plumbing — and is
+    resolved against the platform
+    (:func:`~repro.core.config.resolve_interpret`).
     """
     fam = get_family(desc.family)
     cfg = get_config()
     _seen_descs.setdefault(desc.cache_key(), desc)
-    if interpret is None:
-        interpret = cfg.interpret
+    interpret = resolve_interpret(cfg.interpret if interpret is None
+                                  else interpret)
     if plan is None:
         plan = _resolve_plan(desc, cfg, operands=operands, kw=kw,
                              interpret=interpret)
@@ -329,10 +339,11 @@ def warmup(descriptors: Optional[Iterable[KernelDescriptor]] = None, *,
     ``autotune_timings == 0`` and zero plan-cache misses.
 
     Returns ``{family: warmed descriptor count}``; the same counts
-    accumulate in ``stats()`` under ``"warmups"``.  A descriptor whose
-    build fails (or that warmup cannot synthesize operands for, e.g.
-    mesh descriptors) still warms its plan — degradation is partial,
-    never fatal.
+    accumulate in ``stats()`` under ``"warmups"``.  A descriptor warmup
+    cannot synthesize operands for (e.g. mesh descriptors) still warms
+    its plan.  A failed build raises when kernels run compiled — it is a
+    kernel the chip's compiler refused — and only warns under the
+    interpreter, where the descriptor keeps its plan.
     """
     cfg = get_config()
     if descriptors is None:
@@ -343,8 +354,8 @@ def warmup(descriptors: Optional[Iterable[KernelDescriptor]] = None, *,
                 "configure(warm_start=...) / REPRO_WARM_START")
         from . import warmstart as _warmstart
         descriptors = _warmstart.load_manifest(path)
-    if interpret is None:
-        interpret = cfg.interpret
+    interpret = resolve_interpret(cfg.interpret if interpret is None
+                                  else interpret)
     counts: Dict[str, int] = {}
     for desc in descriptors:
         fam = get_family(desc.family)
@@ -358,6 +369,8 @@ def warmup(descriptors: Optional[Iterable[KernelDescriptor]] = None, *,
                     fam.execute(desc, plan, *operands,
                                 interpret=interpret, **kw)
             except Exception as e:
+                if not interpret:
+                    raise
                 warnings.warn(
                     f"warmup build failed for {desc.family} "
                     f"{desc.cache_key()!r}: {e}")
@@ -399,7 +412,7 @@ def stats() -> Dict[str, Dict[str, int]]:
     {family: {plan_hits, plan_misses, plan_evictions, planner_calls,
               plan_source_tuned_cache, plan_source_autotuned,
               plan_source_model, autotune_timings, launches,
-              comm_bytes, collective_launches, warmups,
+              launches_fused, comm_bytes, collective_launches, warmups,
               kernel_hits, kernel_misses, kernel_evictions}}
 
     Backward families (``<family>_bwd`` descriptors, DESIGN.md §11) fold
@@ -415,7 +428,7 @@ def stats() -> Dict[str, Dict[str, int]]:
                 "plan_hits", "plan_misses", "plan_evictions",
                 "planner_calls",
                 *(f"plan_source_{s}" for s in PLAN_SOURCES),
-                "autotune_timings", "launches",
+                "autotune_timings", "launches", "launches_fused",
                 "comm_bytes", "collective_launches", "warmups",
                 "kernel_hits", "kernel_misses", "kernel_evictions")},
         })
@@ -446,6 +459,9 @@ def stats() -> Dict[str, Dict[str, int]]:
         for fam, n in _launches.items():
             b, sfx = slot(fam)
             b["launches" + sfx] = n
+        for fam, n in _fused_launches.items():
+            b, sfx = slot(fam)
+            b["launches_fused" + sfx] = n
         for fam, n in _comm_bytes.items():
             b, sfx = slot(fam)
             b["comm_bytes" + sfx] = n
@@ -486,6 +502,7 @@ def reset_stats(*, entries: bool = True):
         _plan_sources.clear()
         _autotune_timings.clear()
         _launches.clear()
+        _fused_launches.clear()
         _comm_bytes.clear()
         _collective_launches.clear()
         _warmups.clear()

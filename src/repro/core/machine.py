@@ -310,10 +310,13 @@ def canonical_dtype(dtype) -> str:
     raise ValueError(f"unsupported dtype for machine model: {dtype}")
 
 
-# TPU v5e constants.  peak bf16 = 197 TFLOP/s (given); fp32 through the MXU
-# runs at half rate with fp32 accumulate; int8 doubles bf16 — mirroring the
-# dtype asymmetry the paper measures in Table I (where M4 is FP32-centric;
-# v5e is bf16-centric: the engine's dtype default flips accordingly).
+# TPU v5e constants, the model of JAX ``device_kind`` "TPU v5 lite".
+# Peaks from the Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB of HBM at 819 GB/s.  fp32 through the MXU runs at
+# half the bf16 rate with fp32 accumulate (an estimate, not a published
+# figure) — mirroring the dtype asymmetry the paper measures in Table I
+# (where M4 is FP32-centric; v5e is bf16-centric: the engine's dtype
+# default flips accordingly).
 TPU_V5E = MachineModel(
     name="tpu_v5e",
     mxu_rows=128,
@@ -322,8 +325,8 @@ TPU_V5E = MachineModel(
         "bfloat16": 197e12,
         "float16": 197e12,
         "float32": 98.5e12,
-        "int8": 394e12,
-        "float8_e4m3": 394e12,  # fp8 rides the int8 MAC rate
+        "int8": 393e12,
+        "float8_e4m3": 393e12,  # fp8 rides the int8 MAC rate
         "float64": 0.5e12,  # emulated; not a target dtype
     },
     hbm_bytes=16 * 1024**3,
@@ -337,8 +340,9 @@ TPU_V5E = MachineModel(
     dcn_bw=25e9 / 8,  # ~25 Gb/s effective per chip across pods
 )
 
-# The CPU host we validate on (interpret mode).  Only used to sanity-scale
-# wall-clock expectations in benchmarks; never by the planner.
+# The CPU host we validate on (interpret mode); no ``device_kind`` maps to
+# it.  Only used to sanity-scale wall-clock expectations in benchmarks;
+# never by the planner.
 CPU_HOST = MachineModel(
     name="cpu_host",
     mxu_rows=1,
@@ -358,10 +362,27 @@ CPU_HOST = MachineModel(
 
 DEFAULT_MACHINE = TPU_V5E
 
+# Built-in models by the ``device_kind`` JAX reports for a TPU chip.
+MACHINES_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
 
 def get_machine(name: str = "tpu_v5e") -> MachineModel:
     """Look up a built-in machine model by name."""
     return {"tpu_v5e": TPU_V5E, "cpu_host": CPU_HOST}[name]
+
+
+def machine_for_device(device) -> MachineModel:
+    """The pinned model of a JAX ``device``: on a TPU, looked up by its
+    ``device_kind`` (a kind with no model is an error, not a default);
+    off a TPU — the CPU test backend — the v5e planning target."""
+    if device.platform != "tpu":
+        return DEFAULT_MACHINE
+    model = MACHINES_BY_DEVICE_KIND.get(device.device_kind)
+    if model is None:
+        raise ValueError(
+            f"no machine model for TPU device_kind {device.device_kind!r}; "
+            f"known: {sorted(MACHINES_BY_DEVICE_KIND)}")
+    return model
 
 
 def _validate_refit(data, base: MachineModel) -> Optional[str]:

@@ -116,10 +116,9 @@ def _probe_mesh():
 
 
 def _shmap_collective(mesh, body, out_spec):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=P("probe"),
-                             out_specs=out_spec, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("probe"),
+                                 out_specs=out_spec, check_vma=False))
 
 
 def probe_all_gather(mbytes: int = 4, iters: int = 5) -> ProbeResult:

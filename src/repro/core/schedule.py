@@ -36,6 +36,7 @@ planning layer and the generated kernels.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import List, Sequence, Tuple
 
 import jax
@@ -59,6 +60,143 @@ QUANT_TILE = 128
 def round_up(a: int, b: int) -> int:
     """Round ``a`` up to the nearest multiple of ``b``."""
     return ceil_div(a, b) * b
+
+
+# Lane width of a TPU vector register: a window origin on an operand's
+# minor dimension must be a multiple of it for Mosaic to lower the load.
+LANES = 128
+
+
+def sublanes(dtype) -> int:
+    """Rows of one native register tile for ``dtype`` (8 for 32-bit, 16
+    for 16-bit, 32 for 8-bit values): the alignment Mosaic needs to prove
+    for a window origin on an operand's second-minor dimension."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+
+
+def padded_extent(extent: int, window: int, align: int) -> int:
+    """Extent of the staged buffer a fixed ``window`` slides over.
+
+    One window spanning the whole extent sits at origin 0.  Otherwise the
+    buffer is rounded up to ``align`` so that a clamped edge window
+    (origin ``extent_p - window``) stays aligned: its overhang reads the
+    block padding past the operand, which the ownership and K-tail masks
+    discard, and the writeback never stores it.
+    """
+    return extent if window >= extent else round_up(extent, align)
+
+
+# Scoped VMEM of the fused kernels, which stage whole operands.  Mosaic's
+# default limit (16 MiB on v5e) is below what they need, so each asks for
+# its own (:func:`vmem_limit`), never more than the cap, which leaves room
+# under the chip's 128 MiB for Mosaic's internal scratch.  The planners'
+# legality checks count the same bytes (:func:`vmem_fits`), so a plan
+# they call fused is one Mosaic accepts.
+VMEM_LIMIT_CAP = 100 << 20
+VMEM_LIMIT_FLOOR = 16 << 20
+# Room for the values a kernel body keeps in VMEM beside its declared
+# blocks and scratch (score tiles, masks, casts).
+VMEM_HEADROOM = 4 << 20
+
+
+def tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a ``(rows, cols)`` block: it is laid out in whole
+    native register tiles (:func:`sublanes` x :data:`LANES`), so a
+    ``(t, 1)`` column of scales takes the room of a ``(t, 128)`` panel."""
+    sub = 8 * max(1, 4 // itemsize)
+    return round_up(rows, sub) * round_up(cols, LANES) * itemsize
+
+
+def vmem_need(blocks, scratch: int = 0) -> int:
+    """Scoped VMEM of a kernel that stages ``blocks`` — ``(rows, cols,
+    itemsize)`` each, double-buffered by the pipeline, inputs and outputs
+    alike — next to ``scratch`` bytes of scratch buffers."""
+    return (2 * sum(tile_bytes(*b) for b in blocks) + scratch
+            + VMEM_HEADROOM)
+
+
+def vmem_fits(need: int, capacity: int) -> bool:
+    """Can a kernel needing ``need`` bytes (:func:`vmem_need`) run on a
+    chip with ``capacity`` bytes of VMEM?"""
+    return need <= min(capacity, VMEM_LIMIT_CAP)
+
+
+def vmem_limit(need: int) -> int:
+    """The ``vmem_limit_bytes`` a kernel needing ``need`` bytes asks for."""
+    return int(min(max(need, VMEM_LIMIT_FLOOR), VMEM_LIMIT_CAP))
+
+
+def matmul_vmem_need(rows: int, n: int, k: int, *, a_isz: int, b_isz: int,
+                     out_isz: int, acc: Tuple[int, int], layout: str = "nn",
+                     accumulate: bool = False, row_scales: bool = False,
+                     col_rows: int = 0) -> int:
+    """Scoped VMEM of a fused (grouped) GEMM over staged extents ``rows``
+    x ``n`` x ``k``: A (or x) and the output whole, one B (or expert
+    weight) panel, a C input when ``accumulate``, an ``(rows, 1)`` f32
+    column of row scales, ``col_rows`` ``(1, n)`` rows (column scales,
+    bias; 32 bytes a lane whatever their dtype) and the ``acc`` f32 /
+    int32 accumulator.  The kernel builders pass their exact extents; the
+    legality checks pass upper bounds, so legal implies it fits."""
+    blocks = [(rows, k, a_isz),
+              (k, n, b_isz) if layout == "nn" else (n, k, b_isz),
+              (rows, n, out_isz)]
+    if accumulate:
+        blocks.append((rows, n, out_isz))
+    if row_scales:
+        blocks.append((rows, 1, 4))
+    blocks += [(1, n, 4)] * col_rows
+    return vmem_need(blocks, tile_bytes(*acc, 4))
+
+
+def flash_vmem_need(sq: int, sk: int, d: int, *, isz: int, bq: int, bk: int,
+                    lse: bool = False) -> int:
+    """Scoped VMEM of the fused flash forward over staged extents ``sq`` /
+    ``sk``: q, out, k and v whole (plus the ``(sq, 1)`` LSE column when
+    drained), the running max / denominator / accumulator and the
+    ``(bq, bk)`` f32 score tile."""
+    blocks = [(sq, d, isz)] * 2 + [(sk, d, isz)] * 2
+    if lse:
+        blocks.append((sq, 1, 4))
+    scratch = (2 * tile_bytes(bq, 1, 4) + tile_bytes(bq, d, 4)
+               + tile_bytes(bq, bk, 4))
+    return vmem_need(blocks, scratch)
+
+
+def flash_bwd_vmem_need(sq: int, sk: int, d: int, *, isz: int, bq: int,
+                        bk: int) -> int:
+    """Scoped VMEM of the fused flash backward: q, o, dO and the LSE
+    column, k and v whole, dQ / dK / dV whole in f32, the D row and dQ
+    accumulator, and the ``(bq, bk)`` f32 score tile."""
+    blocks = ([(sq, d, isz)] * 3 + [(sk, d, isz)] * 2 + [(sq, 1, 4)]
+              + [(sq, d, 4)] + [(sk, d, 4)] * 2)
+    scratch = (tile_bytes(bq, 1, 4) + tile_bytes(bq, d, 4)
+               + tile_bytes(bq, bk, 4))
+    return vmem_need(blocks, scratch)
+
+
+def grouped_bwd_vmem_need(t: int, k: int, n: int, *, experts: int, isz: int,
+                          acc: Tuple[int, int], with_db: bool = False) -> int:
+    """Scoped VMEM of the fused grouped backward: x, dy, one expert panel,
+    dX (f32) and every expert's dW (f32, plus db) whole, and the ``acc``
+    f32 accumulator."""
+    blocks = ([(t, k, isz), (t, n, isz), (k, n, isz), (t, k, 4)]
+              + [(k, n, 4)] * experts)
+    if with_db:
+        blocks.append((experts, n, 4))
+    return vmem_need(blocks, tile_bytes(*acc, 4))
+
+
+def proven_align(origins, cap: int = LANES) -> int:
+    """Largest power of two ``<= cap`` dividing every origin: the value a
+    kernel may pass to ``pl.multiple_of`` for a table-driven window origin
+    (a hint that is false would be undefined behaviour on the chip)."""
+    g = 0
+    for o in origins:
+        g = math.gcd(g, int(o))
+    a = cap
+    while g % a:
+        a //= 2
+    return a
 
 
 # ---------------------------------------------------------------------------
@@ -90,6 +228,12 @@ class TileSchedule:
     block (:data:`QUANT_TILE`-wide) the window origin's row falls in —
     carried on every tile so quantized and wide plans share one table
     layout; wide kernels simply never read the column.
+
+    ``m_p``/``n_p``/``k_p`` are the staged buffer extents
+    (:func:`padded_extent`): windows slide over them, so clamped edge
+    origins stay aligned, and ``row_align``/``col_align``/``k_align`` are
+    the alignments every row / column / K-panel origin provably has
+    (:func:`proven_align`) — the hints the kernel hands Mosaic.
     """
 
     m: int
@@ -99,44 +243,62 @@ class TileSchedule:
     k_steps: int
     blocks: Tuple[Tuple[int, int], ...]
     tiles: Tuple[Tuple[int, int, int, int, int, int, int, int], ...]
+    m_p: int
+    n_p: int
+    k_p: int
+    row_align: int
+    col_align: int
+    k_align: int
 
     @property
     def num_tiles(self) -> int:
         return len(self.tiles)
+
+    def k_window(self, ks):
+        """``(k0, kstart)`` of K-panel ``ks`` over the staged K extent."""
+        return clamped_k_window(ks, self.bk, self.k_p)
 
     def validate(self):
         """Every C element owned by exactly one tile mask."""
         owned = 0
         for row0, col0, row_end, col_end, rs, cs, bid, sidx in self.tiles:
             bm_e, bn_e = self.blocks[bid]
-            assert 0 <= rs and rs + bm_e <= self.m, (rs, bm_e, self.m)
-            assert 0 <= cs and cs + bn_e <= self.n, (cs, bn_e, self.n)
+            assert 0 <= rs and rs + bm_e <= self.m_p, (rs, bm_e, self.m_p)
+            assert 0 <= cs and cs + bn_e <= self.n_p, (cs, bn_e, self.n_p)
             assert rs <= row0 and row_end <= rs + bm_e
             assert cs <= col0 and col_end <= cs + bn_e
+            assert row_end <= self.m and col_end <= self.n
+            assert rs % self.row_align == 0 and cs % self.col_align == 0
             assert sidx == rs // QUANT_TILE, (sidx, rs)
             owned += (row_end - row0) * (col_end - col0)
         assert owned == self.m * self.n, (owned, self.m * self.n)
         return True
 
 
-def flatten_regions(m: int, n: int, k: int, bk: int,
-                    regions: Sequence) -> TileSchedule:
+def flatten_regions(m: int, n: int, k: int, bk: int, regions: Sequence,
+                    row_align: int = 8) -> TileSchedule:
     """Flatten a region cover into the fused kernel's tile tables.
 
     ``regions`` is any sequence of objects with ``row0/col0/rows/cols``
     ownership rectangles and ``bm/bn`` block geometry (the
     :class:`repro.core.blocking.Region` shape).  Region blocks are clamped
-    to the matrix (``bm_e = min(bm, m)``) so every fixed-shape window fits
-    the real operand buffers; a clamped block walks its region with the
-    *effective* stride, so raggedness is absorbed by the per-tile
-    ownership mask, never by the shapes.
+    to the staged buffer (``bm_e = min(bm, m_p)``) so every fixed-shape
+    window fits it; a clamped block walks its region with the *effective*
+    stride, so raggedness is absorbed by the per-tile ownership mask,
+    never by the shapes.  ``row_align`` is the operand dtype's sublane
+    count (:func:`sublanes`): rows are staged in whole register tiles,
+    even under one window, because Mosaic cannot mask a packed (16-bit
+    or narrower) tile of fewer rows than one sublane group.
     """
     bk = max(1, min(bk, k))
+    m_p = round_up(m, row_align)
+    n_p = n if all(r.bn >= n for r in regions) else round_up(n, LANES)
+    k_p = padded_extent(k, bk, LANES)
     blocks: List[Tuple[int, int]] = []
     ids = {}
     tiles = []
     for r in regions:
-        bm_e, bn_e = min(r.bm, m), min(r.bn, n)
+        bm_e, bn_e = min(r.bm, m_p), min(r.bn, n_p)
         bid = ids.get((bm_e, bn_e))
         if bid is None:
             bid = ids[(bm_e, bn_e)] = len(blocks)
@@ -147,12 +309,17 @@ def flatten_regions(m: int, n: int, k: int, bk: int,
             for j in range(ceil_div(r.cols, bn_e)):
                 col0 = r.col0 + j * bn_e
                 col_end = min(col0 + bn_e, r.col0 + r.cols)
-                rs = min(row0, m - bm_e)
+                rs = min(row0, m_p - bm_e)
                 tiles.append((row0, col0, row_end, col_end,
-                              rs, min(col0, n - bn_e),
+                              rs, min(col0, n_p - bn_e),
                               bid, rs // QUANT_TILE))
-    return TileSchedule(m=m, n=n, k=k, bk=bk, k_steps=ceil_div(k, bk),
-                        blocks=tuple(blocks), tiles=tuple(tiles))
+    k_steps = ceil_div(k, bk)
+    return TileSchedule(
+        m=m, n=n, k=k, bk=bk, k_steps=k_steps, blocks=tuple(blocks),
+        tiles=tuple(tiles), m_p=m_p, n_p=n_p, k_p=k_p,
+        row_align=proven_align(t[4] for t in tiles),
+        col_align=proven_align(t[5] for t in tiles),
+        k_align=proven_align(min(s * bk, k_p - bk) for s in range(k_steps)))
 
 
 def pack_table(rows: Sequence[Sequence[int]]) -> np.ndarray:
@@ -194,6 +361,11 @@ class GroupedTileSchedule:
     is the clamped origin of its fixed ``bm``-row window, ``expert``
     selects the weight (and bias) panel, and ``state`` marks the tile as
     compute / zero-fill (rows past ``sum(group_sizes)``) / skip.
+
+    With ``row_align > 1`` each group's blocks start at its offset
+    rounded down to ``row_align`` (the first block owns fewer rows), so
+    every window origin is aligned for Mosaic; windows then slide over the
+    staged extents ``t_p``/``n_p``/``k_p`` (:func:`padded_extent`).
     """
 
     t: int
@@ -203,15 +375,47 @@ class GroupedTileSchedule:
     bm: int
     bk: int
     bn: int
+    row_align: int = 1
 
     def __post_init__(self):
         assert self.bm <= self.t and self.bn <= self.n and self.bk <= self.k
 
     @property
+    def t_p(self) -> int:
+        return padded_extent(self.t, self.bm, self.row_align)
+
+    @property
+    def n_p(self) -> int:
+        return padded_extent(self.n, self.bn, LANES)
+
+    @property
+    def k_p(self) -> int:
+        return padded_extent(self.k, self.bk, LANES)
+
+    @property
+    def row_origin_align(self) -> int:
+        """Alignment every table ``row_start`` provably has."""
+        if self.bm >= self.t:
+            return LANES  # one window: every origin is 0
+        return self.row_align if self.bm % self.row_align == 0 else 1
+
+    @property
+    def col_align(self) -> int:
+        return proven_align(min(j * self.bn, self.n_p - self.bn)
+                            for j in range(self.n_steps))
+
+    @property
+    def k_align(self) -> int:
+        return proven_align(min(s * self.bk, self.k_p - self.bk)
+                            for s in range(self.k_steps))
+
+    @property
     def max_tiles(self) -> int:
-        """Static row-tile bound: every expert may add one partial block,
-        plus the zero-fill tail region."""
-        return ceil_div(self.t, self.bm) + self.num_experts + 1
+        """Static row-tile bound: every expert may add one partial block
+        (and one more for its rounded-down start), plus the zero-fill
+        tail region."""
+        slack = (self.num_experts + 1) * (self.row_align - 1)
+        return ceil_div(self.t + slack, self.bm) + self.num_experts + 1
 
     @property
     def k_steps(self) -> int:
@@ -233,7 +437,11 @@ class GroupedTileSchedule:
         all_sizes = jnp.concatenate([sizes, tail[None]])          # (E+1,)
         all_off = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                    jnp.cumsum(all_sizes)])        # (E+2,)
-        nblocks = (all_sizes + bm - 1) // bm                      # (E+1,)
+        # Each group's block grid starts at its offset rounded down to
+        # row_align; empty groups get no blocks.
+        lead = all_off[:-1] % self.row_align                      # (E+1,)
+        nblocks = jnp.where(all_sizes > 0,
+                            (all_sizes + lead + bm - 1) // bm, 0)  # (E+1,)
         bstart = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                   jnp.cumsum(nblocks)])           # (E+2,)
         g = jnp.arange(self.max_tiles, dtype=jnp.int32)
@@ -242,12 +450,14 @@ class GroupedTileSchedule:
         owner = jnp.clip(
             jnp.searchsorted(bstart, g, side="right") - 1, 0, e)
         local = g - bstart[owner]
-        row0 = all_off[owner] + local * bm
-        row_end = jnp.minimum(row0 + bm, all_off[owner] + all_sizes[owner])
+        start = all_off[owner] - lead[owner] + local * bm
+        row0 = jnp.maximum(start, all_off[owner])
+        row_end = jnp.minimum(start + bm, all_off[owner] + all_sizes[owner])
         active = g < bstart[-1]
         row0 = jnp.where(active, row0, t)
         row_end = jnp.where(active, row_end, t)
-        rs = jnp.clip(jnp.minimum(row0, t - bm), 0)
+        start = jnp.where(active, start, t)
+        rs = jnp.clip(jnp.minimum(start, self.t_p - bm), 0)
         expert = jnp.minimum(owner, e - 1)  # always a legal panel index
         state = jnp.where(
             active & (row_end > row0),
@@ -269,7 +479,8 @@ class GroupedTileSchedule:
             if state == TILE_SKIP:
                 assert row0 == row_end, (row0, row_end)
                 continue
-            assert 0 <= rs and rs + self.bm <= self.t, (rs, self.bm, self.t)
+            assert 0 <= rs and rs + self.bm <= self.t_p, (rs, self.bm)
+            assert rs % self.row_origin_align == 0, rs
             assert rs <= row0 and row_end <= rs + self.bm
             assert 0 <= expert < self.num_experts
             assert (owner_of[row0:row_end] == -1).all(), "row owned twice"
@@ -443,7 +654,9 @@ class FlashTileSchedule:
     inward instead of shrinking), ``[k0, k_end)`` are the key columns
     this tile contributes (the predicate on the clamped-window overlap
     and the sk tail), and ``first``/``last`` flag the q-block's carry
-    boundaries.
+    boundaries.  Windows slide over the staged extents ``sq_p``/``sk_p``
+    (:func:`padded_extent`), so every origin is a multiple of ``q_align``
+    / ``k_align`` (:func:`proven_align`).
     """
 
     sq: int
@@ -452,6 +665,10 @@ class FlashTileSchedule:
     bk: int
     causal: bool
     tiles: Tuple[Tuple[int, int, int, int, int, int, int, int], ...]
+    sq_p: int
+    sk_p: int
+    q_align: int
+    k_align: int
 
     @property
     def num_tiles(self) -> int:
@@ -471,8 +688,9 @@ class FlashTileSchedule:
         open_q = None  # ownership of the q-block currently being walked
         prev_k_end = 0
         for q0, q_end, qs, k0, k_end, ks, first, last in self.tiles:
-            assert 0 <= qs and qs + self.bq <= self.sq, (qs, self.bq, self.sq)
-            assert 0 <= ks and ks + self.bk <= self.sk, (ks, self.bk, self.sk)
+            assert 0 <= qs and qs + self.bq <= self.sq_p, (qs, self.bq)
+            assert 0 <= ks and ks + self.bk <= self.sk_p, (ks, self.bk)
+            assert qs % self.q_align == 0 and ks % self.k_align == 0
             assert qs <= q0 and q_end <= qs + self.bq
             assert ks <= k0 and k_end <= ks + self.bk
             assert k0 < k_end <= self.sk
@@ -496,35 +714,40 @@ class FlashTileSchedule:
 
 
 def flash_tile_schedule(sq: int, sk: int, bq: int, bk: int,
-                        causal: bool) -> FlashTileSchedule:
+                        causal: bool, row_align: int = 8) -> FlashTileSchedule:
     """Build the flattened causal-aware (q, k) tile walk.
 
     Block edges are clamped to the problem so every fixed-shape window
-    fits the operands; for ``causal=True`` a k-block whose first column
+    fits the staged operands (padded to ``row_align`` rows when a window
+    does not span them); for ``causal=True`` a k-block whose first column
     ``k0`` exceeds the q-block's last *owned* row is fully masked and
     never enters the table (the heterogeneous-cover idea applied to the
     causal triangle — at plan time, not as a run-time branch).
     """
     bq = max(1, min(bq, sq))
     bk = max(1, min(bk, sk))
+    sq_p = padded_extent(sq, bq, row_align)
+    sk_p = padded_extent(sk, bk, row_align)
     ck = ceil_div(sk, bk)
     tiles: List[Tuple[int, ...]] = []
     for qi in range(ceil_div(sq, bq)):
         q0 = qi * bq
         q_end = min(q0 + bq, sq)
-        qs = min(q0, sq - bq)
+        qs = min(q0, sq_p - bq)
         # k-blocks with any visible column for the owned rows [q0, q_end)
         k_hi = min(ck, ceil_div(q_end, bk)) if causal else ck
         row = []
         for ki in range(k_hi):
             k0 = ki * bk
             row.append([q0, q_end, qs, k0, min(k0 + bk, sk),
-                        min(k0, sk - bk), 0, 0])
+                        min(k0, sk_p - bk), 0, 0])
         row[0][6] = 1
         row[-1][7] = 1
         tiles.extend(tuple(r) for r in row)
     return FlashTileSchedule(sq=sq, sk=sk, bq=bq, bk=bk, causal=causal,
-                             tiles=tuple(tiles))
+                             tiles=tuple(tiles), sq_p=sq_p, sk_p=sk_p,
+                             q_align=proven_align(t[2] for t in tiles),
+                             k_align=proven_align(t[5] for t in tiles))
 
 
 # ---------------------------------------------------------------------------
@@ -532,7 +755,8 @@ def flash_tile_schedule(sq: int, sk: int, bq: int, bk: int,
 # ---------------------------------------------------------------------------
 
 def clamped_k_window(ks, bk: int, k: int):
-    """Two-step K load: ``(k0, kstart)`` for K-panel ``ks``.
+    """Two-step K load: ``(k0, kstart)`` for K-panel ``ks`` over a staged
+    K extent ``k``.
 
     ``k0`` is the nominal panel start; ``kstart`` the clamped origin of
     the fixed-``bk`` window (the last panel slides inward instead of
@@ -543,12 +767,13 @@ def clamped_k_window(ks, bk: int, k: int):
     return k0, jnp.minimum(k0, k - bk)
 
 
-def k_tail_mask(x, axis: int, k0, kstart):
-    """Predicate the clamped-K overlap: keep only lanes at/after the
-    nominal panel start.  ``where`` (not multiply) because the overlap may
-    hold non-finite user data."""
+def k_tail_mask(x, axis: int, k0, kstart, k: int):
+    """Predicate a K window: keep only lanes in ``[k0, k)`` — at/after the
+    nominal panel start (the clamped overlap) and before the operand's
+    real K extent (the staged buffer's padding).  ``where`` (not
+    multiply) because both may hold non-finite data."""
     kk = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis) + kstart
-    return jnp.where(kk >= k0, x, 0)
+    return jnp.where((kk >= k0) & (kk < k), x, 0)
 
 
 def ownership_mask(shape: Tuple[int, int], rs, cs, row0, row_end,

@@ -32,9 +32,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import (DecodeTileSchedule, FlashTileSchedule,
+                                 flash_bwd_vmem_need, flash_vmem_need,
                                  ownership_mask, pack_table,
-                                 predicated_store)
-from repro.kernels.pallas_compat import CompilerParams
+                                 predicated_store, vmem_limit)
 
 NEG_INF = -1e30
 
@@ -115,7 +115,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def build_flash_kernel(*, batch_heads: int, sq: int, sk: int, d: int,
                        block_q: int = 512, block_k: int = 512,
                        causal: bool = True, dtype=jnp.bfloat16,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """Returns f(q:(BH,sq,d), k:(BH,sk,d), v:(BH,sk,d)) -> (BH,sq,d)."""
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -138,7 +138,7 @@ def build_flash_kernel(*, batch_heads: int, sq: int, sk: int, d: int,
             pltpu.VMEM((block_q, 1), jnp.float32),  # running denom
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -151,7 +151,7 @@ def build_flash_kernel(*, batch_heads: int, sq: int, sk: int, d: int,
 # ---------------------------------------------------------------------------
 
 def _fused_flash_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref,
-                        m_ref, l_ref, acc_ref, *, bq, bk, d, causal, scale,
+                        m_ref, l_ref, acc_ref, *, schedule, d, scale,
                         lse_ref=None):
     """Walk the flattened causal-aware tile table: one grid step = one
     active (q-block, k-block) pair.  q/k/v/out are staged whole per
@@ -159,9 +159,11 @@ def _fused_flash_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref,
     origins); the online-softmax carry lives in VMEM scratch, reset at
     ``first`` tiles and drained into the output — with a predicated
     two-step RMW store over the owned query rows — at ``last`` tiles."""
+    bq, bk, causal = schedule.bq, schedule.bk, schedule.causal
     t = pl.program_id(1)
-    q0, q_end, qs = tbl_ref[t, 0], tbl_ref[t, 1], tbl_ref[t, 2]
-    k0, k_end, ks = tbl_ref[t, 3], tbl_ref[t, 4], tbl_ref[t, 5]
+    q0, q_end, k0, k_end = (tbl_ref[t, 0], tbl_ref[t, 1], tbl_ref[t, 3],
+                            tbl_ref[t, 4])
+    qs, ks = _window_origins(tbl_ref, t, schedule)
 
     @pl.when(tbl_ref[t, 6] == 1)
     def _init():
@@ -169,7 +171,8 @@ def _fused_flash_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref,
 
     q = q_ref[0, pl.ds(qs, bq), :]  # (bq, d), two-step clamped window
     k = k_ref[0, pl.ds(ks, bk), :]  # (bk, d)
-    v = v_ref[0, pl.ds(ks, bk), :]
+    v = _rows_below(v_ref[0, pl.ds(ks, bk), :], ks, schedule.sk,
+                    schedule.sk_p)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     # Predicate the tile's contribution range [k0, k_end): the clamped
@@ -201,9 +204,26 @@ def _fused_flash_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref,
                              lse, own1)
 
 
+def _window_origins(tbl_ref, t, schedule):
+    """The tile's clamped q / k window origins, with the alignment the
+    schedule proves for them (Mosaic must prove it to slice VMEM)."""
+    return (pl.multiple_of(tbl_ref[t, 2], schedule.q_align),
+            pl.multiple_of(tbl_ref[t, 5], schedule.k_align))
+
+
+def _rows_below(x, origin, extent, staged):
+    """Zero the rows of a window at/after ``extent``: a window over a
+    padded staged buffer (``staged > extent``) reads block padding, which
+    may be non-finite and would leak through a zero probability."""
+    if staged == extent:
+        return x
+    rows = origin + jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+    return jnp.where(rows < extent, x, 0)
+
+
 def build_fused_flash_kernel(*, schedule: FlashTileSchedule,
                              batch_heads: int, d: int,
-                             dtype=jnp.bfloat16, interpret: bool = True,
+                             dtype=jnp.bfloat16, interpret: bool = False,
                              return_lse: bool = False):
     """Generate ONE pallas_call executing a whole flash tile schedule.
 
@@ -217,32 +237,32 @@ def build_fused_flash_kernel(*, schedule: FlashTileSchedule,
     (``(BH, sq)`` fp32) — the residual the backward walk recomputes P
     from (DESIGN.md §11); the forward math is bit-identical either way.
     """
-    sq, sk = schedule.sq, schedule.sk
-    bq, bk = schedule.bq, schedule.bk
+    sq = schedule.sq
+    bq = schedule.bq
     table = pack_table(schedule.tiles)  # (tiles, 8) int32, trace-time
+    # Slices are staged at the schedule's padded extents (the blocks
+    # overhang ragged sequences so clamped windows keep aligned origins).
+    spec_q = pl.BlockSpec((1, schedule.sq_p, d), lambda b, t, tbl: (b, 0, 0))
+    spec_k = pl.BlockSpec((1, schedule.sk_p, d), lambda b, t, tbl: (b, 0, 0))
 
-    opts = dict(bq=bq, bk=bk, d=d, causal=schedule.causal, scale=d ** -0.5)
+    opts = dict(schedule=schedule, d=d, scale=d ** -0.5)
     if return_lse:
         def body(tbl, q, k, v, o_ref, lse_ref, m_ref, l_ref, acc_ref):
             _fused_flash_kernel(tbl, q, k, v, o_ref, m_ref, l_ref, acc_ref,
                                 lse_ref=lse_ref, **opts)
         out_shape = [jax.ShapeDtypeStruct((batch_heads, sq, d), dtype),
                      jax.ShapeDtypeStruct((batch_heads, sq, 1), jnp.float32)]
-        out_specs = [pl.BlockSpec((1, sq, d), lambda b, t, tbl: (b, 0, 0)),
-                     pl.BlockSpec((1, sq, 1), lambda b, t, tbl: (b, 0, 0))]
+        out_specs = [spec_q, pl.BlockSpec((1, schedule.sq_p, 1),
+                                          lambda b, t, tbl: (b, 0, 0))]
     else:
         body = functools.partial(_fused_flash_kernel, **opts)
         out_shape = jax.ShapeDtypeStruct((batch_heads, sq, d), dtype)
-        out_specs = pl.BlockSpec((1, sq, d), lambda b, t, tbl: (b, 0, 0))
+        out_specs = spec_q
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # the tile table
         grid=(batch_heads, schedule.num_tiles),
-        in_specs=[
-            pl.BlockSpec((1, sq, d), lambda b, t, tbl: (b, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, t, tbl: (b, 0, 0)),
-            pl.BlockSpec((1, sk, d), lambda b, t, tbl: (b, 0, 0)),
-        ],
+        in_specs=[spec_q, spec_k, spec_k],
         out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),  # running max
@@ -255,10 +275,14 @@ def build_fused_flash_kernel(*, schedule: FlashTileSchedule,
         body,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # batch x heads parallel; the tile walk is the sequential
             # carry dimension (the online-softmax state threads it)
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(flash_vmem_need(
+                schedule.sq_p, schedule.sk_p, d,
+                isz=jnp.dtype(dtype).itemsize, bq=bq, bk=schedule.bk,
+                lse=return_lse)),
         ),
         interpret=interpret,
     )
@@ -278,8 +302,8 @@ def build_fused_flash_kernel(*, schedule: FlashTileSchedule,
 # pulled from the pool by a table-driven BlockSpec index map
 # ---------------------------------------------------------------------------
 
-def _decode_flash_kernel(tbl_ref, *refs, page_size, rep, scale,
-                         kv_quant=False):
+def _decode_flash_kernel(tbl_ref, *refs, page_size, num_kv_heads, rep,
+                         scale, kv_quant=False):
     """One grid step of the paged decode walk.
 
     ``tbl_ref`` rows are ``(seq, page, k_len, first, last)``
@@ -290,12 +314,21 @@ def _decode_flash_kernel(tbl_ref, *refs, page_size, rep, scale,
     output row at ``last`` — the same m/l/acc discipline as the fused
     flash walk, batched over heads instead of query rows.
 
+    The page is contracted as one ``(P * hkv, hd)`` matrix: column
+    ``c`` of the ``(h, P * hkv)`` score tile is position ``c // hkv`` of
+    KV head ``c % hkv``, and each query head keeps only its own KV
+    head's columns (GQA by mask).  Both contractions are then plain 2-D
+    GEMMs, which is what Mosaic lowers.  The other heads' columns are
+    ``hkv``x the score work, but one such step is a single MXU pass: on a
+    v5e at 8/16 heads of 128 a static loop of per-KV-head dots took 1.7x
+    as long per decode call, and both were far from the page-read bound.
+
     ``kv_quant`` (DESIGN.md §13): the pools are int8 with per-token f32
-    scale rows riding as two extra ``(1, P)`` operands on the same
-    table-driven index map.  The scales are *separable by page position*,
-    so dequant never touches the (P, hkv, hd) tiles: the K scales
-    multiply the score columns (``q . (k*s) == (q . k) * s``) and the V
-    scales fold into P before the PV contraction
+    scale rows riding as two extra ``(1, P * hkv)`` operands (expanded
+    per column) on the same table-driven index map.  The scales are
+    *separable by page position*, so dequant never touches the KV tiles:
+    the K scales multiply the score columns (``q . (k*s) == (q . k) * s``)
+    and the V scales fold into P before the PV contraction
     (``sum_p p . (v*s) == sum_p (p*s) . v``) — both lane-dim row
     broadcasts, no 3-D elementwise dequant."""
     idx = 0
@@ -311,32 +344,32 @@ def _decode_flash_kernel(tbl_ref, *refs, page_size, rep, scale,
 
     t = pl.program_id(0)
     k_len = tbl_ref[t, 2]
+    cols = page_size * num_kv_heads
 
     @pl.when(tbl_ref[t, 3] == 1)
     def _init():
         _carry_init(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0]                       # (h, hd)
-    k = k_ref[0].astype(q.dtype)       # (page_size, hkv, hd) — int8 wire
-    v = v_ref[0].astype(q.dtype)       # values are exact in the wide dtype
-    if rep > 1:
-        k = jnp.repeat(k, rep, axis=1)  # GQA: -> (page_size, h, hd)
-        v = jnp.repeat(v, rep, axis=1)
+    q = q_ref[0]                                       # (h, hd)
+    # int8 wire values are exact in the wide dtype.
+    k = k_ref[0].astype(q.dtype).reshape(cols, -1)     # (P * hkv, hd)
+    v = v_ref[0].astype(q.dtype).reshape(cols, -1)
     # Dead page slots may hold stale sequences' values — `where`, never
     # multiply (§IV-B); zeroed v also keeps a fully-masked (empty-slot)
     # tile draining exact zeros.
-    col = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1, 1), 0)
-    v = jnp.where(col < k_len, v, 0)
-    # scores (h, page_size): heads are the batch dim of both tile GEMMs.
-    s = jax.lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
+    pos = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0) // num_kv_heads
+    v = jnp.where(pos < k_len, v, 0)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if kv_quant:
-        s = s * ks_ref[...].astype(jnp.float32)  # (1, P) over (h, P)
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(cols < k_len, s, NEG_INF)
+        s = s * ks_ref[...]  # (1, P * hkv) over (h, P * hkv)
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    live = (col // num_kv_heads < k_len) & (col % num_kv_heads == head // rep)
+    s = jnp.where(live, s, NEG_INF)
 
     # Per-head online-softmax update — the m/l algebra of
-    # `_online_softmax_update` with the PV contraction batched over heads.
+    # `_online_softmax_update`, with the V scales folded into P.
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -344,9 +377,9 @@ def _decode_flash_kernel(tbl_ref, *refs, page_size, rep, scale,
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
     pv = p
     if kv_quant:
-        pv = p * vs_ref[...].astype(jnp.float32)
+        pv = p * vs_ref[...]
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pv.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
+        pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
@@ -359,7 +392,7 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
                               num_heads: int, num_kv_heads: int,
                               head_dim: int, dtype=jnp.bfloat16,
                               kv_dtype=None, kv_quant: bool = False,
-                              interpret: bool = True):
+                              interpret: bool = False):
     """Generate ONE pallas_call executing a whole paged decode step.
 
     Returns ``f(table, q:(S,h,hd), k_pool:(pages,P,hkv,hd), v_pool) ->
@@ -375,8 +408,8 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
     h, hkv, hd = num_heads, num_kv_heads, head_dim
     kv_dtype = kv_dtype or dtype
     body = functools.partial(_decode_flash_kernel, page_size=P,
-                             rep=h // hkv, scale=hd ** -0.5,
-                             kv_quant=kv_quant)
+                             num_kv_heads=hkv, rep=h // hkv,
+                             scale=hd ** -0.5, kv_quant=kv_quant)
 
     in_specs = [
         pl.BlockSpec((1, h, hd), lambda t, tbl: (tbl[t, 0], 0, 0)),
@@ -386,10 +419,11 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
                      lambda t, tbl: (tbl[t, 1], 0, 0, 0)),
     ]
     if kv_quant:
-        # per-token dequant scale rows of the walked page (DESIGN.md §13)
+        # per-token dequant scales of the walked page, one per score
+        # column (DESIGN.md §13)
         in_specs += [
-            pl.BlockSpec((1, P), lambda t, tbl: (tbl[t, 1], 0)),
-            pl.BlockSpec((1, P), lambda t, tbl: (tbl[t, 1], 0)),
+            pl.BlockSpec((1, P * hkv), lambda t, tbl: (tbl[t, 1], 0)),
+            pl.BlockSpec((1, P * hkv), lambda t, tbl: (tbl[t, 1], 0)),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -408,7 +442,7 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, h, hd), dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # one sequential dimension: the carry threads the page walk
             dimension_semantics=("arbitrary",),
         ),
@@ -424,25 +458,32 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
 
 def _fused_flash_bwd_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
                             lse_ref, dq_ref, dk_ref, dv_ref,
-                            d_ref, dqacc_ref, *, bq, bk, d, causal, scale):
+                            d_ref, dqacc_ref, *, schedule, d, scale):
     """One grid step = one active (q-block, k-block) pair of the forward
     schedule.  P is recomputed from the staged LSE rows (no second online
     reduction); dK/dV accumulate fp32 across q-blocks by read-modify-write
     on the whole-staged outputs (contributions outside a tile's owned
     rows/cols are masked to zero, so clamped-window overlap adds zero);
     dQ accumulates in scratch across a q-block's k walk and drains with a
-    predicated store at ``last`` tiles."""
+    predicated store at ``last`` tiles.  Windows over padded staged
+    buffers zero their padding rows: a non-finite pad times a zero
+    probability would otherwise leak into the RMW-accumulated dK/dV."""
+    bq, bk, causal = schedule.bq, schedule.bk, schedule.causal
+    sq, sk, sq_p, sk_p = schedule.sq, schedule.sk, schedule.sq_p, schedule.sk_p
     t = pl.program_id(1)
-    q0, q_end, qs = tbl_ref[t, 0], tbl_ref[t, 1], tbl_ref[t, 2]
-    k0, k_end, ks = tbl_ref[t, 3], tbl_ref[t, 4], tbl_ref[t, 5]
+    q0, q_end, k0, k_end = (tbl_ref[t, 0], tbl_ref[t, 1], tbl_ref[t, 3],
+                            tbl_ref[t, 4])
+    qs, ks = _window_origins(tbl_ref, t, schedule)
 
     @pl.when(t == 0)
     def _zero_outputs():
         dk_ref[...] = jnp.zeros_like(dk_ref)
         dv_ref[...] = jnp.zeros_like(dv_ref)
 
-    o_win = o_ref[0, pl.ds(qs, bq), :].astype(jnp.float32)
-    do_win = do_ref[0, pl.ds(qs, bq), :].astype(jnp.float32)
+    o_win = _rows_below(o_ref[0, pl.ds(qs, bq), :].astype(jnp.float32),
+                        qs, sq, sq_p)
+    do_win = _rows_below(do_ref[0, pl.ds(qs, bq), :].astype(jnp.float32),
+                         qs, sq, sq_p)
 
     @pl.when(tbl_ref[t, 6] == 1)
     def _init():
@@ -451,10 +492,10 @@ def _fused_flash_bwd_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
         d_ref[...] = jnp.sum(do_win * o_win, axis=1, keepdims=True)
         dqacc_ref[...] = jnp.zeros_like(dqacc_ref)
 
-    q = q_ref[0, pl.ds(qs, bq), :]
-    k = k_ref[0, pl.ds(ks, bk), :]
-    v = v_ref[0, pl.ds(ks, bk), :]
-    lse = lse_ref[0, pl.ds(qs, bq), :]  # (bq, 1) fp32
+    q = _rows_below(q_ref[0, pl.ds(qs, bq), :], qs, sq, sq_p)
+    k = _rows_below(k_ref[0, pl.ds(ks, bk), :], ks, sk, sk_p)
+    v = _rows_below(v_ref[0, pl.ds(ks, bk), :], ks, sk, sk_p)
+    lse = _rows_below(lse_ref[0, pl.ds(qs, bq), :], qs, sq, sq_p)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -499,7 +540,7 @@ def _fused_flash_bwd_kernel(tbl_ref, q_ref, k_ref, v_ref, o_ref, do_ref,
 
 def build_fused_flash_bwd_kernel(*, schedule: FlashTileSchedule,
                                  batch_heads: int, d: int,
-                                 dtype=jnp.bfloat16, interpret: bool = True):
+                                 dtype=jnp.bfloat16, interpret: bool = False):
     """Generate ONE pallas_call executing a whole flash backward schedule.
 
     Returns ``f(q, k, v, o, do, lse) -> (dq, dk, dv)`` over ``(BH, s, d)``
@@ -510,20 +551,20 @@ def build_fused_flash_bwd_kernel(*, schedule: FlashTileSchedule,
     (DESIGN.md §11).
     """
     sq, sk = schedule.sq, schedule.sk
-    bq, bk = schedule.bq, schedule.bk
+    bq = schedule.bq
     table = pack_table(schedule.tiles)
 
-    body = functools.partial(
-        _fused_flash_bwd_kernel, bq=bq, bk=bk, d=d, causal=schedule.causal,
-        scale=d ** -0.5)
+    body = functools.partial(_fused_flash_bwd_kernel, schedule=schedule,
+                             d=d, scale=d ** -0.5)
 
-    spec_q = pl.BlockSpec((1, sq, d), lambda b, t, tbl: (b, 0, 0))
-    spec_k = pl.BlockSpec((1, sk, d), lambda b, t, tbl: (b, 0, 0))
+    spec_q = pl.BlockSpec((1, schedule.sq_p, d), lambda b, t, tbl: (b, 0, 0))
+    spec_k = pl.BlockSpec((1, schedule.sk_p, d), lambda b, t, tbl: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(batch_heads, schedule.num_tiles),
         in_specs=[spec_q, spec_k, spec_k, spec_q, spec_q,
-                  pl.BlockSpec((1, sq, 1), lambda b, t, tbl: (b, 0, 0))],
+                  pl.BlockSpec((1, schedule.sq_p, 1),
+                               lambda b, t, tbl: (b, 0, 0))],
         out_specs=[spec_q, spec_k, spec_k],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),  # D = rowsum(dO . O)
@@ -539,8 +580,11 @@ def build_fused_flash_bwd_kernel(*, schedule: FlashTileSchedule,
             jax.ShapeDtypeStruct((batch_heads, sk, d), jnp.float32),
             jax.ShapeDtypeStruct((batch_heads, sk, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(flash_bwd_vmem_need(
+                schedule.sq_p, schedule.sk_p, d,
+                isz=jnp.dtype(dtype).itemsize, bq=bq, bk=schedule.bk)),
         ),
         interpret=interpret,
     )

@@ -32,7 +32,7 @@ from repro.core import engine
 from repro.core.blocking import (FlashDecodePlan, FlashPlan,
                                  flash_bwd_fused_legal, plan_flash,
                                  plan_flash_bwd, plan_flash_decode)
-from repro.core.config import get_config
+from repro.core.config import get_config, resolve_interpret
 from repro.core.descriptor import (FlashBwdDescriptor, FlashDecodeDescriptor,
                                    FlashDescriptor)
 from repro.core.machine import canonical_dtype
@@ -60,7 +60,8 @@ def execute(desc: FlashDescriptor, plan: FlashPlan, qf, kf, vf, *,
             interpret: bool = False) -> jax.Array:
     """Engine executor: run one planned flash attention forward."""
     fused = engine.resolve_fused(plan)
-    engine.count_launches("flash_attention", plan_launches(plan, fused))
+    engine.count_launches("flash_attention", plan_launches(plan, fused),
+                          fused=fused)
     if fused:
         return _fused_executor(desc, plan, qf.dtype, interpret)(qf, kf, vf)
     key = desc.cache_key() + ("kernel", plan.block_q, plan.block_k, interpret)
@@ -115,7 +116,7 @@ def execute_decode(desc: FlashDecodeDescriptor, plan: FlashDecodePlan,
     KV-int8 pools (DESIGN.md §13) ride the same launch: per-token scale
     rows ``(pages, page_size)`` join as two extra table-indexed operands.
     """
-    engine.count_launches("flash_decode", 1)
+    engine.count_launches("flash_decode", 1, fused=True)
     kv_quant = k_scale is not None
     schedule = plan.tile_schedule()
     key = desc.cache_key() + ("decode", canonical_dtype(k_pool.dtype),
@@ -127,9 +128,12 @@ def execute_decode(desc: FlashDecodeDescriptor, plan: FlashDecodePlan,
         interpret=interpret))
     table = schedule.tables(block_tables, lengths)
     if kv_quant:
-        return kernel(table, q, k_pool, v_pool,
-                      k_scale.astype(jnp.float32),
-                      v_scale.astype(jnp.float32))
+        # One scale per score column (position-major, KV head minor).
+        def per_column(sc):
+            return jnp.repeat(sc.astype(jnp.float32), desc.num_kv_heads,
+                              axis=1)
+        return kernel(table, q, k_pool, v_pool, per_column(k_scale),
+                      per_column(v_scale))
     return kernel(table, q, k_pool, v_pool)
 
 
@@ -197,7 +201,7 @@ def _flash_vjp_fwd(causal, qf, kf, vf):
     desc = _flat_desc(causal, qf, kf)
     bdesc = FlashBwdDescriptor.from_forward(desc)
     fused_ok = (cfg.fused != "off"
-                and flash_bwd_fused_legal(bdesc, cfg.machine))
+                and flash_bwd_fused_legal(bdesc, cfg.machine_model))
     if fused_ok:
         plan = engine.plan_for(desc)
         fused_ok = engine.resolve_fused(plan)
@@ -207,13 +211,13 @@ def _flash_vjp_fwd(causal, qf, kf, vf):
         return _flash_dispatch(causal, qf, kf, vf), {"ref": (qf, kf, vf)}
     # Forward with the LSE rows drained for the backward walk — same
     # schedule, same online-softmax math as the primal fused kernel.
-    interpret = cfg.interpret
+    interpret = resolve_interpret(cfg.interpret)
     key = desc.cache_key() + ("fused_lse", plan.block_q, plan.block_k,
                               interpret)
     kernel = engine.build_cached(key, lambda: build_fused_flash_kernel(
         schedule=plan.tile_schedule(), batch_heads=desc.batch_heads,
         d=desc.d, dtype=qf.dtype, interpret=interpret, return_lse=True))
-    engine.count_launches("flash_attention", 1)
+    engine.count_launches("flash_attention", 1, fused=True)
     o, lse = kernel(qf, kf, vf)
     return o, {"fused": (qf, kf, vf, o, lse)}
 

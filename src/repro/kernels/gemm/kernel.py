@@ -32,11 +32,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.schedule import (clamped_k_window, k_tail_mask,
+from repro.core.schedule import (k_tail_mask, matmul_vmem_need,
                                  ownership_mask, pack_table,
-                                 predicated_store)
+                                 predicated_store, vmem_limit)
 from repro.kernels.epilogue import apply_epilogue, needs_bias
-from repro.kernels.pallas_compat import CompilerParams
 
 
 def _gemm_kernel_body(*refs, layout, k_steps, k_rem, bk, epilogue,
@@ -96,7 +95,7 @@ def _gemm_kernel_body(*refs, layout, k_steps, k_rem, bk, epilogue,
 def build_gemm_kernel(*, m: int, n: int, k: int, bm: int, bn: int, bk: int,
                       layout: str = "nn", epilogue: Optional[str] = None,
                       accumulate: bool = False, in_dtype=jnp.float32,
-                      out_dtype=jnp.float32, interpret: bool = True):
+                      out_dtype=jnp.float32, interpret: bool = False):
     """Generate the shape-specialized pallas_call for one GEMM region.
 
     Returns a function ``f(a, b, [bias], [c_in]) -> out`` of exact shapes
@@ -128,7 +127,7 @@ def build_gemm_kernel(*, m: int, n: int, k: int, bm: int, bn: int, bk: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -151,8 +150,8 @@ def build_gemm_kernel(*, m: int, n: int, k: int, bm: int, bn: int, bk: int,
 # Fused single-launch plan execution (DESIGN.md §8/§9)
 # ---------------------------------------------------------------------------
 
-def _fused_kernel_body(tbl_ref, *refs, blocks, layout, k, bk, k_steps,
-                       epilogue, accumulate, out_dtype, quant=None):
+def _fused_kernel_body(tbl_ref, *refs, schedule, layout, epilogue,
+                       accumulate, out_dtype, quant=None):
     """Walk the flattened tile schedule: one grid step = one (tile, K-panel).
 
     refs: a, b, [sa], [sb], [bias], [c_in], out, acc_scratch — each a full
@@ -193,13 +192,18 @@ def _fused_kernel_body(tbl_ref, *refs, blocks, layout, k, bk, k_steps,
     o_ref = refs[idx]; idx += 1
     acc_ref = refs[idx]
 
+    k, bk, k_steps = schedule.k, schedule.bk, schedule.k_steps
     t = pl.program_id(1)
     ks = pl.program_id(2)
     row0, col0 = tbl_ref[t, 0], tbl_ref[t, 1]
     row_end, col_end = tbl_ref[t, 2], tbl_ref[t, 3]
-    rs, cs = tbl_ref[t, 4], tbl_ref[t, 5]
+    # Window origins come from SMEM; the schedule proves their alignment,
+    # which Mosaic needs to lower the sliced loads and stores.
+    rs = pl.multiple_of(tbl_ref[t, 4], schedule.row_align)
+    cs = pl.multiple_of(tbl_ref[t, 5], schedule.col_align)
 
-    k0, kstart = clamped_k_window(ks, bk, k)  # two-step K load (tail)
+    k0, kstart = schedule.k_window(ks)  # two-step K load (tail)
+    kstart = pl.multiple_of(kstart, schedule.k_align)
 
     def make_branch(bm_e, bn_e):
         def branch():
@@ -226,11 +230,11 @@ def _fused_kernel_body(tbl_ref, *refs, blocks, layout, k, bk, k_steps,
                 # the wide dtype; the column scales stay in the epilogue.
                 b = b.astype(a.dtype)
             if k % bk:
-                # K-tail predication: the clamped window overlaps the
-                # previous panel; keep only lanes at/after the nominal
-                # start (repro.core.schedule.k_tail_mask).
-                a = k_tail_mask(a, 1, k0, kstart)
-                b = k_tail_mask(b, b_k_dim, k0, kstart)
+                # K-tail predication: the last window overlaps the
+                # previous panel or the staged padding; keep only lanes
+                # in [k0, k) (repro.core.schedule.k_tail_mask).
+                a = k_tail_mask(a, 1, k0, kstart, k)
+                b = k_tail_mask(b, b_k_dim, k0, kstart, k)
             acc_ref[0:bm_e, 0:bn_e] += jax.lax.dot_general(
                 a, b, dn, preferred_element_type=acc_dt)
 
@@ -256,7 +260,7 @@ def _fused_kernel_body(tbl_ref, *refs, blocks, layout, k, bk, k_steps,
                     o_ref, (0, pl.ds(rs, bm_e), pl.ds(cs, bn_e)), out, own)
         return branch
 
-    branches = [make_branch(bm_e, bn_e) for bm_e, bn_e in blocks]
+    branches = [make_branch(bm_e, bn_e) for bm_e, bn_e in schedule.blocks]
     if len(branches) == 1:
         branches[0]()
     else:
@@ -266,7 +270,7 @@ def _fused_kernel_body(tbl_ref, *refs, blocks, layout, k, bk, k_steps,
 def build_fused_gemm_kernel(*, schedule, batch: int = 0, layout: str = "nn",
                             epilogue: Optional[str] = None,
                             accumulate: bool = False, in_dtype=jnp.float32,
-                            out_dtype=jnp.float32, interpret: bool = True,
+                            out_dtype=jnp.float32, interpret: bool = False,
                             quant=None):
     """Generate ONE pallas_call executing a whole blocking plan + batch.
 
@@ -284,8 +288,10 @@ def build_fused_gemm_kernel(*, schedule, batch: int = 0, layout: str = "nn",
     runs only) and ``sb: (1, n)`` column scales — fused into the epilogue
     (DESIGN.md §13).
     """
-    m, n, k = schedule.m, schedule.n, schedule.k
-    bk, k_steps = schedule.bk, schedule.k_steps
+    m, n = schedule.m, schedule.n
+    # Operands are staged at the schedule's padded extents: the blocks
+    # overhang ragged operands so clamped windows keep aligned origins.
+    m_p, n_p, k_p = schedule.m_p, schedule.n_p, schedule.k_p
     nb = max(1, batch)
     has_bias = needs_bias(epilogue)
     has_sa = quant is not None and not quant.weight_only
@@ -296,42 +302,59 @@ def build_fused_gemm_kernel(*, schedule, batch: int = 0, layout: str = "nn",
     table = pack_table(schedule.tiles)  # (tiles, 8) int32, trace-time
 
     body = functools.partial(
-        _fused_kernel_body, blocks=schedule.blocks, layout=layout, k=k,
-        bk=bk, k_steps=k_steps, epilogue=epilogue, accumulate=accumulate,
+        _fused_kernel_body, schedule=schedule, layout=layout,
+        epilogue=epilogue, accumulate=accumulate,
         out_dtype=jnp.dtype(out_dtype), quant=quant)
 
-    in_specs = [
-        pl.BlockSpec((1, m, k), lambda b, t, ks, tbl: (b, 0, 0)),
-        pl.BlockSpec((1, k, n) if layout == "nn" else (1, n, k),
-                     lambda b, t, ks, tbl: (b, 0, 0)),
-    ]
-    if has_sa:
-        in_specs.append(pl.BlockSpec((m, 1), lambda b, t, ks, tbl: (0, 0)))
-    if has_sb:
-        in_specs.append(pl.BlockSpec((1, n), lambda b, t, ks, tbl: (0, 0)))
-    if has_bias:
-        in_specs.append(pl.BlockSpec((1, n), lambda b, t, ks, tbl: (0, 0)))
-    if accumulate:
-        in_specs.append(pl.BlockSpec((1, m, n),
-                                     lambda b, t, ks, tbl: (b, 0, 0)))
+    def whole(*shape):
+        return pl.BlockSpec(shape, lambda b, t, ks, tbl: (0,) * len(shape))
 
+    def per_batch(*shape):
+        return pl.BlockSpec((1,) + shape,
+                            lambda b, t, ks, tbl: (b,) + (0,) * len(shape))
+
+    in_specs = [per_batch(m_p, k_p),
+                per_batch(k_p, n_p) if layout == "nn" else per_batch(n_p, k_p)]
+    if has_sa:
+        in_specs.append(whole(m_p, 1))
+    if has_sb:
+        in_specs.append(whole(1, n_p))
+    if has_bias:
+        in_specs.append(whole(1, n_p))
+    if accumulate:
+        in_specs.append(per_batch(m_p, n_p))
+
+    acc_dtype = jnp.int32 if int_acc else jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # the tile table
-        grid=(nb, schedule.num_tiles, k_steps),
+        grid=(nb, schedule.num_tiles, schedule.k_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, m, n), lambda b, t, ks, tbl: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((bm_max, bn_max),
-                                   jnp.int32 if int_acc else jnp.float32)],
+        out_specs=per_batch(m_p, n_p),
+        scratch_shapes=[pltpu.VMEM((bm_max, bn_max), acc_dtype)],
     )
+    in_isz = jnp.dtype(in_dtype).itemsize
+    need = matmul_vmem_need(
+        m_p, n_p, k_p, a_isz=quant.wire_itemsize if has_sa else in_isz,
+        b_isz=quant.wire_itemsize if has_sb else in_isz,
+        out_isz=jnp.dtype(out_dtype).itemsize, acc=(bm_max, bn_max),
+        layout=layout, accumulate=accumulate, row_scales=has_sa,
+        col_rows=int(has_sb) + int(has_bias))
 
     kernel = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, m, n), jnp.dtype(out_dtype)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(need)),
         interpret=interpret,
     )
 
     def run(a, b, bias=None, c_in=None, sa=None, sb=None):
+        if m < 8:
+            # XLA stores an array of fewer than 8 rows in narrower tiles,
+            # whose packed values Mosaic cannot mask (the K tail): stage
+            # A as whole register tiles instead.
+            a = jnp.pad(a, ((0, 0), (0, m_p - m), (0, 0)))
         args = [table, a, b]
         if has_sa:
             assert sa is not None

@@ -103,8 +103,8 @@ def gemm_region(a, b, region, desc: GemmDescriptor, bk: int,
                 interpret: Optional[bool] = None):
     """Run one region's microkernel on the corresponding operand slices."""
     if interpret is None:
-        from repro.core.config import get_config
-        interpret = get_config().interpret
+        from repro.core.config import get_config, resolve_interpret
+        interpret = resolve_interpret(get_config().interpret)
     r = region
     a_r = jax.lax.dynamic_slice(a, (r.row0, 0), (r.rows, desc.k))
     if desc.layout == "nn":
@@ -198,7 +198,8 @@ def execute(desc: GemmDescriptor, plan: BlockingPlan, a, b, *,
     check_bias(desc.epilogue, bias)
     if desc.quant is not None:
         if engine.resolve_fused(plan):
-            engine.count_launches("gemm", plan_launches(plan, fused=True))
+            engine.count_launches("gemm", plan_launches(plan, fused=True),
+                                  fused=True)
             run = _fused_executor(desc, plan, interpret)
             return run(a[None], b[None], bias, None, sa=sa, sb=sb)[0]
         # The pre-quant path: no pallas_call at all — quantized operands,
@@ -206,7 +207,8 @@ def execute(desc: GemmDescriptor, plan: BlockingPlan, a, b, *,
         engine.count_launches("gemm", 0)
         return _xla_quant_gemm(desc, a, b, bias, sa, sb)
     if engine.resolve_fused(plan):
-        engine.count_launches("gemm", plan_launches(plan, fused=True))
+        engine.count_launches("gemm", plan_launches(plan, fused=True),
+                              fused=True)
         run = _fused_executor(desc, plan, interpret)
         if desc.batch:
             out = run(a, b, bias, c)

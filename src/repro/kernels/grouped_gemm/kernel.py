@@ -38,8 +38,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import (TILE_COMPUTE, TILE_ZERO, GroupedTileSchedule,
-                                 clamped_k_window, k_tail_mask,
-                                 ownership_mask, predicated_store)
+                                 clamped_k_window, grouped_bwd_vmem_need,
+                                 k_tail_mask, matmul_vmem_need,
+                                 ownership_mask, predicated_store,
+                                 vmem_limit)
 from repro.kernels.epilogue import apply_epilogue, needs_bias
 
 
@@ -47,8 +49,20 @@ from repro.kernels.epilogue import apply_epilogue, needs_bias
 # Fused scheduled lowering (DESIGN.md §9): one launch, no pad, no gather
 # ---------------------------------------------------------------------------
 
-def _fused_grouped_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn, k_steps,
-                          epilogue, out_dtype, quant=None):
+def _window(tbl_ref, g, j, ks, schedule):
+    """Tile ``g``'s row origin and the (N, K) window origins of grid step
+    ``(j, ks)``, each with the alignment the schedule proves (Mosaic must
+    prove it to slice VMEM).  Returns ``(rs, col0, cs, k0, kstart)``."""
+    sch = schedule
+    rs = pl.multiple_of(tbl_ref[g, 2], sch.row_origin_align)
+    col0 = j * sch.bn                       # nominal N-block start
+    cs = pl.multiple_of(jnp.minimum(col0, sch.n_p - sch.bn), sch.col_align)
+    k0, kstart = clamped_k_window(ks, sch.bk, sch.k_p)
+    return rs, col0, cs, k0, pl.multiple_of(kstart, sch.k_align)
+
+
+def _fused_grouped_kernel(tbl_ref, *refs, schedule, epilogue, out_dtype,
+                          quant=None):
     """Walk the ragged tile table: one grid step = one (row-block, N-block,
     K-panel).  refs: x, w, [sx], [sw], [bias], out, acc_scratch — x/out
     staged whole (clamped row windows need element-granular origins),
@@ -79,16 +93,16 @@ def _fused_grouped_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn, k_steps,
     o_ref = refs[idx]; idx += 1
     acc_ref = refs[idx]
 
+    kdim, n, bm, bk, bn = (schedule.k, schedule.n, schedule.bm, schedule.bk,
+                           schedule.bn)
+    k_steps = schedule.k_steps
     g = pl.program_id(0)
     j = pl.program_id(1)
     ks = pl.program_id(2)
-    row0, row_end, rs = tbl_ref[g, 0], tbl_ref[g, 1], tbl_ref[g, 2]
+    row0, row_end = tbl_ref[g, 0], tbl_ref[g, 1]
     state = tbl_ref[g, 4]
-
-    col0 = j * bn                       # nominal N-block start (ownership)
-    cs = jnp.minimum(col0, n - bn)      # clamped window origin (N tail)
+    rs, col0, cs, k0, kstart = _window(tbl_ref, g, j, ks, schedule)
     col_end = jnp.minimum(col0 + bn, n)
-    k0, kstart = clamped_k_window(ks, bk, kdim)
 
     @pl.when(state == TILE_COMPUTE)
     def _compute():
@@ -102,9 +116,9 @@ def _fused_grouped_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn, k_steps,
             # int8 weight values are exact in the wide dtype; the column
             # scales stay in the epilogue.
             b = b.astype(a.dtype)
-        if kdim % bk:  # K-tail predication on the clamped-window overlap
-            a = k_tail_mask(a, 1, k0, kstart)
-            b = k_tail_mask(b, 0, k0, kstart)
+        if kdim % bk:  # K-tail predication: clamped overlap + padding
+            a = k_tail_mask(a, 1, k0, kstart, kdim)
+            b = k_tail_mask(b, 0, k0, kstart, kdim)
         acc_ref[...] += jax.lax.dot_general(
             a, b, (((1,), (0,)), ((), ())),
             preferred_element_type=acc_dt)
@@ -138,7 +152,7 @@ def _fused_grouped_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn, k_steps,
 def build_fused_grouped_kernel(*, schedule: GroupedTileSchedule,
                                epilogue: Optional[str] = None,
                                in_dtype=jnp.float32, out_dtype=jnp.float32,
-                               interpret: bool = True, quant=None):
+                               interpret: bool = False, quant=None):
     """Generate ONE pallas_call executing a whole ragged grouped dispatch.
 
     Returns ``f(table, x, w, [bias], sx=None, sw=None) -> (T, N)`` where
@@ -153,49 +167,59 @@ def build_fused_grouped_kernel(*, schedule: GroupedTileSchedule,
     ``sw`` per-expert dense columns ``(E, N)`` whose owning row the tile
     table's expert column selects — same index map as the weight panel.
     """
-    t, kdim, n = schedule.t, schedule.k, schedule.n
-    bm, bk, bn = schedule.bm, schedule.bk, schedule.bn
+    t, n = schedule.t, schedule.n
+    # Staged at the schedule's padded extents: the blocks overhang ragged
+    # operands so clamped windows keep aligned origins.
+    t_p, k_p, n_p = schedule.t_p, schedule.k_p, schedule.n_p
+    bm, bn = schedule.bm, schedule.bn
     has_bias = needs_bias(epilogue)
     has_sx = quant is not None and not quant.weight_only
     has_sw = quant is not None
     int_acc = has_sx and quant.dtype == "int8"
 
     body = functools.partial(
-        _fused_grouped_kernel, kdim=kdim, n=n, bm=bm, bk=bk, bn=bn,
-        k_steps=schedule.k_steps, epilogue=epilogue,
+        _fused_grouped_kernel, schedule=schedule, epilogue=epilogue,
         out_dtype=jnp.dtype(out_dtype), quant=quant)
 
     in_specs = [
-        pl.BlockSpec((t, kdim), lambda g, j, ks, tbl: (0, 0)),
+        pl.BlockSpec((t_p, k_p), lambda g, j, ks, tbl: (0, 0)),
         # the whole weight panel of the expert owning row-block g
-        pl.BlockSpec((1, kdim, n), lambda g, j, ks, tbl: (tbl[g, 3], 0, 0)),
+        pl.BlockSpec((1, k_p, n_p), lambda g, j, ks, tbl: (tbl[g, 3], 0, 0)),
     ]
     if has_sx:
         # per-row activation scales, whole-staged like x (clamped row
         # windows need element-granular origins)
         in_specs.append(
-            pl.BlockSpec((t, 1), lambda g, j, ks, tbl: (0, 0)))
+            pl.BlockSpec((t_p, 1), lambda g, j, ks, tbl: (0, 0)))
     if has_sw:
         # the scale row of the expert owning row-block g
         in_specs.append(
-            pl.BlockSpec((1, n), lambda g, j, ks, tbl: (tbl[g, 3], 0)))
+            pl.BlockSpec((1, n_p), lambda g, j, ks, tbl: (tbl[g, 3], 0)))
     if has_bias:
         in_specs.append(
-            pl.BlockSpec((1, n), lambda g, j, ks, tbl: (tbl[g, 3], 0)))
+            pl.BlockSpec((1, n_p), lambda g, j, ks, tbl: (tbl[g, 3], 0)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,  # the tile table
         grid=(schedule.max_tiles, schedule.n_steps, schedule.k_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((t, n), lambda g, j, ks, tbl: (0, 0)),
+        out_specs=pl.BlockSpec((t_p, n_p), lambda g, j, ks, tbl: (0, 0)),
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.int32 if int_acc else jnp.float32)],
     )
+    isz = jnp.dtype(in_dtype).itemsize  # x arrives in its wire dtype
+    need = matmul_vmem_need(
+        t_p, n_p, k_p, a_isz=isz,
+        b_isz=quant.wire_itemsize if has_sw else isz,
+        out_isz=jnp.dtype(out_dtype).itemsize, acc=(bm, bn),
+        row_scales=has_sx, col_rows=int(has_sw) + int(has_bias))
 
     kernel = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, n), jnp.dtype(out_dtype)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(need)),
         interpret=interpret,
     )
 
@@ -222,8 +246,7 @@ def build_fused_grouped_kernel(*, schedule: GroupedTileSchedule,
 # touches the pad/scatter path
 # ---------------------------------------------------------------------------
 
-def _fused_grouped_bwd_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn,
-                              k_steps, n_steps, with_db):
+def _fused_grouped_bwd_kernel(tbl_ref, *refs, schedule, with_db):
     """Walk the ragged tile table with the grid reordered to
     ``(row-block, K-panel, N-block)``: the dX tile ``(bm, bk)``
     accumulates over the innermost N walk in scratch and drains with a
@@ -244,10 +267,13 @@ def _fused_grouped_bwd_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn,
         db_ref = refs[idx]; idx += 1
     dxacc_ref = refs[idx]
 
+    kdim, n, bm, bk, bn = (schedule.k, schedule.n, schedule.bm, schedule.bk,
+                           schedule.bn)
+    n_steps = schedule.n_steps
     g = pl.program_id(0)
     ks = pl.program_id(1)
     j = pl.program_id(2)
-    row0, row_end, rs = tbl_ref[g, 0], tbl_ref[g, 1], tbl_ref[g, 2]
+    row0, row_end = tbl_ref[g, 0], tbl_ref[g, 1]
     e = tbl_ref[g, 3]
     state = tbl_ref[g, 4]
 
@@ -257,9 +283,7 @@ def _fused_grouped_bwd_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn,
         if db_ref is not None:
             db_ref[...] = jnp.zeros_like(db_ref)
 
-    col0 = j * bn
-    cs = jnp.minimum(col0, n - bn)
-    k0, kstart = clamped_k_window(ks, bk, kdim)
+    rs, col0, cs, k0, kstart = _window(tbl_ref, g, j, ks, schedule)
     k_end = jnp.minimum(k0 + bk, kdim)
 
     @pl.when(state == TILE_COMPUTE)
@@ -274,6 +298,11 @@ def _fused_grouped_bwd_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn,
         own_dy = ownership_mask((bm, bn), rs, cs, row0, row_end, col0, n)
         dy_m = jnp.where(own_dy, dy_blk, 0.0)
         w_blk = w_ref[0, pl.ds(kstart, bk), pl.ds(cs, bn)].astype(jnp.float32)
+        if schedule.n_p != n:
+            # Padding columns may be non-finite: zero them before they
+            # meet dY's zeroed columns in the contraction.
+            cols = cs + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
+            w_blk = jnp.where(cols < n, w_blk, 0.0)
 
         # dgrad: dX[rows, kpanel] += dY @ W^T — masked dY zeroes every
         # term another tile owns, so no W-side mask is needed.
@@ -313,7 +342,7 @@ def _fused_grouped_bwd_kernel(tbl_ref, *refs, kdim, n, bm, bk, bn,
 def build_fused_grouped_bwd_kernel(*, schedule: GroupedTileSchedule,
                                    with_db: bool = False,
                                    in_dtype=jnp.float32,
-                                   interpret: bool = True):
+                                   interpret: bool = False):
     """Generate ONE pallas_call executing a whole grouped backward.
 
     Returns ``f(table, x, dy, w) -> (dx, dw[, db])`` with
@@ -324,28 +353,28 @@ def build_fused_grouped_bwd_kernel(*, schedule: GroupedTileSchedule,
     once per K-panel (DESIGN.md §11).
     """
     t, kdim, n = schedule.t, schedule.k, schedule.n
-    bm, bk, bn = schedule.bm, schedule.bk, schedule.bn
+    t_p, k_p, n_p = schedule.t_p, schedule.k_p, schedule.n_p
+    bm, bk = schedule.bm, schedule.bk
     e = schedule.num_experts
 
-    body = functools.partial(
-        _fused_grouped_bwd_kernel, kdim=kdim, n=n, bm=bm, bk=bk, bn=bn,
-        k_steps=schedule.k_steps, n_steps=schedule.n_steps, with_db=with_db)
+    body = functools.partial(_fused_grouped_bwd_kernel, schedule=schedule,
+                             with_db=with_db)
 
     in_specs = [
-        pl.BlockSpec((t, kdim), lambda g, ks, j, tbl: (0, 0)),
-        pl.BlockSpec((t, n), lambda g, ks, j, tbl: (0, 0)),
-        pl.BlockSpec((1, kdim, n), lambda g, ks, j, tbl: (tbl[g, 3], 0, 0)),
+        pl.BlockSpec((t_p, k_p), lambda g, ks, j, tbl: (0, 0)),
+        pl.BlockSpec((t_p, n_p), lambda g, ks, j, tbl: (0, 0)),
+        pl.BlockSpec((1, k_p, n_p), lambda g, ks, j, tbl: (tbl[g, 3], 0, 0)),
     ]
     out_specs = [
-        pl.BlockSpec((t, kdim), lambda g, ks, j, tbl: (0, 0)),
-        pl.BlockSpec((e, kdim, n), lambda g, ks, j, tbl: (0, 0, 0)),
+        pl.BlockSpec((t_p, k_p), lambda g, ks, j, tbl: (0, 0)),
+        pl.BlockSpec((e, k_p, n_p), lambda g, ks, j, tbl: (0, 0, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((t, kdim), jnp.float32),
         jax.ShapeDtypeStruct((e, kdim, n), jnp.float32),
     ]
     if with_db:
-        out_specs.append(pl.BlockSpec((e, n), lambda g, ks, j, tbl: (0, 0)))
+        out_specs.append(pl.BlockSpec((e, n_p), lambda g, ks, j, tbl: (0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((e, n), jnp.float32))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -356,10 +385,15 @@ def build_fused_grouped_bwd_kernel(*, schedule: GroupedTileSchedule,
         scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
     )
 
+    need = grouped_bwd_vmem_need(t_p, k_p, n_p, experts=e,
+                                 isz=jnp.dtype(in_dtype).itemsize,
+                                 acc=(bm, bk), with_db=with_db)
     kernel = pl.pallas_call(
         body,
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit(need)),
         interpret=interpret,
     )
 
@@ -418,7 +452,7 @@ def build_grouped_gemm_kernel(*, t_padded: int, k: int, n: int, num_experts: int
                               bm: int = 128, bk: int = 512, bn: int = 256,
                               epilogue: Optional[str] = None,
                               in_dtype=jnp.float32, out_dtype=jnp.float32,
-                              interpret: bool = True):
+                              interpret: bool = False):
     """Returns f(x:(Tp,K), w:(E,K,N), [bias:(E,N)], block_expert:(nb,),
     nrows:(1,)) -> (Tp,N)."""
     bn = min(bn, n)

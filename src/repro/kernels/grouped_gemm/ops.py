@@ -171,7 +171,6 @@ def _execute_mesh(desc: GroupedGemmDescriptor, plan: GroupedGemmPlan, x4, w,
         raise NotImplementedError("mesh grouped GEMM is wide-only")
     if bias is not None:
         raise NotImplementedError("mesh grouped GEMM has no bias path")
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.runtime.shardlib import current_mesh
     mesh = current_mesh()
@@ -199,8 +198,8 @@ def _execute_mesh(desc: GroupedGemmDescriptor, plan: GroupedGemmPlan, x4, w,
             y = run_local(rows, w_full, e)
             return y.reshape(e, nl, cap, f).transpose(1, 0, 2, 3)
 
-        fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(None)),
-                       out_specs=P(axis), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(None)),
+                           out_specs=P(axis), check_vma=False)
         return fn(x4, w)
 
     events = mesh_comm_events(desc, "distributed")
@@ -221,8 +220,8 @@ def _execute_mesh(desc: GroupedGemmDescriptor, plan: GroupedGemmPlan, x4, w,
         y = jax.lax.all_to_all(y, axis, split_axis=0, concat_axis=0)
         return y.transpose(1, 0, 2, 3, 4).reshape(nl, e, cap, f)
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
-                   out_specs=P(axis), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis)),
+                       out_specs=P(axis), check_vma=False)
     return fn(x4, w)
 
 
@@ -241,13 +240,15 @@ def execute(desc: GroupedGemmDescriptor, plan: GroupedGemmPlan, x, w,
         # formulation (zero engine launches).
         if engine.resolve_fused(plan):
             engine.count_launches("grouped_gemm",
-                                  plan_launches(plan, fused=True))
+                                  plan_launches(plan, fused=True),
+                                  fused=True)
             return _execute_fused(desc, plan, x, w, group_sizes, bias,
                                   interpret, sx=sx, sw=sw)
         engine.count_launches("grouped_gemm", 0)
         return _xla_quant_grouped(desc, x, w, group_sizes, bias, sx, sw)
     fused = engine.resolve_fused(plan)
-    engine.count_launches("grouped_gemm", plan_launches(plan, fused=fused))
+    engine.count_launches("grouped_gemm", plan_launches(plan, fused=fused),
+                          fused=fused)
     if fused:
         return _execute_fused(desc, plan, x, w, group_sizes, bias, interpret)
     return _execute_padded(desc, plan, x, w, group_sizes, bias, interpret)
@@ -335,7 +336,7 @@ def _grouped_vjp_fwd(epilogue, x, w, group_sizes, bias):
     desc = GroupedGemmDescriptor.from_operands(x, w, epilogue=epilogue)
     bdesc = GroupedGemmBwdDescriptor.from_forward(desc)
     fused_ok = (cfg.fused != "off"
-                and grouped_bwd_fused_legal(bdesc, cfg.machine))
+                and grouped_bwd_fused_legal(bdesc, cfg.machine_model))
     out = engine.dispatch(desc, x, w, group_sizes, bias=bias)
     # Residual dict keys are pytree *structure* — the backward branch is
     # resolved at trace time, not with traced booleans.

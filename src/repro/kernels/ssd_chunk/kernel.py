@@ -29,8 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _ssd_chunk_body(c_ref, b_ref, l_ref, x_ref, o_ref, s_ref):
     c = c_ref[0]          # (Q, n)
@@ -49,7 +47,7 @@ def _ssd_chunk_body(c_ref, b_ref, l_ref, x_ref, o_ref, s_ref):
 
 
 def build_ssd_chunk_kernel(*, groups: int, q: int, n: int, p: int,
-                           dtype=jnp.float32, interpret: bool = True):
+                           dtype=jnp.float32, interpret: bool = False):
     """f(C:(G,Q,n), B:(G,Q,n), L:(G,Q,Q), xdt:(G,Q,p)) -> (G,Q,p)."""
     return pl.pallas_call(
         _ssd_chunk_body,
@@ -63,7 +61,7 @@ def build_ssd_chunk_kernel(*, groups: int, q: int, n: int, p: int,
         out_specs=pl.BlockSpec((1, q, p), lambda g: (g, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((groups, q, p), dtype),
         scratch_shapes=[pltpu.VMEM((q, q), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -74,8 +72,18 @@ def build_ssd_chunk_kernel(*, groups: int, q: int, n: int, p: int,
 # Fused carried-state scan (DESIGN.md §10): one launch for the whole scan
 # ---------------------------------------------------------------------------
 
-def _ssd_scan_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref, s0_ref,
-                   y_ref, sf_ref, *rest, q, chunks):
+def _chunk_decay_row(decay_in, n):
+    """The whole-chunk decay (decay-in's last row) broadcast to a
+    ``(G, NC, 1, n)`` row operand: scaling the ``(p, n)`` state by it is a
+    sublane broadcast, where one element picked out of the ``(Q, 1)``
+    column would need a broadcast in both sublanes and lanes, which
+    Mosaic does not lower."""
+    return jnp.broadcast_to(decay_in[..., -1:, None],
+                            decay_in.shape[:2] + (1, n))
+
+
+def _ssd_scan_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref, cd_ref,
+                   s0_ref, y_ref, sf_ref, *rest, q, chunks):
     """One grid step = one (group, chunk) cell; the chunk dimension is
     sequential, so ``state_ref`` (the (p, n) SSM state, fp32) carries
     across it as accumulator scratch — the inter-chunk recurrence *is*
@@ -97,14 +105,14 @@ def _ssd_scan_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref, s0_ref,
     b = b_ref[0, 0]          # (Q, n)
     l = l_ref[0, 0]          # (Q, Q) decay mask
     x = x_ref[0, 0]          # (Q, p)
-    di = di_ref[0, 0]        # (Q,)  decay into each row from chunk start
-    do = do_ref[0, 0]        # (Q,)  decay from each row to chunk end
+    di = di_ref[0, 0]        # (Q, 1) decay into each row from chunk start
+    do = do_ref[0, 0]        # (Q, 1) decay from each row to chunk end
     state = state_ref[...]   # (p, n) state *entering* this chunk
 
     # inter-chunk contribution: y_off = (C · S_prevᵀ) ⊙ decay_in
     y_off = jax.lax.dot_general(
         c.astype(jnp.float32), state, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * di[:, None]
+        preferred_element_type=jnp.float32) * di
     # intra-chunk ladder (identical math to _ssd_chunk_body)
     s = jax.lax.dot_general(
         c, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -115,10 +123,10 @@ def _ssd_scan_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref, s0_ref,
 
     # state update: S ← S · exp(da_tot) + Bᵀ · (xdt ⊙ decay_out); the
     # whole-chunk decay is decay_in's last element (da_cs[-1] == da_tot).
-    xw = (x.astype(jnp.float32) * do[:, None]).astype(x.dtype)
+    xw = (x.astype(jnp.float32) * do).astype(x.dtype)
     bx = jax.lax.dot_general(
         xw, b, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    state_ref[...] = state * di[q - 1] + bx
+    state_ref[...] = state * cd_ref[0, 0] + bx
 
     @pl.when(ci == chunks - 1)
     def _final():
@@ -126,7 +134,7 @@ def _ssd_scan_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref, s0_ref,
 
 
 def build_ssd_scan_kernel(*, groups: int, chunks: int, q: int, n: int,
-                          p: int, dtype=jnp.float32, interpret: bool = True,
+                          p: int, dtype=jnp.float32, interpret: bool = False,
                           return_states: bool = False):
     """Generate ONE pallas_call executing a whole chunked SSD scan.
 
@@ -162,26 +170,33 @@ def build_ssd_scan_kernel(*, groups: int, chunks: int, q: int, n: int,
             pl.BlockSpec((1, 1, q, n), lambda g, c: (g, c, 0, 0)),
             pl.BlockSpec((1, 1, q, q), lambda g, c: (g, c, 0, 0)),
             pl.BlockSpec((1, 1, q, p), lambda g, c: (g, c, 0, 0)),
-            pl.BlockSpec((1, 1, q), lambda g, c: (g, c, 0)),
-            pl.BlockSpec((1, 1, q), lambda g, c: (g, c, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda g, c: (g, c, 0, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda g, c: (g, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, n), lambda g, c: (g, c, 0, 0)),
             pl.BlockSpec((1, p, n), lambda g, c: (g, 0, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )
-    return kernel
+
+    def run(c, b, l, xdt, decay_in, decay_out, s0):
+        # Decays ride as (Q, 1) columns: a row-broadcast Mosaic lowers.
+        return kernel(c, b, l, xdt, decay_in[..., None],
+                      decay_out[..., None], _chunk_decay_row(decay_in, n), s0)
+
+    return run
 
 
 # ---------------------------------------------------------------------------
 # Fused carried-state backward (DESIGN.md §11): one reverse-walk launch
 # ---------------------------------------------------------------------------
 
-def _ssd_scan_bwd_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref,
+def _ssd_scan_bwd_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref, cd_ref,
                        states_ref, dy_ref, dsf_ref, dc_ref, db_ref, dl_ref,
                        dx_ref, ddi_ref, ddo_ref, ds0_ref, ds_ref, *,
                        q, chunks):
@@ -202,22 +217,22 @@ def _ssd_scan_bwd_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref,
     b = b_ref[0, 0].astype(jnp.float32)      # (Q, n)
     l = l_ref[0, 0].astype(jnp.float32)      # (Q, Q)
     x = x_ref[0, 0].astype(jnp.float32)      # (Q, p)
-    di = di_ref[0, 0]                        # (Q,) fp32
-    do = do_ref[0, 0]                        # (Q,) fp32
+    di = di_ref[0, 0]                        # (Q, 1) fp32
+    do = do_ref[0, 0]                        # (Q, 1) fp32
     s_in = states_ref[0, 0]                  # (p, n) state entering chunk
     dy = dy_ref[0, 0].astype(jnp.float32)    # (Q, p)
     ds_out = ds_ref[...]                     # (p, n) cotangent of S_out
 
     # state update S_out = S_in * di[Q-1] + Bᵀ(x ⊙ do) backward: the
     # carried cotangent splits into the decay leg and the Bx leg.
-    ds_in = ds_out * di[q - 1]
+    ds_in = ds_out * cd_ref[0, 0]
     ddi_last = jnp.sum(s_in * ds_out)        # scalar -> ddi[Q-1]
     dxw = jax.lax.dot_general(b, ds_out, (((1,), (1,)), ((), ())),
                               preferred_element_type=jnp.float32)  # (Q, p)
-    xw = x * do[:, None]
+    xw = x * do
     db = jax.lax.dot_general(xw, ds_out, (((1,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)   # (Q, n)
-    dx = dxw * do[:, None]
+    dx = dxw * do
     ddo = jnp.sum(dxw * x, axis=1, keepdims=True)                  # (Q, 1)
 
     # intra-chunk ladder backward: recompute scores/W, then walk
@@ -237,7 +252,7 @@ def _ssd_scan_bwd_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref,
                               preferred_element_type=jnp.float32)
 
     # inter-chunk offset y_off = (C · S_inᵀ) ⊙ di backward.
-    a = dy * di[:, None]
+    a = dy * di
     y_off_raw = jax.lax.dot_general(c, s_in, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
     dc += jax.lax.dot_general(a, s_in, (((1,), (0,)), ((), ())),
@@ -252,8 +267,8 @@ def _ssd_scan_bwd_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref,
     db_ref[0, 0] = db.astype(db_ref.dtype)
     dl_ref[0, 0] = dl.astype(dl_ref.dtype)
     dx_ref[0, 0] = dx.astype(dx_ref.dtype)
-    ddi_ref[0, 0] = ddi[:, 0]
-    ddo_ref[0, 0] = ddo[:, 0]
+    ddi_ref[0, 0] = ddi
+    ddo_ref[0, 0] = ddo
     ds_ref[...] = ds_in
 
     @pl.when(ci == chunks - 1)
@@ -263,7 +278,7 @@ def _ssd_scan_bwd_body(c_ref, b_ref, l_ref, x_ref, di_ref, do_ref,
 
 def build_ssd_scan_bwd_kernel(*, groups: int, chunks: int, q: int, n: int,
                               p: int, dtype=jnp.float32,
-                              interpret: bool = True):
+                              interpret: bool = False):
     """Generate ONE reverse-walk pallas_call for the chunked-scan backward.
 
     Returns ``f(C, B, L, xdt, decay_in, decay_out, states, dY, dSf) ->
@@ -284,8 +299,9 @@ def build_ssd_scan_bwd_kernel(*, groups: int, chunks: int, q: int, n: int,
             pl.BlockSpec((1, 1, q, n), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, 1, q, q), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, 1, q, p), lambda g, c: (g, last - c, 0, 0)),
-            pl.BlockSpec((1, 1, q), lambda g, c: (g, last - c, 0)),
-            pl.BlockSpec((1, 1, q), lambda g, c: (g, last - c, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda g, c: (g, last - c, 0, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda g, c: (g, last - c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, n), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, 1, p, n), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, 1, q, p), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, p, n), lambda g, c: (g, 0, 0)),
@@ -295,8 +311,8 @@ def build_ssd_scan_bwd_kernel(*, groups: int, chunks: int, q: int, n: int,
             pl.BlockSpec((1, 1, q, n), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, 1, q, q), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, 1, q, p), lambda g, c: (g, last - c, 0, 0)),
-            pl.BlockSpec((1, 1, q), lambda g, c: (g, last - c, 0)),
-            pl.BlockSpec((1, 1, q), lambda g, c: (g, last - c, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda g, c: (g, last - c, 0, 0)),
+            pl.BlockSpec((1, 1, q, 1), lambda g, c: (g, last - c, 0, 0)),
             pl.BlockSpec((1, p, n), lambda g, c: (g, 0, 0)),
         ],
         out_shape=[
@@ -304,14 +320,21 @@ def build_ssd_scan_bwd_kernel(*, groups: int, chunks: int, q: int, n: int,
             jax.ShapeDtypeStruct((groups, chunks, q, n), jnp.float32),
             jax.ShapeDtypeStruct((groups, chunks, q, q), jnp.float32),
             jax.ShapeDtypeStruct((groups, chunks, q, p), jnp.float32),
-            jax.ShapeDtypeStruct((groups, chunks, q), jnp.float32),
-            jax.ShapeDtypeStruct((groups, chunks, q), jnp.float32),
+            jax.ShapeDtypeStruct((groups, chunks, q, 1), jnp.float32),
+            jax.ShapeDtypeStruct((groups, chunks, q, 1), jnp.float32),
             jax.ShapeDtypeStruct((groups, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )
-    return kernel
+
+    def run(c, b, l, xdt, decay_in, decay_out, states, dy, dsf):
+        dc, db, dl, dx, ddi, ddo, ds0 = kernel(
+            c, b, l, xdt, decay_in[..., None], decay_out[..., None],
+            _chunk_decay_row(decay_in, n), states, dy, dsf)
+        return dc, db, dl, dx, ddi[..., 0], ddo[..., 0], ds0
+
+    return run

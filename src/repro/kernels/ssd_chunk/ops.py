@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from repro.core import engine
 from repro.core.blocking import SsdChunkPlan, plan_ssd, plan_ssd_bwd, \
     ssd_bwd_fused_legal
-from repro.core.config import get_config
+from repro.core.config import get_config, resolve_interpret
 from repro.core.descriptor import SsdChunkBwdDescriptor, SsdChunkDescriptor
 from repro.core.schedule import plan_launches
 from repro.kernels.ssd_chunk.kernel import (build_ssd_chunk_kernel,
@@ -99,7 +99,8 @@ def execute(desc: SsdChunkDescriptor, plan: SsdChunkPlan, c_mat, b_mat,
                              interpret)
     decay_in, decay_out, s0 = rest
     fused = engine.resolve_fused(plan)
-    engine.count_launches("ssd_chunk", plan_launches(plan, fused))
+    engine.count_launches("ssd_chunk", plan_launches(plan, fused),
+                          fused=fused)
     if fused:
         return _execute_scan_fused(desc, c_mat, b_mat, l_mat, xdt,
                                    decay_in, decay_out, s0, interpret)
@@ -158,7 +159,7 @@ def _ssd_vjp_fwd(c, b, l, xdt, decay_in, decay_out, s0):
     desc = SsdChunkDescriptor.from_scan_operands(c, xdt)
     bdesc = SsdChunkBwdDescriptor.from_forward(desc)
     fused_ok = (cfg.fused != "off"
-                and ssd_bwd_fused_legal(bdesc, cfg.machine))
+                and ssd_bwd_fused_legal(bdesc, cfg.machine_model))
     if fused_ok:
         # The backward replays the per-chunk entering states, so the
         # forward must run fused too (the states drain from its walk).
@@ -168,12 +169,12 @@ def _ssd_vjp_fwd(c, b, l, xdt, decay_in, decay_out, s0):
         return out, {"ref": (c, b, l, xdt, decay_in, decay_out, s0)}
     # Forward with the entering states drained for the reverse walk —
     # same schedule, same carried-state math as the primal fused kernel.
-    interpret = cfg.interpret
+    interpret = resolve_interpret(cfg.interpret)
     key = desc.cache_key() + ("fused_states", interpret)
     kernel = engine.build_cached(key, lambda: build_ssd_scan_kernel(
         groups=desc.groups, chunks=desc.chunks, q=desc.q, n=desc.n,
         p=desc.p, dtype=xdt.dtype, interpret=interpret, return_states=True))
-    engine.count_launches("ssd_chunk", 1)
+    engine.count_launches("ssd_chunk", 1, fused=True)
     y, sf, states = kernel(c, b, l, xdt, decay_in, decay_out, s0)
     return (y, sf), {"fused": (c, b, l, xdt, decay_in, decay_out, states)}
 

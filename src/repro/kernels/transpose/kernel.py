@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _transpose_body(x_ref, o_ref, scratch_ref):
     # Stage the tile through scratch (the ZA tile), then emit its transpose.
@@ -30,7 +28,7 @@ def _transpose_body(x_ref, o_ref, scratch_ref):
 
 def build_transpose_kernel(rows: int, cols: int, bt_r: int = 256,
                            bt_c: int = 256, dtype=jnp.float32,
-                           interpret: bool = True, batch: int = 0):
+                           interpret: bool = False, batch: int = 0):
     """Generate a (nb, rows, cols) -> (nb, cols, rows) transpose.
 
     Block (bt_r, bt_c) is read at block-index (b, i, j) and written at
@@ -49,7 +47,7 @@ def build_transpose_kernel(rows: int, cols: int, bt_r: int = 256,
         out_specs=pl.BlockSpec((1, bt_c, bt_r), lambda b, i, j: (b, j, i)),
         out_shape=jax.ShapeDtypeStruct((nb, cols, rows), dtype),
         scratch_shapes=[pltpu.VMEM((bt_r, bt_c), dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
         interpret=interpret,

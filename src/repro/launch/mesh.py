@@ -12,21 +12,11 @@ Axis semantics (DESIGN.md §5):
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-# ``AxisType`` (and make_mesh's ``axis_types=``) only exist on newer jax;
-# older releases default every axis to Auto semantics anyway.
-try:
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

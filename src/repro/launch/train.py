@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced_config
 from repro.data import SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import adamw, warmup_cosine
 from repro.runtime.steps import make_train_step, model_for
 from repro.runtime.train_loop import TrainLoopConfig, run_with_restarts
@@ -26,7 +27,11 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the smoke-scale config (default); "
+                         "--no-reduced trains the registered config as "
+                         "published")
     ap.add_argument("--scale", type=int, default=1,
                     help="multiplier on the reduced config width/depth")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
@@ -40,14 +45,13 @@ def main():
                     help="fused-lowering policy for engine dispatches,"
                          " forward and backward (DESIGN.md §10-11)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.backend is not None or args.fused is not None:
         from repro.core.config import configure
         overrides = {}
         if args.backend is not None:
             overrides["backend"] = args.backend
-            if args.backend == "pallas":
-                overrides["interpret"] = True  # container has no TPU
         if args.fused is not None:
             overrides["fused"] = args.fused
         configure(**overrides)

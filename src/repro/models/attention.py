@@ -31,6 +31,13 @@ Q_CHUNK = 512
 
 NEG_INF = -1e30
 
+# Page size of the decode attention walk.  Under the pallas backend a
+# dense decode cache is read by the same ``flash_decode`` kernel as the
+# serving runtime's page pool, viewed as pages of this size, so a request
+# decoded alone and one decoded in a continuous batch with this page size
+# run the same online-softmax steps on the same values.
+DECODE_PAGE_SIZE = 16
+
 
 class KVCache(NamedTuple):
     k: jax.Array  # (b, S, h_kv, hd)
@@ -322,6 +329,23 @@ def _paged_decode(cfg, cache: PagedKVCache, q, k, v, pos2d, dt, g):
     return new_cache, out
 
 
+def _dense_decode_paged(cache: KVCache, q, pos2d):
+    """Decode attention over a dense cache with the ``flash_decode``
+    kernel: row ``i``'s ``(capacity, hkv, hd)`` cache is ``capacity / P``
+    consecutive pages of a pool whose block table is the identity.
+    Needs ``capacity % P == 0`` and an unwrapped cache (position ``p`` at
+    slot ``p``)."""
+    from repro.kernels.flash_attention import paged_decode_attention
+    b, cap, hkv, hd = cache.k.shape
+    nblk = cap // DECODE_PAGE_SIZE
+    shape = (b * nblk, DECODE_PAGE_SIZE, hkv, hd)
+    tables = jnp.arange(b * nblk, dtype=jnp.int32).reshape(b, nblk)
+    lengths = jnp.broadcast_to(jnp.minimum(pos2d[:, -1] + 1, cap), (b,))
+    return paged_decode_attention(q[:, 0], cache.k.reshape(shape),
+                                  cache.v.reshape(shape), tables,
+                                  lengths)[:, None]
+
+
 # ---------------------------------------------------------------------------
 # Public apply
 # ---------------------------------------------------------------------------
@@ -379,19 +403,26 @@ def attention_apply(params, cfg, x, positions, *, cache: Optional[KVCache] = Non
             msize = mesh.shape.get("model", 1) if mesh is not None else 1
             seq_sharded = msize > 1 and hkv % msize != 0 \
                 and cache.k.shape[1] % msize == 0
-            kf = _repeat_kv(new_cache.k.astype(dt), g)
-            vf = _repeat_kv(new_cache.v.astype(dt), g)
-            if seq_sharded:
-                kv_spec = (("pod", "data"), "model", None, None)
-                kf = shard_activation(kf, kv_spec)
-                vf = shard_activation(vf, kv_spec)
-            qpos = pos2d[:, -1].reshape(-1, 1, 1, 1)  # (1|b, 1, 1, 1)
-            mask = (new_cache.pos[:, None, None, :] <= qpos)
-            if window is not None:
-                mask &= new_cache.pos[:, None, None, :] > qpos - window
-            mask &= new_cache.pos[:, None, None, :] >= 0
-            out = _attend(q, kf, vf, mask, cfg.attn_logit_softcap,
-                          kv_seq_sharded=seq_sharded)
+            if (get_config().backend == "pallas" and window is None
+                    and not cfg.attn_logit_softcap and not seq_sharded
+                    and cap % DECODE_PAGE_SIZE == 0
+                    and new_cache.k.dtype == dt):
+                # The serving runtime's decode kernel and page walk.
+                out = _dense_decode_paged(new_cache, q, pos2d)
+            else:
+                kf = _repeat_kv(new_cache.k.astype(dt), g)
+                vf = _repeat_kv(new_cache.v.astype(dt), g)
+                if seq_sharded:
+                    kv_spec = (("pod", "data"), "model", None, None)
+                    kf = shard_activation(kf, kv_spec)
+                    vf = shard_activation(vf, kv_spec)
+                qpos = pos2d[:, -1].reshape(-1, 1, 1, 1)  # (1|b, 1, 1, 1)
+                mask = (new_cache.pos[:, None, None, :] <= qpos)
+                if window is not None:
+                    mask &= new_cache.pos[:, None, None, :] > qpos - window
+                mask &= new_cache.pos[:, None, None, :] >= 0
+                out = _attend(q, kf, vf, mask, cfg.attn_logit_softcap,
+                              kv_seq_sharded=seq_sharded)
         else:
             out = _attention_seq(q, _repeat_kv(k, g), _repeat_kv(v, g),
                                  positions, positions, window,

@@ -2,11 +2,19 @@
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def rope_freqs(head_dim: int, theta: float = 10000.0):
+    """Inverse frequencies ``theta ** (-i / half)``, computed on the host.
+
+    A constant every program embeds bit for bit.  Traced, the power is
+    either folded on the host or evaluated by the device's ``pow``,
+    depending on each program's fusions: on a TPU the decode step at
+    batch 1 and at batch 8 then rotated by different angles.
+    """
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    return jnp.asarray(1.0 / theta ** (np.arange(half) / half), jnp.float32)
 
 
 def apply_rope(x, positions, theta: float = 10000.0):

@@ -192,6 +192,18 @@ def _merge_inactive(new_cache, old_cache, active):
     return out
 
 
+def repeat_rows(cache, n: int):
+    """``cache`` with each batch row repeated ``n`` times in place (row
+    ``i`` becomes rows ``i*n .. i*n+n-1``); the axes as in
+    :func:`_merge_inactive`."""
+    def rep(tree, axis):
+        return jax.tree.map(lambda x: jnp.repeat(x, n, axis=axis), tree)
+
+    groups = cache["groups"]
+    return {"groups": None if groups is None else rep(groups, 1),
+            "rem": [rep(r, 0) for r in cache["rem"]]}
+
+
 def make_paged_serve_step(cfg):
     """One continuous-batching decode step over the paged serving cache.
 

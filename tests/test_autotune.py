@@ -424,3 +424,26 @@ def test_reset_stats_keeps_entries_for_phase_boundaries():
     assert s["plan_hits"] == 1 and s["plan_misses"] == 0
     assert s["kernel_misses"] == 0 and s["kernel_hits"] >= 1
     assert len(GLOBAL_KERNEL_CACHE) == kernels_built
+
+
+@pytest.mark.parametrize("interpret", [True, False])
+def test_search_skips_failed_candidate_only_under_interpreter(interpret):
+    """Under the interpreter a candidate whose build raises is skipped
+    with a warning; compiled, the failure raises — on a chip it is a
+    kernel the compiler refused, which must not lose quietly to a plan
+    that still builds."""
+    d = GemmDescriptor(m=64, n=64, k=64)
+
+    def refused(*args, **kw):
+        raise RuntimeError("kernel refused")
+
+    operands = (rand((64, 64)), rand((64, 64)))
+    if interpret:
+        with pytest.warns(UserWarning, match="candidate failed"):
+            plan, timed = autotune.search(refused, d, TPU_V5E, operands, {},
+                                          interpret=True, budget=4)
+        assert plan is None and timed == 0
+    else:
+        with pytest.raises(RuntimeError, match="kernel refused"):
+            autotune.search(refused, d, TPU_V5E, operands, {},
+                            interpret=False, budget=4)

@@ -4,6 +4,7 @@
 absent, the property tests degrade to a small deterministic case sweep
 instead of erroring at collection.
 """
+import jax.numpy as jnp
 import pytest
 
 try:
@@ -15,6 +16,7 @@ except ImportError:
 from repro.core import GemmDescriptor, fused_legal, plan_gemm, palette
 from repro.core.blocking import Region, ceil_div
 from repro.core.machine import TPU_V5E
+from repro.core.schedule import VMEM_LIMIT_CAP, matmul_vmem_need
 
 
 def desc(m, n, k, **kw):
@@ -93,12 +95,16 @@ class TestTileSchedule:
 
     def test_blocks_clamped_to_matrix(self):
         """A region block larger than the matrix clamps so its fixed-shape
-        window fits the real operand buffers."""
+        window fits the staged operand buffers: the rows rounded up to
+        one register tile (8 rows of f32, the unit Mosaic stages), the
+        columns exact under a single window."""
         d = GemmDescriptor(m=7, n=33, k=100)
         sched = plan_gemm(d, force_block=(512, 1024),
                           heterogeneous=False).tile_schedule()
         sched.validate()
-        assert all(bm <= 7 and bn <= 33 for bm, bn in sched.blocks)
+        assert (sched.m_p, sched.n_p) == (8, 33)
+        assert all(bm <= sched.m_p and bn <= sched.n_p
+                   for bm, bn in sched.blocks)
 
     def test_bk_clamped_to_k(self):
         d = GemmDescriptor(m=128, n=128, k=100)
@@ -119,6 +125,29 @@ class TestTileSchedule:
         huge = GemmDescriptor(m=8192, n=8192, k=8192)
         assert not fused_legal(huge, TPU_V5E)  # operands exceed VMEM
         assert not plan_gemm(huge).fused
+
+    @pytest.mark.parametrize("m,n,k,dtype,fused", [
+        (1, 1024, 3072, "bfloat16", True),
+        (80, 80, 512, "float32", True),
+        (2000, 2000, 700, "float32", True),
+        (5120, 3072, 1024, "bfloat16", True),
+        (5376, 3072, 1024, "bfloat16", False),  # would ask for 100.25 MiB
+        (8192, 3072, 1024, "bfloat16", False),  # would ask for 140 MiB
+    ])
+    def test_fused_legality_covers_kernel_vmem(self, m, n, k, dtype, fused):
+        """Legality counts the scoped VMEM the fused kernel asks Mosaic
+        for: a plan called fused asks for no more than the cap, so Mosaic
+        never refuses a kernel the planner chose."""
+        d = desc(m, n, k, in_dtype=dtype, out_dtype=dtype)
+        plan = plan_gemm(d)
+        assert fused_legal(d, TPU_V5E) is fused
+        assert fused or not plan.fused
+        s = plan.tile_schedule()
+        isz = jnp.dtype(dtype).itemsize
+        need = matmul_vmem_need(
+            s.m_p, s.n_p, s.k_p, a_isz=isz, b_isz=isz, out_isz=isz,
+            acc=(max(b[0] for b in s.blocks), max(b[1] for b in s.blocks)))
+        assert (need <= VMEM_LIMIT_CAP) is fused
 
     @pytest.mark.parametrize("m,n,k,force", [
         (128, 128, 512, None),         # BENCH_gemm_fused nn_128: 0.79x fused

@@ -124,12 +124,15 @@ def test_grouped_fused_matches_padscatter_bitwise(sizes, t_extra):
 
 
 def test_grouped_fused_matches_ref_bitwise_single_k_panel():
-    """With one K panel the fused kernel's accumulation order matches the
-    oracle einsum exactly — bit-identical, not just close."""
+    """With one K panel the fused kernel sums the same 96 products per
+    output as the oracle einsum, in one fp32 dot: they may differ only in
+    summation order (XLA's CPU dot splits K its own way), so they agree
+    to ~sqrt(K) fp32 ulps of the largest output (4x margin)."""
     sizes_a, x, w = _grouped_case([37, 0, 201, 70], 4, kdim=96, n=160)
-    out = grouped_gemm(x, w, sizes_a, fused=True)
-    ref = ref_grouped_gemm(x, w, sizes_a)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    out = np.asarray(grouped_gemm(x, w, sizes_a, fused=True))
+    ref = np.asarray(ref_grouped_gemm(x, w, sizes_a))
+    tol = 4 * np.sqrt(96) * np.finfo(np.float32).eps * np.abs(ref).max()
+    np.testing.assert_allclose(out, ref, rtol=0, atol=tol)
 
 
 @pytest.mark.parametrize("epilogue", ["bias", "gelu", "silu", "relu",

@@ -330,14 +330,17 @@ ref = _ref_ep(None, x4, w)
 
 with use(backend="pallas", interpret=True), \
      use_mesh(make_test_mesh(1, 8)):
-    # --- both pinned strategies bit-exact on the ragged input ----------
+    # --- both pinned strategies match the oracle on the ragged input ---
+    # Same k products per output, summed in fp32 in an order that depends
+    # on the lowering: ~sqrt(k) fp32 ulps of the largest output (4x margin).
+    tol = 4 * np.sqrt(k) * np.finfo(np.float32).eps * float(jnp.abs(ref).max())
     for comm in ("gathered", "distributed"):
         pin = dataclasses.replace(plan_grouped(mesh_local_desc(desc, comm)),
                                   desc=desc, comm=comm)
         engine.reset_stats()
         y = engine.dispatch(desc, x4, w, None, plan=pin)
         err = float(jnp.max(jnp.abs(y - ref)))
-        assert err == 0.0, (comm, err)
+        assert err <= tol, (comm, err, tol)
         s = engine.stats()["grouped_gemm"]
         assert s["launches"] == 1, (comm, s)  # fused single launch/shard
         if comm == "distributed":

@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs import get_config, reduced_config
 from repro.models import common
-from repro.models.rotary import apply_rope
+from repro.models.rotary import apply_rope, rope_freqs
 from repro.models.moe import moe_apply, moe_init
 from repro.models.rglru import rglru_apply, rglru_init, _rglru_scan
 from repro.models.ssd import ssd_apply, ssd_init, _ssd_chunked
@@ -55,6 +55,15 @@ def test_rope_preserves_norm_and_relativity():
         rk = apply_rope(k, jnp.array([[pk]]), 10000.0)
         return float(jnp.sum(rq * rk))
     assert abs(dot_at(5, 3) - dot_at(9, 7)) < 1e-3
+
+
+def test_rope_freqs_are_a_host_constant():
+    """The inverse frequencies enter every program as the same literal:
+    no power is traced, so no compiler can evaluate it two ways."""
+    jaxpr = jax.make_jaxpr(lambda: rope_freqs(128, 1e6))()
+    assert not any(e.primitive.name == "pow" for e in jaxpr.eqns)
+    want = (1.0 / 1e6 ** (np.arange(64) / 64)).astype(np.float32)
+    np.testing.assert_array_equal(np.asarray(rope_freqs(128, 1e6)), want)
 
 
 # ---------------------------------------------------------------------------

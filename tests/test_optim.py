@@ -88,7 +88,6 @@ def test_compressed_psum_single_device():
     from jax.sharding import Mesh
     import jax
     mesh_devices = np.array(jax.devices()[:1])
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = Mesh(mesh_devices, ("pod",))
     x = jnp.asarray(np.random.default_rng(1).standard_normal((8, 16)),
@@ -97,5 +96,5 @@ def test_compressed_psum_single_device():
     def f(x):
         return compressed_psum(x, "pod")
 
-    out = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(x)
+    out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P())(x)
     np.testing.assert_allclose(out, x, atol=0.05, rtol=0.05)

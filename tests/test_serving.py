@@ -165,6 +165,20 @@ def test_serve_step_carries_position():
     assert int(pos) == 2
 
 
+@pytest.mark.parametrize("arch,overrides", CASES)
+def test_generate_decode_rows_repeats_each_prompt(arch, overrides):
+    """decode_rows decodes every prompt's cache (KV, ring and recurrent
+    state) repeated and returns the first copy of each: the same tokens
+    as decoding at the prompts' own width, on a backend whose programs
+    of different widths round alike."""
+    cfg, params = _setup(arch, overrides)
+    prompts = jnp.asarray(np.arange(16, dtype=np.int32).reshape(2, 8) % 11)
+    want = generate(cfg, params, prompts, 4)["tokens"]
+    got = generate(cfg, params, prompts, 4, decode_rows=6)["tokens"]
+    assert got.shape == (2, 4)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_generate_reports_engine_stats():
     """generate() snapshots engine.stats() into its result, mirroring
     launch.train's provenance reporting."""
