@@ -125,6 +125,7 @@ def build_flash_kernel(*, batch_heads: int, sq: int, sk: int, d: int,
         k_steps=grid[2], sk=sk, causal=causal, scale=d ** -0.5)
     return pl.pallas_call(
         body,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -273,6 +274,7 @@ def build_fused_flash_kernel(*, schedule: FlashTileSchedule,
 
     kernel = pl.pallas_call(
         body,
+        name="flash_attention",
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -440,6 +442,7 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
 
     return pl.pallas_call(
         body,
+        name="flash_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, h, hd), dtype),
         compiler_params=pltpu.CompilerParams(
@@ -574,6 +577,7 @@ def build_fused_flash_bwd_kernel(*, schedule: FlashTileSchedule,
 
     kernel = pl.pallas_call(
         body,
+        name="flash_attention_bwd",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((batch_heads, sq, d), jnp.float32),

@@ -122,6 +122,7 @@ def build_gemm_kernel(*, m: int, n: int, k: int, bm: int, bn: int, bk: int,
 
     kernel = pl.pallas_call(
         body,
+        name="gemm",
         grid=(grid_m, grid_n, grid_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
@@ -342,6 +343,7 @@ def build_fused_gemm_kernel(*, schedule, batch: int = 0, layout: str = "nn",
 
     kernel = pl.pallas_call(
         body,
+        name="gemm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nb, m, n), jnp.dtype(out_dtype)),
         compiler_params=pltpu.CompilerParams(

@@ -216,6 +216,7 @@ def build_fused_grouped_kernel(*, schedule: GroupedTileSchedule,
 
     kernel = pl.pallas_call(
         body,
+        name="grouped_gemm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, n), jnp.dtype(out_dtype)),
         compiler_params=pltpu.CompilerParams(
@@ -390,6 +391,7 @@ def build_fused_grouped_bwd_kernel(*, schedule: GroupedTileSchedule,
                                  acc=(bm, bk), with_db=with_db)
     kernel = pl.pallas_call(
         body,
+        name="grouped_gemm_bwd",
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
@@ -487,6 +489,7 @@ def build_grouped_gemm_kernel(*, t_padded: int, k: int, n: int, num_experts: int
 
     kernel = pl.pallas_call(
         body,
+        name="grouped_gemm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_padded, n), out_dtype),
         interpret=interpret,

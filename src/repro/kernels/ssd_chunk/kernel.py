@@ -51,6 +51,7 @@ def build_ssd_chunk_kernel(*, groups: int, q: int, n: int, p: int,
     """f(C:(G,Q,n), B:(G,Q,n), L:(G,Q,Q), xdt:(G,Q,p)) -> (G,Q,p)."""
     return pl.pallas_call(
         _ssd_chunk_body,
+        name="ssd_chunk",
         grid=(groups,),
         in_specs=[
             pl.BlockSpec((1, q, n), lambda g: (g, 0, 0)),
@@ -164,6 +165,7 @@ def build_ssd_scan_kernel(*, groups: int, chunks: int, q: int, n: int,
             jax.ShapeDtypeStruct((groups, chunks, p, n), jnp.float32))
     kernel = pl.pallas_call(
         body,
+        name="ssd_chunk",
         grid=(groups, chunks),
         in_specs=[
             pl.BlockSpec((1, 1, q, n), lambda g, c: (g, c, 0, 0)),
@@ -293,6 +295,7 @@ def build_ssd_scan_bwd_kernel(*, groups: int, chunks: int, q: int, n: int,
     body = functools.partial(_ssd_scan_bwd_body, q=q, chunks=chunks)
     kernel = pl.pallas_call(
         body,
+        name="ssd_chunk_bwd",
         grid=(groups, chunks),
         in_specs=[
             pl.BlockSpec((1, 1, q, n), lambda g, c: (g, last - c, 0, 0)),

@@ -42,6 +42,7 @@ def build_transpose_kernel(rows: int, cols: int, bt_r: int = 256,
     grid = (nb, pl.cdiv(rows, bt_r), pl.cdiv(cols, bt_c))
     return pl.pallas_call(
         _transpose_body,
+        name="transpose",
         grid=grid,
         in_specs=[pl.BlockSpec((1, bt_r, bt_c), lambda b, i, j: (b, i, j))],
         out_specs=pl.BlockSpec((1, bt_c, bt_r), lambda b, i, j: (b, j, i)),

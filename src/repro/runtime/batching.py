@@ -27,9 +27,23 @@ re-admit is greedy-token-identical to an uninterrupted run):
 
 Everything host-side here is numpy/python — the device only ever sees
 the shape-stable step inputs.
+
+Timing (DESIGN.md §15): each phase of ``step()`` runs under
+``_phase(key)``, which adds its ``time.perf_counter()`` seconds to
+``phase_seconds[key]`` and opens the profiler span ``serve.<key>`` — on
+the same clock as the device operations when a trace is recorded.  The
+top-level phases (admission, prefill, grow, eviction, tables, decode)
+never overlap: one nested in another is left out of the outer one, so
+their sum is the time inside ``step()`` less some bookkeeping.  Dotted
+keys (``decode.dispatch``, ``decode.emit``) are parts of the phase
+before the dot and count inside it; the rest of ``decode`` is the span
+``serve.decode.sync``, the wait for the device.  ``queue_wait`` is a
+counter: seconds from entering the queue to admission, summed over
+admissions.  ``serve.step`` spans a whole ``step()``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -61,8 +75,9 @@ class _Seq:
     generated: List[int] = dataclasses.field(default_factory=list)
     evictions: int = 0
     admit_order: int = -1     # monotonic stamp of the latest admission
-    t_visible: float = 0.0    # wall time the request hit the queue
-    t_last: float = 0.0       # wall time of the previous emitted token
+    t_queued: float = 0.0     # when it last entered the queue (submit or
+                              # eviction), on time.perf_counter()
+    t_last: float = 0.0       # when the previous token was emitted
 
     @property
     def context(self) -> np.ndarray:
@@ -103,11 +118,12 @@ class ContinuousBatchingEngine:
         self.finished: Dict[int, _Seq] = {}
         self.token_latencies: List[float] = []
         self._tables_dirty = True
-        # Wall-clock per scheduler phase (DESIGN.md §15) — "prefill" is
-        # deducted from the admission block so the four never overlap.
-        self.phase_seconds: Dict[str, float] = {
-            "admission": 0.0, "prefill": 0.0, "decode": 0.0,
-            "eviction": 0.0}
+        # Seconds per phase of step() (module docstring), kept by _phase;
+        # every key exists from the start.
+        self.phase_seconds: Dict[str, float] = dict.fromkeys((
+            "admission", "prefill", "grow", "eviction", "tables", "decode",
+            "decode.dispatch", "decode.emit", "queue_wait"), 0.0)
+        self._nested_s = 0.0  # top-level phases inside the open one
 
     # -- warm-start ---------------------------------------------------------
 
@@ -131,7 +147,7 @@ class ContinuousBatchingEngine:
         provable via ``engine.stats()``.  Returns a summary dict.
         """
         from repro.core.config import get_config
-        t0 = time.time()
+        t0 = time.perf_counter()
         kernels: Dict[str, int] = {}
         if manifest is not None or get_config().warm_start:
             kernels = engine.warmup(manifest=manifest)
@@ -145,17 +161,32 @@ class ContinuousBatchingEngine:
             jnp.zeros((self.num_slots,), jnp.int32),
             jnp.zeros((self.num_slots,), bool))
         jax.block_until_ready(toks)
-        return {"seconds": time.time() - t0, "kernels": kernels,
+        return {"seconds": time.perf_counter() - t0, "kernels": kernels,
                 "prefill_lengths": lengths}
 
     # -- submission ---------------------------------------------------------
 
     def submit(self, req: Request) -> None:
-        seq = _Seq(req=req, t_visible=time.time())
-        seq.t_last = seq.t_visible
-        self.queue.append(seq)
+        now = time.perf_counter()
+        self.queue.append(_Seq(req=req, t_queued=now, t_last=now))
 
     # -- internals ----------------------------------------------------------
+
+    @contextlib.contextmanager
+    def _phase(self, key: str):
+        """Time the block into ``phase_seconds[key]`` under the profiler
+        span ``serve.<key>``.  A top-level (undotted) key leaves out the
+        top-level phases nested in it; a dotted key counts them."""
+        outer, self._nested_s = self._nested_s, 0.0
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation("serve." + key):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            top = "." not in key
+            self.phase_seconds[key] += dt - self._nested_s if top else dt
+            self._nested_s = outer + (dt if top else self._nested_s)
 
     def _prefill_fn(self, length: int):
         fn = self._prefills.get(length)
@@ -177,22 +208,24 @@ class ContinuousBatchingEngine:
         # uninterrupted run would hold (the last emitted token is never
         # in the cache yet), then the normal decode step recomputes from
         # it — so evict/re-admit cycles stay greedy-token-identical.
+        self.phase_seconds["queue_wait"] += time.perf_counter() - seq.t_queued
         readmit = bool(seq.generated)
         ctx = seq.context[:-1] if readmit else seq.context
         L = len(ctx)
         page_ids = self.pool.owned_pages(slot)
         page_ids += self.pool.grow(slot, L)
-        t0 = time.time()
-        logits, dense = self._prefill_fn(L)(
-            self.params, {"tokens": jnp.asarray(ctx)[None, :]})
-        self.cache = write_prefill(self.cache, dense, slot=slot, length=L,
-                                   page_ids=page_ids,
-                                   page_size=self.spec.page_size)
-        self.phase_seconds["prefill"] += time.time() - t0
-        if readmit:
-            tok = seq.generated[-1]
-        else:
-            tok = int(jnp.argmax(logits[0]))
+        with self._phase("prefill"):
+            logits, dense = self._prefill_fn(L)(
+                self.params, {"tokens": jnp.asarray(ctx)[None, :]})
+            self.cache = write_prefill(self.cache, dense, slot=slot,
+                                       length=L, page_ids=page_ids,
+                                       page_size=self.spec.page_size)
+            # The argmax readback is the admission's one device sync.  A
+            # re-admission reads nothing back: its prefill finishes on the
+            # device inside the next decode's sync.
+            tok = seq.generated[-1] if readmit else int(
+                jnp.argmax(logits[0]))
+        if not readmit:
             self._emit(seq, tok)
         self.slots[slot] = seq
         self.lengths[slot] = L
@@ -200,7 +233,7 @@ class ContinuousBatchingEngine:
         self._tables_dirty = True
 
     def _emit(self, seq: _Seq, tok: int) -> None:
-        now = time.time()
+        now = time.perf_counter()
         seq.generated.append(tok)
         self.token_latencies.append(now - seq.t_last)
         seq.t_last = now
@@ -221,14 +254,14 @@ class ContinuousBatchingEngine:
                 f"be evicted — pool too small for one sequence")
         # LIFO victim choice: the most recently admitted sequence has the
         # least decode investment to replay on re-admission.
-        t0 = time.time()
-        victim = max(victims, key=lambda i: self.slots[i].admit_order)
-        seq = self.slots[victim]
-        seq.evictions += 1
-        self.evictions += 1
-        self._release(victim)
-        self.queue.appendleft(seq)
-        self.phase_seconds["eviction"] += time.time() - t0
+        with self._phase("eviction"):
+            victim = max(victims, key=lambda i: self.slots[i].admit_order)
+            seq = self.slots[victim]
+            seq.evictions += 1
+            self.evictions += 1
+            self._release(victim)
+            seq.t_queued = time.perf_counter()
+            self.queue.appendleft(seq)
 
     def _try_admissions(self) -> None:
         while self.queue:
@@ -261,55 +294,59 @@ class ContinuousBatchingEngine:
 
     # -- one scheduler tick -------------------------------------------------
 
+    def _retire_done(self) -> None:
+        for slot, seq in enumerate(self.slots):
+            if seq is not None and seq.done:
+                self.finished[seq.req.rid] = seq
+                self._release(slot)
+
     def step(self) -> int:
         """Retire finished sequences, admit what fits, grow, run ONE
         decode launch over the live batch.  Returns the number of live
         slots this step decoded (0 = idle tick)."""
-        t_admit = time.time()
-        pf0 = self.phase_seconds["prefill"]
-        for slot, seq in enumerate(self.slots):
-            if seq is not None and seq.done:
-                self.finished[seq.req.rid] = seq
-                self._release(slot)
-        self._try_admissions()
-        # Admission emits one token (the prefill argmax) — sequences that
-        # completed right there retire without ever decoding.
-        for slot, seq in enumerate(self.slots):
-            if seq is not None and seq.done:
-                self.finished[seq.req.rid] = seq
-                self._release(slot)
-        self.phase_seconds["admission"] += (
-            time.time() - t_admit - (self.phase_seconds["prefill"] - pf0))
-        self.tick += 1
-        if not any(s is not None for s in self.slots):
-            return 0
-        # Growth may evict — the mask MUST be taken after it, or an
-        # evicted slot would decode as active and scatter its KV through
-        # the zeroed block table into page 0 (owned by someone else).
-        self._grow_active()
-        active_mask = np.array([s is not None for s in self.slots])
-        n_active = int(active_mask.sum())
-        if n_active == 0:
-            return 0
-        if self._tables_dirty:
-            self.cache = refresh_tables(self.cache,
-                                        self.pool.device_tables())
-            self._tables_dirty = False
-        t_dec = time.time()
-        toks, self.cache, _ = self._step(
-            self.params, self.cache,
-            jnp.asarray(self.next_token)[:, None],
-            jnp.asarray(self.lengths, dtype=jnp.int32),
-            jnp.asarray(active_mask))
-        toks = np.asarray(toks)[:, 0]
-        for slot, seq in enumerate(self.slots):
-            if seq is None or not active_mask[slot]:
-                continue
-            self._emit(seq, int(toks[slot]))
-            self.lengths[slot] += 1
-            self.next_token[slot] = int(toks[slot])
-        self.phase_seconds["decode"] += time.time() - t_dec
-        return n_active
+        with jax.profiler.TraceAnnotation("serve.step"):
+            with self._phase("admission"):
+                self._retire_done()
+                self._try_admissions()
+                # Admission emits one token (the prefill argmax) —
+                # sequences that completed right there retire without
+                # ever decoding.
+                self._retire_done()
+            self.tick += 1
+            if not any(s is not None for s in self.slots):
+                return 0
+            # Growth may evict — the mask MUST be taken after it, or an
+            # evicted slot would decode as active and scatter its KV
+            # through the zeroed block table into page 0 (owned by
+            # someone else).
+            with self._phase("grow"):
+                self._grow_active()
+            active_mask = np.array([s is not None for s in self.slots])
+            n_active = int(active_mask.sum())
+            if n_active == 0:
+                return 0
+            if self._tables_dirty:
+                with self._phase("tables"):
+                    self.cache = refresh_tables(self.cache,
+                                                self.pool.device_tables())
+                self._tables_dirty = False
+            with self._phase("decode"):
+                with self._phase("decode.dispatch"):
+                    toks, self.cache, _ = self._step(
+                        self.params, self.cache,
+                        jnp.asarray(self.next_token)[:, None],
+                        jnp.asarray(self.lengths, dtype=jnp.int32),
+                        jnp.asarray(active_mask))
+                with jax.profiler.TraceAnnotation("serve.decode.sync"):
+                    toks = np.asarray(toks)[:, 0]
+                with self._phase("decode.emit"):
+                    for slot, seq in enumerate(self.slots):
+                        if seq is None or not active_mask[slot]:
+                            continue
+                        self._emit(seq, int(toks[slot]))
+                        self.lengths[slot] += 1
+                        self.next_token[slot] = int(toks[slot])
+            return n_active
 
     # -- driver -------------------------------------------------------------
 
@@ -323,7 +360,7 @@ class ContinuousBatchingEngine:
         metrics."""
         pending = sorted(requests, key=lambda r: (r.arrival, r.rid))
         stats0 = engine.stats()
-        t0 = time.time()
+        t0 = time.perf_counter()
         decode_steps = 0
         while pending or self.queue or any(s is not None
                                            for s in self.slots):
@@ -334,7 +371,7 @@ class ContinuousBatchingEngine:
             if self.tick > max_steps:
                 raise RuntimeError("scheduler did not converge "
                                    f"within {max_steps} steps")
-        wall = time.time() - t0
+        wall = time.perf_counter() - t0
         stats1 = engine.stats()
 
         lat = np.asarray(self.token_latencies)

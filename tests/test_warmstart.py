@@ -480,5 +480,7 @@ def test_continuous_serving_warm_step(tmp_path):
     for rid, toks in cold["outputs"].items():
         np.testing.assert_array_equal(warm["outputs"][rid], toks)
     ph = warm["metrics"]["phase_seconds"]
-    assert set(ph) == {"admission", "prefill", "decode", "eviction"}
+    assert set(ph) == {"admission", "prefill", "grow", "eviction", "tables",
+                       "decode", "decode.dispatch", "decode.emit",
+                       "queue_wait"}
     assert all(v >= 0 for v in ph.values())
