@@ -413,7 +413,8 @@ class FlashDecodeDescriptor(KernelDescriptor):
     @classmethod
     def from_operands(cls, q, k_pool, block_tables):
         s, h, hd = q.shape
-        pages, page_size, hkv, _ = k_pool.shape
+        # one layer's pool, or the last four dims of a stack of them
+        pages, page_size, hkv, _ = k_pool.shape[-4:]
         return cls(num_seqs=s, pages=pages, page_size=page_size,
                    max_blocks=block_tables.shape[1], num_heads=h,
                    num_kv_heads=hkv, head_dim=hd,

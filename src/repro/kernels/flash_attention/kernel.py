@@ -304,17 +304,18 @@ def build_fused_flash_kernel(*, schedule: FlashTileSchedule,
 # pulled from the pool by a table-driven BlockSpec index map
 # ---------------------------------------------------------------------------
 
-def _decode_flash_kernel(tbl_ref, *refs, page_size, num_kv_heads, rep,
-                         scale, kv_quant=False):
+def _decode_flash_kernel(tbl_ref, layer_ref, *refs, page_size,
+                         num_kv_heads, rep, scale, kv_quant=False):
     """One grid step of the paged decode walk.
 
     ``tbl_ref`` rows are ``(seq, page, k_len, first, last)``
     (:class:`~repro.core.schedule.DecodeTileSchedule`): the BlockSpec
-    index maps already pulled query row ``seq`` and pool page ``page``
-    into VMEM, so the body only masks the page tail (``k_len``), runs the
-    per-head online-softmax update, and drains the carry into the owned
-    output row at ``last`` — the same m/l/acc discipline as the fused
-    flash walk, batched over heads instead of query rows.
+    index maps already pulled query row ``seq`` and page ``page`` of
+    layer ``layer_ref[0]``'s pool into VMEM, so the body only masks the
+    page tail (``k_len``), runs the per-head online-softmax update, and
+    drains the carry into the owned output row at ``last`` — the same
+    m/l/acc discipline as the fused flash walk, batched over heads
+    instead of query rows.
 
     The page is contracted as one ``(P * hkv, hd)`` matrix: column
     ``c`` of the ``(h, P * hkv)`` score tile is position ``c // hkv`` of
@@ -397,15 +398,19 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
                               interpret: bool = False):
     """Generate ONE pallas_call executing a whole paged decode step.
 
-    Returns ``f(table, q:(S,h,hd), k_pool:(pages,P,hkv,hd), v_pool) ->
-    (S,h,hd)`` where ``table`` is the runtime ``(max_tiles, 5)`` int32
-    tile table (:meth:`DecodeTileSchedule.tables`).  Unlike the fused
-    flash kernel's trace-time table, this one is a *scalar-prefetch
-    operand*: the batch composition is data, so the kernel compiles once
-    per pool geometry and the churning batch never retraces.  The
-    BlockSpec index maps read the table — grid step ``t`` stages exactly
-    query row ``table[t, 0]`` and pool page ``table[t, 1]``, which is
-    how the walk touches only live pages (DESIGN.md §12)."""
+    Returns ``f(table, layer, q:(S,h,hd), k_pool:(L,pages,P,hkv,hd),
+    v_pool) -> (S,h,hd)`` where ``table`` is the runtime ``(max_tiles,
+    5)`` int32 tile table (:meth:`DecodeTileSchedule.tables`) and
+    ``layer`` a ``(1,)`` int32 index into the pools' leading layer dim.
+    Both are *scalar-prefetch operands*: the batch composition and the
+    layer are data, so the kernel compiles once per pool geometry and
+    neither the churning batch nor the layer scan retraces.  The
+    BlockSpec index maps read them — grid step ``t`` stages exactly
+    query row ``table[t, 0]`` and page ``table[t, 1]`` of layer
+    ``layer[0]``, straight from the whole stack of pools the decode
+    step carries through its layer scan, so no layer's pool is ever
+    sliced out or copied (DESIGN.md §12).  The table stays the first
+    operand: the benchmark's trace tells the kernel apart by it."""
     S, P = schedule.num_seqs, schedule.page_size
     h, hkv, hd = num_heads, num_kv_heads, head_dim
     kv_dtype = kv_dtype or dtype
@@ -413,26 +418,30 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
                              num_kv_heads=hkv, rep=h // hkv,
                              scale=hd ** -0.5, kv_quant=kv_quant)
 
+    def page(t, tbl, layer):
+        return (layer[0], tbl[t, 1], 0, 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, h, hd), lambda t, tbl: (tbl[t, 0], 0, 0)),
-        pl.BlockSpec((1, P, hkv, hd),
-                     lambda t, tbl: (tbl[t, 1], 0, 0, 0)),
-        pl.BlockSpec((1, P, hkv, hd),
-                     lambda t, tbl: (tbl[t, 1], 0, 0, 0)),
+        pl.BlockSpec((1, h, hd), lambda t, tbl, layer: (tbl[t, 0], 0, 0)),
+        pl.BlockSpec((pl.squeezed, 1, P, hkv, hd), page),
+        pl.BlockSpec((pl.squeezed, 1, P, hkv, hd), page),
     ]
     if kv_quant:
         # per-token dequant scales of the walked page, one per score
-        # column (DESIGN.md §13)
+        # column (DESIGN.md §13); the caller passes the layer's rows
         in_specs += [
-            pl.BlockSpec((1, P * hkv), lambda t, tbl: (tbl[t, 1], 0)),
-            pl.BlockSpec((1, P * hkv), lambda t, tbl: (tbl[t, 1], 0)),
+            pl.BlockSpec((1, P * hkv),
+                         lambda t, tbl, layer: (tbl[t, 1], 0)),
+            pl.BlockSpec((1, P * hkv),
+                         lambda t, tbl, layer: (tbl[t, 1], 0)),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # the runtime tile table
+        num_scalar_prefetch=2,  # the runtime tile table, the layer
         grid=(schedule.max_tiles,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, hd), lambda t, tbl: (tbl[t, 0], 0, 0)),
+        out_specs=pl.BlockSpec((1, h, hd),
+                               lambda t, tbl, layer: (tbl[t, 0], 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),   # running max
             pltpu.VMEM((h, 1), jnp.float32),   # running denom

@@ -106,18 +106,28 @@ engine.register_family("flash_attention_bwd", planner=plan_flash_bwd,
 
 def execute_decode(desc: FlashDecodeDescriptor, plan: FlashDecodePlan,
                    q, k_pool, v_pool, block_tables, lengths, *,
-                   k_scale=None, v_scale=None, interpret: bool = False):
+                   k_scale=None, v_scale=None, layer=None,
+                   interpret: bool = False):
     """Engine executor: run one planned paged decode-attention step.
 
     The kernel is cached on the static pool geometry alone; the batch
     composition (block tables + lengths) becomes the runtime tile table,
     built with jnp ops at trace time and shipped as a scalar-prefetch
     operand — so a churning batch re-enters the same compiled launch.
-    KV-int8 pools (DESIGN.md §13) ride the same launch: per-token scale
-    rows ``(pages, page_size)`` join as two extra table-indexed operands.
+    The pools are either one layer's ``(pages, P, hkv, hd)`` or the
+    whole stack ``(L, pages, P, hkv, hd)`` with ``layer`` the index to
+    read; a single layer runs as a stack of one.  KV-int8 pools
+    (DESIGN.md §13) ride the same launch: the walked layer's per-token
+    scale rows ``(pages, page_size)`` join as two extra table-indexed
+    operands.
     """
     engine.count_launches("flash_decode", 1, fused=True)
     kv_quant = k_scale is not None
+    if layer is None:
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+        if kv_quant:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+    layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     schedule = plan.tile_schedule()
     key = desc.cache_key() + ("decode", canonical_dtype(k_pool.dtype),
                               kv_quant, interpret)
@@ -130,11 +140,11 @@ def execute_decode(desc: FlashDecodeDescriptor, plan: FlashDecodePlan,
     if kv_quant:
         # One scale per score column (position-major, KV head minor).
         def per_column(sc):
-            return jnp.repeat(sc.astype(jnp.float32), desc.num_kv_heads,
-                              axis=1)
-        return kernel(table, q, k_pool, v_pool, per_column(k_scale),
+            return jnp.repeat(sc[layer[0]].astype(jnp.float32),
+                              desc.num_kv_heads, axis=1)
+        return kernel(table, layer, q, k_pool, v_pool, per_column(k_scale),
                       per_column(v_scale))
-    return kernel(table, q, k_pool, v_pool)
+    return kernel(table, layer, q, k_pool, v_pool)
 
 
 engine.register_family("flash_decode", planner=plan_flash_decode,
@@ -142,21 +152,25 @@ engine.register_family("flash_decode", planner=plan_flash_decode,
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
-                           k_scale=None, v_scale=None) -> jax.Array:
+                           k_scale=None, v_scale=None,
+                           layer=None) -> jax.Array:
     """One decode step against a paged KV pool (DESIGN.md §12).
 
     q: (S, h, hd) — one query row per decode slot; k_pool/v_pool:
-    (pages, page_size, hkv, hd); block_tables: (S, max_blocks) int32 page
+    (pages, page_size, hkv, hd), or with ``layer`` the whole stack
+    (L, pages, page_size, hkv, hd) of which layer ``layer`` (an int32
+    scalar) is read in place; block_tables: (S, max_blocks) int32 page
     ids; lengths: (S,) live KV length per slot (0 = inactive, output row
     is zeros).  Returns (S, h, hd).
 
     With int8 pools, ``k_scale``/``v_scale`` are the per-token dequant
-    rows ``(pages, page_size)`` f32 (DESIGN.md §13) — same launch count,
-    the scales fold into the score/PV algebra in-kernel.
+    rows ``(pages, page_size)`` f32 (``(L, pages, page_size)`` with
+    ``layer``; DESIGN.md §13) — same launch count, the scales fold into
+    the score/PV algebra in-kernel.
     """
     desc = FlashDecodeDescriptor.from_operands(q, k_pool, block_tables)
     return engine.dispatch(desc, q, k_pool, v_pool, block_tables, lengths,
-                           k_scale=k_scale, v_scale=v_scale)
+                           k_scale=k_scale, v_scale=v_scale, layer=layer)
 
 
 def _flat_desc(causal, qf, kf) -> FlashDescriptor:

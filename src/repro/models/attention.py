@@ -81,12 +81,22 @@ class PagedKVCache(NamedTuple):
 
     ``k_scale``/``v_scale`` (int8 pools only, else None): per-token f32
     dequant scales, same page layout as the pools with the head/feature
-    dims reduced away — ``(num_pages, page_size)``."""
+    dims reduced away — ``(num_pages, page_size)``.
+
+    ``layer`` is None except inside the decode step's layer scan
+    (``repro.models.blocks.stack_apply``): there the pools (and scales)
+    are the whole stack ``(L, num_pages, ...)``, carried through the scan
+    and donated by the caller, and ``layer`` is the scalar index of the
+    layer being applied.  :func:`_paged_decode` then writes the new row
+    at ``[layer, page, offset]`` in place and the decode kernel reads
+    that layer's pages straight from the stack, so no layer's pool is
+    ever sliced out or copied back (DESIGN.md §12)."""
     k: jax.Array       # (num_pages, page_size, h_kv, hd)
     v: jax.Array       # (num_pages, page_size, h_kv, hd)
     tables: jax.Array  # (num_slots, max_blocks) int32 page ids
     k_scale: Optional[jax.Array] = None  # (num_pages, page_size) f32
     v_scale: Optional[jax.Array] = None
+    layer: Optional[jax.Array] = None    # int32 scalar, stacked pools only
 
 
 def init_paged_kv_cache(num_slots, spec: PageSpec, n_kv, head_dim,
@@ -264,14 +274,21 @@ def _paged_decode(cfg, cache: PagedKVCache, q, k, v, pos2d, dt, g):
     into page ``tables[i, pos // P]`` at offset ``pos % P`` — inactive
     rows scatter to an out-of-bounds page id, which ``mode="drop"``
     discards, so dead slots never touch the pool (their *output* rows
-    are garbage the step-level merge masks).  Attention runs either through the engine's
-    ``flash_decode`` family (pallas backend: ONE launch walking the
-    runtime :class:`~repro.core.schedule.DecodeTileSchedule`) or the XLA
-    gather formulation (``ref_paged_decode_attention``'s math)."""
+    are garbage the step-level merge masks).
+
+    With ``cache.layer`` set, the pools are the whole layer stack the
+    decode step carries through its layer scan: the row is written at
+    ``[layer, page, offset]`` into the carry itself, and attention reads
+    layer ``layer`` of the stack, so the pool is updated in place and
+    never sliced per layer (DESIGN.md §12).  Attention runs either
+    through the engine's ``flash_decode`` family (pallas backend: ONE
+    launch walking the runtime
+    :class:`~repro.core.schedule.DecodeTileSchedule`) or the XLA gather
+    formulation (``ref_paged_decode_attention``'s math)."""
     S = q.shape[0]
-    pages, P = cache.k.shape[0], cache.k.shape[1]
+    layer = cache.layer
+    pages, P, hkv, hd = cache.k.shape[-4:]
     B = cache.tables.shape[1]
-    hkv, hd = cache.k.shape[2], cache.k.shape[3]
     pos = pos2d[:, 0] if pos2d.shape[0] == S else \
         jnp.broadcast_to(pos2d[:, 0], (S,))
     active = pos >= 0
@@ -281,6 +298,11 @@ def _paged_decode(cfg, cache: PagedKVCache, q, k, v, pos2d, dt, g):
     # mode="drop" discards (NOT -1 — negative indices wrap in jnp).
     pid = jnp.where(active, blk, pages)
     off = safe % P
+    row = (pid, off) if layer is None else (layer, pid, off)
+
+    def write(pool, new):
+        return pool.at[row].set(new.astype(pool.dtype), mode="drop")
+
     ks_new = vs_new = None
     if cache.k_scale is not None:
         # int8 pools (DESIGN.md §13): symmetric per-token quantization at
@@ -293,35 +315,33 @@ def _paged_decode(cfg, cache: PagedKVCache, q, k, v, pos2d, dt, g):
             return qv.astype(jnp.int8), s.astype(jnp.float32)
         kq, ks = _qrow(k[:, 0])
         vq, vs = _qrow(v[:, 0])
-        k_new = cache.k.at[pid, off].set(kq, mode="drop")
-        v_new = cache.v.at[pid, off].set(vq, mode="drop")
-        ks_new = cache.k_scale.at[pid, off].set(ks, mode="drop")
-        vs_new = cache.v_scale.at[pid, off].set(vs, mode="drop")
+        k_new, v_new = write(cache.k, kq), write(cache.v, vq)
+        ks_new, vs_new = write(cache.k_scale, ks), write(cache.v_scale, vs)
     else:
-        k_new = cache.k.at[pid, off].set(k[:, 0].astype(cache.k.dtype),
-                                         mode="drop")
-        v_new = cache.v.at[pid, off].set(v[:, 0].astype(cache.v.dtype),
-                                         mode="drop")
-    new_cache = PagedKVCache(k_new, v_new, cache.tables, ks_new, vs_new)
+        k_new, v_new = write(cache.k, k[:, 0]), write(cache.v, v[:, 0])
+    new_cache = PagedKVCache(k_new, v_new, cache.tables, ks_new, vs_new,
+                             layer)
     lengths = jnp.where(active, pos + 1, 0)
 
     if get_config().backend == "pallas" and not cfg.attn_logit_softcap:
         from repro.kernels.flash_attention import paged_decode_attention
         out = paged_decode_attention(q[:, 0], k_new, v_new, cache.tables,
                                      lengths, k_scale=ks_new,
-                                     v_scale=vs_new)[:, None]
+                                     v_scale=vs_new, layer=layer)[:, None]
         return new_cache, out
     # XLA fallback: gather the block-table pages into a contiguous view
     # (gathered column j holds absolute position j) and mask j >= length
     # — identical math to ref_paged_decode_attention, expressed through
     # the shared _attend so float ops match the dense decode path.
     gidx = jnp.clip(cache.tables, 0, pages - 1)
-    gk = k_new[gidx]  # (S, B, P, hkv, hd)
-    gv = v_new[gidx]
+    pages_of = (lambda pool: pool[gidx]) if layer is None else \
+        (lambda pool: pool[layer, gidx])  # one gather off the stack
+    gk = pages_of(k_new)  # (S, B, P, hkv, hd)
+    gv = pages_of(v_new)
     if ks_new is not None:
         # dequant in f32 before entering the shared attention math
-        gk = gk.astype(jnp.float32) * ks_new[gidx][..., None, None]
-        gv = gv.astype(jnp.float32) * vs_new[gidx][..., None, None]
+        gk = gk.astype(jnp.float32) * pages_of(ks_new)[..., None, None]
+        gv = gv.astype(jnp.float32) * pages_of(vs_new)[..., None, None]
     gk = _repeat_kv(gk.reshape(S, B * P, hkv, hd).astype(dt), g)
     gv = _repeat_kv(gv.reshape(S, B * P, hkv, hd).astype(dt), g)
     live = jnp.arange(B * P)[None, :] < lengths[:, None]  # (S, B*P)

@@ -16,8 +16,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from repro.models import common
-from repro.models.attention import (attention_apply, attention_init,
-                                    init_kv_cache, init_paged_kv_cache)
+from repro.models.attention import (PagedKVCache, attention_apply,
+                                    attention_init, init_kv_cache,
+                                    init_paged_kv_cache)
 from repro.models.mlp import mlp_apply, mlp_init
 from repro.models.moe import moe_apply, moe_init
 from repro.models.rglru import init_recurrent_state, rglru_apply, rglru_init
@@ -171,6 +172,21 @@ def _group_apply(group_params, cfg, x, positions, group_cache, enc_out):
     return x, new_cache, aux
 
 
+def _pools_out(group_cache):
+    """Split a stacked group cache for the layer scan: ``(xs, pools)``.
+
+    Each :class:`PagedKVCache` block leaves only its block tables in
+    ``xs``; its stacked pools (and int8 scales) go to ``pools``, which
+    the scan *carries*.  A pool in ``xs``/``ys`` would be sliced out per
+    layer, written back per layer and copied whole after the loop; as a
+    carry it aliases the donated cache and is updated in place
+    (DESIGN.md §12).  Every other leaf keeps its ``xs`` path."""
+    pools = {k: c._replace(tables=None) for k, c in group_cache.items()
+             if isinstance(c, PagedKVCache)}
+    xs = {k: c.tables if k in pools else c for k, c in group_cache.items()}
+    return xs, pools
+
+
 def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
     """Returns (x, new_cache, aux_loss_sum)."""
     groups, rem = stack_layout(cfg)
@@ -178,10 +194,16 @@ def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
     new_group_cache = None
 
     if groups:
+        gcache, pools = None, {}
+        if cache is not None:
+            gcache, pools = _pools_out(cache["groups"])
+
         def body(carry, xs):
-            h, aux = carry
+            h, aux, pools = carry
             if cache is not None:
-                gp, gc = xs
+                gp, gc, layer = xs
+                gc = {k: pools[k]._replace(tables=c, layer=layer)
+                      if k in pools else c for k, c in gc.items()}
             else:
                 gp, gc = xs, None
             # Name the (bf16) carry so the remat policy saves exactly this
@@ -190,7 +212,11 @@ def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
             # +3.8 GiB/device on starcoder2, EXPERIMENTS.md §Perf).
             h = checkpoint_name(h, "block_carry")
             h, nc, a = _group_apply(gp, cfg, h, positions, gc, enc_out)
-            return (h, aux + a), nc
+            if nc is not None:
+                pools = {k: nc[k]._replace(tables=None, layer=None)
+                         for k in pools}
+                nc = {k: c for k, c in nc.items() if k not in pools}
+            return (h, aux + a, pools), nc
 
         if cfg.remat:
             body = jax.checkpoint(
@@ -198,9 +224,14 @@ def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
                 policy=jax.checkpoint_policies.save_only_these_names(
                     "block_carry"),
                 prevent_cse=False)
-        xs = (params["groups"], cache["groups"]) if cache is not None \
-            else params["groups"]
-        (x, aux_total), new_group_cache = jax.lax.scan(body, (x, aux_total), xs)
+        xs = (params["groups"], gcache, jnp.arange(groups)) \
+            if cache is not None else params["groups"]
+        (x, aux_total, pools), ys = jax.lax.scan(
+            body, (x, aux_total, pools), xs)
+        if cache is not None:
+            new_group_cache = {
+                k: pools[k]._replace(tables=c.tables) if k in pools
+                else ys[k] for k, c in cache["groups"].items()}
 
     new_rem = []
     for i, kind in enumerate(rem):

@@ -11,11 +11,15 @@ The topology is described inside a module fixture — only the worker
 that runs this file loads the TPU compiler — and the tests skip where it
 cannot be described.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.core import GemmDescriptor, engine, plan_gemm, use
 from repro.kernels.flash_attention import (flash_attention,
                                            paged_decode_attention)
@@ -23,6 +27,9 @@ from repro.kernels.gemm import gemm
 from repro.kernels.grouped_gemm import grouped_gemm
 from repro.kernels.ssd_chunk import ssd_chunk_scan
 from repro.kernels.transpose import transpose
+from repro.models.attention import PageSpec
+from repro.runtime.pages import init_serving_cache
+from repro.runtime.steps import make_paged_serve_step, param_shapes
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -113,6 +120,62 @@ def test_flash_decode(one_chip):
                             ((8, 16, 128), BF16), pool, pool,
                             ((8, 64), I32), ((8,), I32))
     assert "tpu_custom_call" in text
+
+
+# One HLO instruction: its name, result type and opcode.
+_INSTR = re.compile(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?) ([\w\-]+)\(")
+
+
+def test_paged_serve_step_keeps_pool_in_place(one_chip):
+    # The continuous-batching decode step at qwen3-0.6b widths over three
+    # scanned layers, 8 slots and the chat cell's 1,792 pages of 16 (a
+    # stack of pools too large for VMEM, as every served pool is: a
+    # smaller one may be staged in VMEM around the loop and copied back),
+    # compiled with the donation the engine jits it with.  The stacked
+    # pools ride the layer scan as its carry and are written in place,
+    # so the program holds no copy, slice or update-slice of a whole
+    # stack or of one layer's pool, and the donated pools alias the
+    # step's output.
+    layers, pages, slots = 3, 1792, 8
+    cfg = dataclasses.replace(get_config("qwen3-0.6b"), num_layers=layers)
+    spec = PageSpec(num_pages=pages, page_size=16, max_blocks=64)
+
+    def chip(x):
+        dt = jnp.bfloat16 if jnp.issubdtype(x.dtype, jnp.floating) \
+            else x.dtype
+        return jax.ShapeDtypeStruct(x.shape, dt, sharding=one_chip)
+
+    params = jax.tree.map(chip, param_shapes(cfg))
+    cache = jax.tree.map(chip, jax.eval_shape(
+        lambda: init_serving_cache(cfg, slots, spec)))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    with use(backend="pallas", interpret=False):
+        text = jax.jit(make_paged_serve_step(cfg), donate_argnums=(1,)).lower(
+            params, cache, jax.ShapeDtypeStruct((slots, 1), jnp.int32,
+                                                sharding=one_chip),
+            rows, rows.update(dtype=jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text
+
+    dims = (f"[{layers},{pages},16,8,128]", f"[{pages},16,8,128]")
+    moved, pool_params = [], []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, result, op = m.groups()
+        if not any(d + "{" in result for d in dims):
+            continue
+        if op == "parameter" and name.startswith("cache"):
+            pool_params.append(int(re.search(r"parameter\((\d+)\)",
+                                             line).group(1)))
+        if re.search(r"copy|dynamic-slice|dynamic-update-slice",
+                     f"{name} {op}"):
+            moved.append(line.strip()[:160])
+    assert not moved, "pool-sized copies or slices:\n" + "\n".join(moved)
+    assert len(pool_params) == 2  # the stacked K and V pools
+    alias = re.search(r"input_output_alias=\{(.*?) \}", text).group(1)
+    for n in pool_params:
+        assert f": ({n}, {{}}" in alias, (n, alias)
 
 
 def test_fused_grouped_gemm(one_chip):
