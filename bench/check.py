@@ -50,7 +50,13 @@ def reference_module(name: str):
 
 
 def dims_key(dims: Dict) -> tuple:
-    return tuple(sorted(dims.items()))
+    """``dims`` as a hashable key for a reference's static argument:
+    sorted ``(key, value)`` pairs, list values (a layer pattern) as
+    tuples."""
+    def frozen(v):
+        return tuple(frozen(x) for x in v) if isinstance(v, list) else v
+
+    return tuple(sorted((k, frozen(v)) for k, v in dims.items()))
 
 
 def teacher_forced(prompt: np.ndarray, served: np.ndarray, n_at: int):

@@ -1,9 +1,10 @@
 """Kernels: ``flash_decode`` (paged decode attention) against its roofline.
 
-The least time is, per decode step and layer, the larger of the
-operations over peak bf16 and the bytes over peak HBM bandwidth, where
-the bytes are the live KV of every live slot plus its query and output
-(``bench/work.py``); the time is the device time of the trace's
+The least time is, per decode step and attention layer, the larger of
+the operations over peak bf16 and the bytes over peak HBM bandwidth,
+where the bytes are the live KV of every live slot plus its query and
+output (the model family's ``decode_attention``), times the family's
+``attention_layers``; the time is the device time of the trace's
 ``flash_decode`` operations in the window.  Every gap between tokens
 holds one decode step, so this moves ``itl_p95_ms``."""
 from bench import work
@@ -15,6 +16,6 @@ def read(r):
         return None
     least = 0.0
     for contexts in r.work.decode_contexts:
-        flops, nbytes = work.decode_attention(r.dims, contexts)
+        flops, nbytes = r.model.decode_attention(r.dims, contexts)
         least += work.min_seconds(flops, nbytes, r.peaks)[0]
-    return 100.0 * least * r.dims["num_hidden_layers"] / t
+    return 100.0 * least * r.model.attention_layers(r.dims) / t

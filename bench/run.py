@@ -3,8 +3,11 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell, its configuration file, its traffic file and its metrics are
-found by name through ``BENCHMARK.json``.  The run makes the weights and
-the schedule from ``--seed``, warms every program the cell's traffic
+found by name through ``BENCHMARK.json``; the configuration names its
+model family (``bench/models/<model>.py``), through which the harness
+makes the weights, hands them to the program, checks the program's sizes
+and counts the work.  The run makes the weights and the schedule from
+``--seed``, warms every program the cell's traffic
 uses (set-up), serves a lead-in, then measures ``--seconds`` of open-loop
 serving through ``ContinuousBatchingEngine`` on the pallas backend.
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
@@ -41,15 +44,6 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
 NO_CHIP = 3
 DRAIN_LIMIT_S = 60.0
 
-# The program's sizes that each configuration key of the published
-# config.json must equal.
-PUBLISHED_TO_PROGRAM = {
-    "num_hidden_layers": "num_layers", "hidden_size": "d_model",
-    "num_attention_heads": "num_heads", "num_key_value_heads":
-    "num_kv_heads", "head_dim": "head_dim", "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings"}
-
 
 @dataclasses.dataclass
 class Cell:
@@ -76,6 +70,8 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     conf = {x["name"]: x for x in bench["configs"]}[c["config"]]
     with open(os.path.join(root, conf["file"])) as f:
         config = json.load(f)
+    check_cuts(config)
+    family(config["model"])  # a missing family is refused here, early
     with open(os.path.join(root, "bench", "traffic",
                            c["traffic"] + ".json")) as f:
         traffic = json.load(f)
@@ -84,37 +80,58 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
                 [m for m in bench["per_layer"] if applies(m, workload)])
 
 
-def model_dims(config: Dict) -> Dict:
-    """The sizes the reference, the weights and the work functions read."""
-    d = {k: config[k] for k in (
-        "num_hidden_layers", "hidden_size", "num_attention_heads",
-        "num_key_value_heads", "intermediate_size", "vocab_size",
-        "rope_theta", "rms_norm_eps")}
-    d["head_dim"] = config.get("head_dim", d["hidden_size"]
-                               // d["num_attention_heads"])
-    d.update(config["architecture"])
-    return d
+def check_cuts(config: Dict) -> None:
+    """Refuse a configuration that cuts a key (``reduced``) without giving
+    its published value (``published``), gives one for a key it does not
+    cut, or does not state the deployment it stands for."""
+    cut, published = set(config["reduced"]), set(config.get("published", {}))
+    bad = [f"{k}: cut without its published value"
+           for k in sorted(cut - published)]
+    bad += [f"{k}: a published value, but not in reduced"
+            for k in sorted(published - cut)]
+    if not config.get("deployment"):
+        bad.append("no deployment stated")
+    if bad:
+        raise SystemExit("configuration's cuts: " + "; ".join(bad))
+
+
+def family(name: str):
+    """The model family module that a configuration names under
+    ``"model"`` (``bench/models/__init__.py`` lists what it defines):
+    ``bench/models/<name>.py``, or a ``.py`` file named by its path from
+    the checkout's root.  Loaded once per process."""
+    rel = name if name.endswith(".py") else os.path.join(
+        "bench", "models", name + ".py")
+    path = os.path.join(ROOT, rel)
+    key = "bench_family_" + "".join(c if c.isalnum() else "_" for c in rel)
+    if key in sys.modules:
+        return sys.modules[key]
+    if not os.path.isfile(path):
+        raise SystemExit(f"no model family {name!r}: {path} does not exist")
+    spec = importlib.util.spec_from_file_location(key, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[key] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def program_config(config: Dict):
-    """The program's registered configuration, refused unless it serves
-    exactly the published sizes in the stated dtype."""
-    from repro.configs import get_config
-    cfg = get_config(config["arch"])
-    want = dict(model_dims(config),
-                tie_word_embeddings=config["tie_word_embeddings"])
-    bad = [f"{k}: program {getattr(cfg, PUBLISHED_TO_PROGRAM[k])!r} != "
-           f"published {want[k]!r}" for k in PUBLISHED_TO_PROGRAM
-           if getattr(cfg, PUBLISHED_TO_PROGRAM[k]) != want[k]]
-    bad += [f"{k}: program {getattr(cfg, k)!r} != {want[k]!r}"
-            for k in ("qk_norm", "qkv_bias") if getattr(cfg, k) != want[k]]
+    """The program's configuration that serves ``config``, as its family
+    builds it (cut keys at their served values)."""
+    return family(config["model"]).program_config(config)
+
+
+def check_program(cfg, config: Dict, model) -> None:
+    """Refuse a program configuration ``cfg`` unless it serves exactly
+    the sizes that ``config`` states (cut ones as cut), in the stated
+    dtype; ``model`` is the configuration's family module."""
+    bad = model.differences(cfg, config)
     bad += [f"{k}: program {getattr(cfg, k)} != {config['torch_dtype']}"
             for k in ("dtype", "kv_cache_dtype")
             if getattr(cfg, k) != config["torch_dtype"]]
     if bad:
-        raise SystemExit("configuration differs from its source: "
+        raise SystemExit("the program does not serve the configuration: "
                          + "; ".join(bad))
-    return cfg
 
 
 def enable_compile_cache() -> str:
@@ -160,6 +177,7 @@ def engine_misses() -> int:
 class Reading:
     """What a per-layer reader (``bench/metrics/<name>.py``) reads."""
     dims: Dict
+    model: object                 # the configuration's family module
     peaks: Dict
     window_s: float
     work: object                  # bench.serve.WindowWork
@@ -210,14 +228,15 @@ def run_cell(cell: Cell, cfg, seed: int, seconds: float, trace: bool, *,
     from bench import trace as trace_lib
 
     config, traffic = cell.config, cell.traffic
-    dims = model_dims(config)
-    arch = config["architecture"]
+    model = family(config["model"])
+    check_program(cfg, config, model)
+    dims = model.dims(config)
     geo = config["serving"]
     compiles = CompileCounter()
 
     t0 = time.time()
-    w = jax.block_until_ready(weights.make(dims, arch, seed))
-    params = weights.to_program(w, arch)
+    w = jax.block_until_ready(model.make(dims, seed))
+    params = model.to_program(w, dims)
     weights.check_layout(params, jax.eval_shape(lambda: load_params(cfg)))
     t_weights = time.time() - t0
     plan = load.build(traffic, seed, seconds, dims["vocab_size"])
@@ -330,8 +349,10 @@ def run_cell(cell: Cell, cfg, seed: int, seconds: float, trace: bool, *,
             metrics[m["name"]] = {"value": end_to_end(m["name"], run),
                                   "unit": m["unit"]}
     else:
-        reading = Reading(dims, peaks, t_close - t_open, work, phase,
-                          summary, memory)
+        reading = Reading(dims=dims, model=model, peaks=peaks,
+                          window_s=t_close - t_open, work=work,
+                          phase_s=phase, trace=summary,
+                          memory_peak_bytes=memory)
         for m in cell.per_layer:
             v = read_metric(m["name"], reading)
             if v is not None:

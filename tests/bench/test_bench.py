@@ -1,8 +1,9 @@
 """CPU tests of the benchmark: schedules, end-to-end arithmetic, work
 functions, the trace reduction on a trace recorded on a TPU v5e, the
 float32 reference against the program, and whole runs at a tiny size in
-which the output check passes, its fp8 control does not, and a token
-altered where it is produced fails the check.
+which the output check passes, its fp8 control does not, a token altered
+where it is produced fails the check, and a model family of the tests'
+own drives the run.
 
 Run from the repository root:  python -m pytest -q tests/bench
 Nothing here describes or touches a TPU.
@@ -29,6 +30,9 @@ from bench import trace as trace_lib  # noqa: E402
 
 # 0.6 s of qwen2.5-3b.prefill-backlog traced on a TPU v5e (gzip).
 TRACE = os.path.join(ROOT, "bench", "testdata", "backlog.xplane.pb.gz")
+# A family of the tests' own: Qwen handed to the program untied.
+UNTIED = "tests/bench/testdata/untied_family.py"
+QWEN = run.family("qwen")
 
 
 def _bench():
@@ -179,20 +183,21 @@ def test_gemm_work_by_hand():
     assert work.gemm_bytes((2, 3, 4)) == 2 * (6 + 12 + 8)
     assert work.min_seconds(48, 52, PEAKS) == (5.2, "memory")
     assert work.min_seconds(5000, 10, PEAKS) == (50.0, "compute")
-    shapes = work.layer_gemms(DIMS, 3)
+    shapes = QWEN.layer_gemms(DIMS, 3)
     assert shapes == [(3, 8, 8), (3, 8, 4), (3, 8, 4), (3, 8, 8),
                       (3, 8, 16), (3, 8, 16), (3, 16, 8)]
-    assert work.step_gemms(DIMS, 3, 1)[-1] == (1, 8, 32)
-    assert len(work.step_gemms(DIMS, 3, 1)) == 15
+    assert QWEN.step_gemms(DIMS, 3, 1)[-1] == (1, 8, 32)
+    assert len(QWEN.step_gemms(DIMS, 3, 1)) == 15
 
 
 def test_attention_work_by_hand():
-    flops, nbytes = work.decode_attention(DIMS, [3, 5])
+    flops, nbytes = QWEN.decode_attention(DIMS, [3, 5])
     assert flops == 4 * 8 * 4 * 2           # 4 * sum(ctx) * hq * hd
     assert nbytes == 2 * (2 * 8 * 2 * 2 + 2 * 2 * 4 * 2)
-    assert work.causal_attention_flops(DIMS, 3) == 4 * 4 * 2 * 6
-    pf = work.prefill_flops(DIMS, 3)
-    mm = sum(work.gemm_flops(s) for s in work.step_gemms(DIMS, 3, 1))
+    assert QWEN.causal_attention_flops(DIMS, 3) == 4 * 4 * 2 * 6
+    assert QWEN.attention_layers(DIMS) == 2
+    pf = QWEN.prefill_flops(DIMS, 3)
+    mm = sum(work.gemm_flops(s) for s in QWEN.step_gemms(DIMS, 3, 1))
     assert pf == mm + 2 * 4 * 4 * 2 * 6
 
 
@@ -225,6 +230,9 @@ def test_reduction_of_a_chip_trace(tmp_path):
     assert s.devices == 1
     assert 0 < s.busy_s <= s.window_s
     assert {"gemm", "flash_decode", "flash_attention"} <= set(s.family_s)
+    # as the trace reduced before kernels were also matched by name
+    assert s.family_s == {"gemm": 0.27381668, "flash_decode": 0.205282936,
+                          "flash_attention": 0.009401712}
     assert sum(s.family_s.values()) <= s.busy_s
     assert 0 < len(s.device_ops) <= 10 and 0 < len(s.idle_gaps) <= 10
     assert all(k.startswith("host:") for k, _ in s.idle_gaps)
@@ -236,18 +244,20 @@ def test_reduction_of_a_chip_trace(tmp_path):
 # the float32 reference against the program's XLA forward
 # ---------------------------------------------------------------------------
 
-def _tiny(arch: str, dtype: str):
+def _tiny(arch: str, dtype: str, **overrides):
     """A reduced program configuration and the matching config file."""
     from repro.configs import get_config, reduced_config
     cfg = reduced_config(get_config(arch), dtype=dtype,
-                         kv_cache_dtype=dtype, logits_dtype=dtype)
+                         kv_cache_dtype=dtype, logits_dtype=dtype,
+                         **overrides)
     base = run.load_cell({"qwen3-0.6b": "qwen3-0.6b.chat",
                           "qwen2.5-3b": "qwen2.5-3b.prefill-backlog"}[arch])
     conf = dict(base.config)
     conf.update(num_hidden_layers=cfg.num_layers, hidden_size=cfg.d_model,
                 num_attention_heads=cfg.num_heads,
                 num_key_value_heads=cfg.num_kv_heads, head_dim=cfg.head_dim,
-                intermediate_size=cfg.d_ff, vocab_size=cfg.vocab_size)
+                intermediate_size=cfg.d_ff, vocab_size=cfg.vocab_size,
+                tie_word_embeddings=cfg.tie_embeddings)
     return cfg, conf, base
 
 
@@ -256,17 +266,17 @@ def test_reference_matches_the_program(arch):
     import jax.numpy as jnp
     from repro.core import use
     from repro.runtime.steps import forward
-    from bench import weights
     from bench.reference import qwen
 
     cfg, conf, _ = _tiny(arch, "float32")
-    dims = run.model_dims(conf)
+    model = run.family(conf["model"])
+    dims = model.dims(conf)
     arch_flags = conf["architecture"]
-    w = weights.make(dims, arch_flags, 3, dtype=jnp.float32)
+    w = model.make(dims, 3, dtype=jnp.float32)
     tokens = np.random.default_rng(0).integers(0, dims["vocab_size"],
                                                qwen.Q_BLOCK)
     with use(backend="xla"):
-        logits, _, _ = forward(cfg, weights.to_program(w, arch_flags),
+        logits, _, _ = forward(cfg, model.to_program(w, dims),
                                {"tokens": jnp.asarray(tokens)[None]})
     ref = qwen.logits_at(w, jnp.asarray(tokens, jnp.int32),
                          jnp.arange(qwen.Q_BLOCK), dims=check.dims_key(dims))
@@ -298,14 +308,20 @@ TINY_TRAFFIC = {
 TINY_LIMIT = 0.012
 
 
-def _tiny_run(seed, *, tamper=None, control=False):
-    from bench import control as control_lib
-    cfg, conf, base = _tiny("qwen3-0.6b", "bfloat16")
+def _tiny_cell(**overrides):
+    """qwen3-0.6b's chat cell at a tiny size, and its program
+    configuration (``overrides``: of the program's sizes and flags)."""
+    cfg, conf, base = _tiny("qwen3-0.6b", "bfloat16", **overrides)
     conf["serving"] = {"slots": 4, "page_size": 16, "pages": 64,
                        "max_context": 96}
     conf["check"] = {"served_logit_gap": TINY_LIMIT}
-    cell = run.Cell("tiny.chat", 1, conf, TINY_TRAFFIC, base.end_to_end,
-                    base.per_layer)
+    return cfg, run.Cell("tiny.chat", 1, conf, TINY_TRAFFIC,
+                         base.end_to_end, base.per_layer)
+
+
+def _tiny_run(seed, *, tamper=None, control=False):
+    from bench import control as control_lib
+    cfg, cell = _tiny_cell()
     out = {}
 
     def inspect(ref, w, dims, finished, rids, n_at):
@@ -359,6 +375,41 @@ def test_token_altered_where_produced_fails_the_check():
     assert gap["value"] > gap["limit"]
 
 
+def test_a_family_of_its_own_drives_the_whole_run(monkeypatch):
+    """A configuration naming another family module gets its weights,
+    hand-over, size check and work counts from that module alone: here
+    one whose program tree is untied, with an ``lm_head`` leaf."""
+    model = run.family(UNTIED)
+    cfg, cell = _tiny_cell(tie_embeddings=False)
+    cell.config["model"] = UNTIED
+    assert not cell.config["tie_word_embeddings"]
+    # A CPU trace holds no TPU planes: hand the readers device seconds.
+    summary = trace_lib.TraceSummary(
+        window_s=2.0, busy_s=1.0, devices=1,
+        family_s={"gemm": 0.5, "flash_decode": 0.25}, device_ops=[],
+        idle_gaps=[])
+    monkeypatch.setattr(trace_lib, "find_xplane", lambda log_dir: log_dir)
+    monkeypatch.setattr(trace_lib, "reduce", lambda path: summary)
+    model.CALLS.clear()
+    assert run.program_config(cell.config).tie_embeddings is False
+    res = run.run_cell(cell, cfg, 2 ** 31 + 99, 2.0, True,
+                       peaks=work.load_peaks("TPU v5 lite"),
+                       say=lambda *_: None)
+    assert res["correct"] is True
+    calls = set(model.CALLS)
+    assert {"program_config", "dims", "differences", "make",
+            "to_program"} <= calls
+    assert {"step_gemms", "attention_layers", "decode_attention",
+            "prefill_flops", "decode_flops"} <= calls
+    assert {"gemm_roofline", "flash_decode_roofline", "model.mfu"} <= set(
+        res["metrics"])
+    # the same run under Qwen's tied tree is refused by the layout check
+    cell.config["model"] = "qwen"
+    with pytest.raises(ValueError, match="program's layout"):
+        run.run_cell(cell, cfg, 2 ** 31 + 99, 2.0, False,
+                     say=lambda *_: None)
+
+
 def test_off_a_tpu_no_result_and_a_nonzero_exit(capsys):
     assert run.main(["--workload", "qwen3-0.6b.chat", "--seed", "1",
                      "--seconds", "1"]) == run.NO_CHIP
@@ -389,5 +440,7 @@ def test_configs_match_the_program():
     for conf in b["configs"]:
         with open(os.path.join(ROOT, conf["file"])) as f:
             c = json.load(f)
-        assert c["source"] == conf["source"] and c["reduced"] == []
-        run.program_config(c)
+        assert c["source"] == conf["source"]
+        assert c["reduced"] == conf["reduced"]
+        run.check_cuts(c)
+        run.check_program(run.program_config(c), c, run.family(c["model"]))

@@ -34,8 +34,8 @@ OLD_PHASES = {"admission": 0.25, "prefill": 1.5, "decode": 4.0,
 def _reading(phase_s, steps=40, prefills=(256, 512, 64)):
     work = WindowWork(decode_contexts=[[300, 600]] * steps,
                       prefills=list(prefills), steps=steps)
-    return run.Reading(dims={}, peaks={}, window_s=2.0, work=work,
-                       phase_s=dict(phase_s), trace=None,
+    return run.Reading(dims={}, model=None, peaks={}, window_s=2.0,
+                       work=work, phase_s=dict(phase_s), trace=None,
                        memory_peak_bytes=None)
 
 
