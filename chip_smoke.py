@@ -152,7 +152,7 @@ def logits_phase(cfg, params, prompt_len: int, seed: int):
                 params, batch)
         if backend == "pallas":
             custom_calls = lowered.as_text().count("tpu_custom_call")
-        logits, _ = lowered.compile()(params, batch)
+        logits, _, _ = lowered.compile()(params, batch)
         out[backend] = np.asarray(logits.astype(jnp.float32))
     ref, got = out["xla"], out["pallas"]
     check(bool(np.isfinite(got).all()), "pallas logits are finite")

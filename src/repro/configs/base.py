@@ -38,6 +38,12 @@ class ModelConfig:
     capacity_factor: float = 1.25
     moe_group: int = 1024
     moe_renormalize: bool = True
+    shared_expert_d_ff: int = 0     # a shared expert of this width, 0 = none
+    # The experts this chip holds: ``experts_held`` of them from
+    # ``first_expert_held`` (0 = all).  The router still scores all
+    # ``num_experts``; the layer computes only its own experts' share.
+    first_expert_held: int = 0
+    experts_held: int = 0
 
     # --- hybrid / ssm -------------------------------------------------------
     block_pattern: Tuple[str, ...] = ("attn",)
@@ -63,6 +69,15 @@ class ModelConfig:
     norm_eps: float = 1e-6
     tie_embeddings: bool = False
     embed_scale: bool = False
+    # Published scalars (Granite): the embedding times
+    # ``embedding_multiplier``, each residual branch times
+    # ``residual_multiplier``, attention scores times
+    # ``attention_multiplier`` (None = head_dim ** -0.5), logits over
+    # ``logits_scaling``.  A factor of 1 adds no operation.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     dtype: str = "bfloat16"
     kv_cache_dtype: str = "bfloat16"
     logits_dtype: str = "bfloat16"  # CE upcasts to fp32 in-reduction
@@ -76,6 +91,11 @@ class ModelConfig:
     def sub_quadratic(self) -> bool:
         """Eligible for long_500k: no global full-attention block."""
         return all(k in ("rec", "ssm", "local") for k in self.block_pattern)
+
+    @property
+    def held_experts(self) -> int:
+        """How many experts this chip holds (all when not cut)."""
+        return self.experts_held or self.num_experts
 
     @property
     def has_decoder(self) -> bool:
@@ -102,8 +122,8 @@ class ModelConfig:
             per_kind["ssm"] = d * (2 * d_in + 2 * g * n + h) + d_in * d + \
                 self.conv1d_width * conv_dim + conv_dim + 3 * h + d_in
         if self.num_experts:
-            ff = self.num_experts * (2 if not self.mlp_gated else 3) * d * f \
-                + d * self.num_experts
+            ff = self.held_experts * (2 if not self.mlp_gated else 3) * d * f \
+                + d * self.num_experts + 3 * d * self.shared_expert_d_ff
         elif self.mlp_gated:
             ff = 3 * d * f
         else:
@@ -126,7 +146,9 @@ class ModelConfig:
             return self.param_count()
         d, f = self.d_model, self.d_ff
         per_expert = (3 if self.mlp_gated else 2) * d * f
-        inactive = self.num_layers * (self.num_experts - self.num_experts_per_tok) \
+        held = self.held_experts
+        inactive = self.num_layers * (held - min(held,
+                                                 self.num_experts_per_tok)) \
             * per_expert
         return self.param_count() - inactive
 
@@ -162,6 +184,9 @@ def reduced_config(cfg: ModelConfig, **overrides) -> ModelConfig:
         vocab_size=512,
         num_experts=min(cfg.num_experts, 4),
         num_experts_per_tok=min(cfg.num_experts_per_tok, 2),
+        experts_held=min(cfg.experts_held, 4),
+        first_expert_held=0,
+        shared_expert_d_ff=128 if cfg.shared_expert_d_ff else 0,
         moe_group=64,
         rglru_width=64 if cfg.rglru_width else 0,
         ssm_state=16 if cfg.ssm_state else 0,

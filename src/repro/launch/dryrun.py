@@ -168,7 +168,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             step_fn = steps_lib.make_prefill_step(cfg, capacity=suite.seq_len)
             jitted = jax.jit(step_fn,
                              in_shardings=(p_shardings, b_shardings),
-                             out_shardings=(None, c_shardings))
+                             out_shardings=(None, c_shardings, None))
             args = (pshapes, ispecs)
         else:  # decode
             cshapes = steps_lib.cache_shapes(cfg, suite.global_batch,

@@ -68,7 +68,7 @@ def generate(cfg, params, prompts, gen_steps: int, *, capacity=None,
     serve = jax.jit(make_serve_step(cfg), donate_argnums=(1,))
 
     t0 = time.time()
-    logits, cache = prefill(params, {"tokens": prompts})
+    logits, cache, _ = prefill(params, {"tokens": prompts})
     jax.block_until_ready(logits)
     t_prefill = time.time() - t0
 
@@ -235,7 +235,7 @@ def main():
         configure(**engine_kw)
 
     if args.continuous:
-        from repro.runtime.batching import poisson_trace
+        from repro.runtime.batching import EXPERT_COUNTERS, poisson_trace
         reqs = poisson_trace(num_requests=6, rate=0.5,
                              prompt_lens=args.prompt_len, max_new=args.gen,
                              vocab_size=cfg.vocab_size, seed=args.seed)
@@ -259,7 +259,11 @@ def main():
         ph = m.get("phase_seconds", {})
         if ph:
             print("phases: " + " ".join(
-                f"{k}={ph[k]*1e3:.1f}ms" for k in sorted(ph)))
+                f"{k}={ph[k]*1e3:.1f}ms" for k in sorted(ph)
+                if k not in EXPERT_COUNTERS))
+            if cfg.num_experts:
+                print("experts: " + " ".join(
+                    f"{k}={int(ph[k])}" for k in EXPERT_COUNTERS))
         w = res.get("warmup")
         if w is not None:
             print(f"warm-start: warmed {sum(w['kernels'].values())} "

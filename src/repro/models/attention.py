@@ -400,6 +400,11 @@ def attention_apply(params, cfg, x, positions, *, cache: Optional[KVCache] = Non
         q = apply_rope(q, pos2d, cfg.rope_theta)
         if kv_override is None:
             k = apply_rope(k, pos2d, cfg.rope_theta)
+    if cfg.attention_multiplier is not None:
+        # Every attention path scales scores by hd ** -0.5; a published
+        # scale enters as q pre-scaled by the ratio (one bf16 rounding).
+        ratio = cfg.attention_multiplier * hd ** 0.5
+        q = (q.astype(jnp.float32) * ratio).astype(q.dtype)
 
     q = shard_activation(q, qspec)
 

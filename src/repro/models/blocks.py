@@ -20,7 +20,7 @@ from repro.models.attention import (PagedKVCache, attention_apply,
                                     attention_init, init_kv_cache,
                                     init_paged_kv_cache)
 from repro.models.mlp import mlp_apply, mlp_init
-from repro.models.moe import moe_apply, moe_init
+from repro.models.moe import moe_init, moe_layer
 from repro.models.rglru import init_recurrent_state, rglru_apply, rglru_init
 from repro.models.ssd import init_ssm_state, ssd_apply, ssd_init
 
@@ -76,10 +76,26 @@ def block_cache(batch, cfg, kind: str, capacity: int, paged=None):
     raise ValueError(kind)
 
 
+def _no_aux(cfg):
+    """(aux_loss, expert counts) of a block without experts; a model
+    without experts carries no counts."""
+    counts = jnp.zeros((2,), jnp.int32) if cfg.num_experts else None
+    return jnp.zeros((), jnp.float32), counts
+
+
+def _residual(cfg, x, y):
+    """x + y, the branch ``y`` scaled by ``cfg.residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * jnp.asarray(cfg.residual_multiplier, y.dtype)
+    return x + y
+
+
 def block_apply(params, cfg, kind: str, x, positions, *, cache=None,
-                enc_out=None) -> Tuple[jax.Array, Any, jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+                enc_out=None) -> Tuple[jax.Array, Any, Any]:
+    """Returns (x, new_cache, (aux_loss, expert_counts)): expert counts
+    as in :func:`repro.models.moe.moe_dropless` (zeros in a block without
+    experts, None in a model without them)."""
+    aux = _no_aux(cfg)
     h = common.norm_apply(cfg.norm_type, params["norm_mix"], x, cfg.norm_eps)
     if kind in ("attn", "local"):
         window = cfg.attn_window if kind == "local" else None
@@ -91,7 +107,7 @@ def block_apply(params, cfg, kind: str, x, positions, *, cache=None,
         y, new_cache = ssd_apply(params["mixer"], cfg, h, state=cache)
     else:
         raise ValueError(kind)
-    x = x + y
+    x = _residual(cfg, x, y)
     x = shard_activation(x, (("pod", "data"), "model", None))
 
     if enc_out is not None and "cross" in params:
@@ -103,10 +119,13 @@ def block_apply(params, cfg, kind: str, x, positions, *, cache=None,
     if cfg.block_has_mlp:
         h = common.norm_apply(cfg.norm_type, params["norm_ff"], x, cfg.norm_eps)
         if cfg.num_experts:
-            y, aux = moe_apply(params["ff"], cfg, h)
+            live = None if cache is None else \
+                jnp.broadcast_to(positions >= 0, h.shape[:2])
+            y, loss, counts = moe_layer(params["ff"], cfg, h, live=live)
+            aux = (loss, counts)
         else:
             y = mlp_apply(params["ff"], cfg, h)
-        x = x + y
+        x = _residual(cfg, x, y)
         x = shard_activation(x, (("pod", "data"), "model", None))
     return x, new_cache, aux
 
@@ -158,15 +177,19 @@ def stack_cache(batch, cfg, capacity: int, paged=None):
     return {"groups": stacked, "rem": rem_caches}
 
 
+def _add(a, b):
+    return jax.tree.map(jnp.add, a, b)
+
+
 def _group_apply(group_params, cfg, x, positions, group_cache, enc_out):
     pat = cfg.block_pattern
     new_cache = {} if group_cache is not None else None
-    aux = jnp.zeros((), jnp.float32)
+    aux = _no_aux(cfg)
     for i, kind in enumerate(pat):
         c = group_cache[f"b{i}"] if group_cache is not None else None
         x, nc, a = block_apply(group_params[f"b{i}"], cfg, kind, x, positions,
                                cache=c, enc_out=enc_out)
-        aux = aux + a
+        aux = _add(aux, a)
         if new_cache is not None:
             new_cache[f"b{i}"] = nc
     return x, new_cache, aux
@@ -188,9 +211,11 @@ def _pools_out(group_cache):
 
 
 def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
-    """Returns (x, new_cache, aux_loss_sum)."""
+    """Returns (x, new_cache, aux_loss_sum, expert_counts): the counts
+    (int32 ``(rows routed to held experts, held experts hit)``) summed
+    over the MoE layers, zeros without experts."""
     groups, rem = stack_layout(cfg)
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _no_aux(cfg)
     new_group_cache = None
 
     if groups:
@@ -216,7 +241,7 @@ def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
                 pools = {k: nc[k]._replace(tables=None, layer=None)
                          for k in pools}
                 nc = {k: c for k, c in nc.items() if k not in pools}
-            return (h, aux + a, pools), nc
+            return (h, _add(aux, a), pools), nc
 
         if cfg.remat:
             body = jax.checkpoint(
@@ -238,10 +263,13 @@ def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
         c = cache["rem"][i] if cache is not None else None
         x, nc, a = block_apply(params["rem"][i], cfg, kind, x, positions,
                                cache=c, enc_out=enc_out)
-        aux_total = aux_total + a
+        aux_total = _add(aux_total, a)
         new_rem.append(nc)
 
     new_cache = None
     if cache is not None:
         new_cache = {"groups": new_group_cache, "rem": new_rem}
-    return x, new_cache, aux_total
+    loss, counts = aux_total
+    if counts is None:
+        counts = jnp.zeros((2,), jnp.int32)
+    return x, new_cache, loss, counts

@@ -80,7 +80,9 @@ class EncoderDecoderModel:
     @staticmethod
     def apply(params, cfg, tokens, feats=None, *, enc_out=None, positions=None,
               cache=None, logits_mode="all"):
-        """Teacher-forced decode over ``tokens`` given encoder input."""
+        """Teacher-forced decode over ``tokens`` given encoder input.
+        Returns (logits, new_cache, aux_loss, expert_counts), as
+        ``LanguageModel.apply``."""
         dt = jnp.dtype(cfg.dtype)
         if enc_out is None:
             enc_out = EncoderDecoderModel.encode(params, cfg, feats)
@@ -89,8 +91,9 @@ class EncoderDecoderModel:
         if positions is None:
             positions = jnp.arange(s, dtype=jnp.int32)
         x = shard_activation(x, (("pod", "data"), "model", None))
-        x, new_cache, aux = stack_apply(params["decoder"], cfg, x, positions,
-                                        cache=cache, enc_out=enc_out)
+        x, new_cache, aux, counts = stack_apply(params["decoder"], cfg, x,
+                                                positions, cache=cache,
+                                                enc_out=enc_out)
         x = common.norm_apply(cfg.norm_type, params["final_norm"], x, cfg.norm_eps)
         if logits_mode == "last":
             x = x[:, -1:]
@@ -98,7 +101,7 @@ class EncoderDecoderModel:
         from repro.core import matmul
         logits = matmul(x, w, out_dtype=jnp.dtype(cfg.logits_dtype))
         logits = shard_activation(logits, (("pod", "data"), "model", None))
-        return logits, new_cache, aux
+        return logits, new_cache, aux, counts
 
     @staticmethod
     def init_cache(cfg, batch, capacity):

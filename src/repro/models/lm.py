@@ -3,7 +3,8 @@
 API (pure functions over nested-dict pytrees):
 
   * ``LanguageModel.init(rng, cfg) -> params``
-  * ``LanguageModel.apply(params, cfg, tokens, ...) -> (logits, cache, aux)``
+  * ``LanguageModel.apply(params, cfg, tokens, ...) ->
+    (logits, cache, aux, expert_counts)``
   * ``LanguageModel.init_cache(cfg, batch, capacity) -> cache``
 
 Decode is ``apply`` with a 1-token input and a cache; caches for "local"
@@ -47,12 +48,16 @@ class LanguageModel:
         prepended before the text tokens (positions account for the
         prefix).  ``logits_mode="last"`` unembeds only the final position
         (prefill: skips a (b,s,V)-sized matmul + HBM round-trip).
-        Returns (logits, new_cache, aux_loss)."""
+        Returns (logits, new_cache, aux_loss, expert_counts), the counts
+        the MoE layers' int32 ``(rows routed to held experts, held
+        experts hit)`` (zeros without experts)."""
         dt = jnp.dtype(cfg.dtype)
         b, s = tokens.shape
         x = common.embed(params["embed"], tokens, dt)
         if cfg.embed_scale:
             x = x * jnp.asarray(cfg.d_model ** 0.5, dt)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, dt)
 
         n_mod = 0
         if modality_feats is not None:
@@ -64,8 +69,8 @@ class LanguageModel:
             positions = jnp.arange(s + n_mod, dtype=jnp.int32)
         x = shard_activation(x, (("pod", "data"), "model", None))
 
-        x, new_cache, aux = stack_apply(params["blocks"], cfg, x, positions,
-                                        cache=cache)
+        x, new_cache, aux, counts = stack_apply(params["blocks"], cfg, x,
+                                                positions, cache=cache)
         x = common.norm_apply(cfg.norm_type, params["final_norm"], x,
                               cfg.norm_eps)
         if logits_mode == "last":
@@ -77,11 +82,13 @@ class LanguageModel:
             w = common.cast_param(params["lm_head"]["w"], dt)
             from repro.core import matmul
             logits = matmul(x, w, out_dtype=ldt)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(cfg.logits_scaling, logits.dtype)
         if cfg.final_logit_softcap:
             cap = cfg.final_logit_softcap
             logits = jnp.tanh(logits / cap) * cap
         logits = shard_activation(logits, (("pod", "data"), "model", None))
-        return logits, new_cache, aux
+        return logits, new_cache, aux, counts
 
     @staticmethod
     def init_cache(cfg, batch, capacity, paged=None):

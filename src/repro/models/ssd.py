@@ -192,7 +192,6 @@ def ssd_apply(params, cfg, x, *, state: Optional[SSMState] = None):
     bb = bb.reshape(bsz, s, g, n)
     cc = cc.reshape(bsz, s, g, n)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
-    dt = jnp.clip(dt, 0.0, 10.0)
     a = -jnp.exp(params["A_log"])  # (h,) negative
 
     if s == 1 and state is not None:
@@ -222,6 +221,7 @@ def init_ssm_state(batch, cfg) -> SSMState:
     d_in, h, g, n = ssd_dims(cfg)
     conv_dim = d_in + 2 * g * n
     return SSMState(
-        conv=jnp.zeros((batch, cfg.conv1d_width - 1, conv_dim), jnp.bfloat16),
+        conv=jnp.zeros((batch, cfg.conv1d_width - 1, conv_dim),
+                       jnp.dtype(cfg.dtype)),
         s=jnp.zeros((batch, h, cfg.ssm_head_dim, n), jnp.float32),
     )

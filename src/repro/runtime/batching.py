@@ -40,6 +40,13 @@ before the dot and count inside it; the rest of ``decode`` is the span
 ``serve.decode.sync``, the wait for the device.  ``queue_wait`` is a
 counter: seconds from entering the queue to admission, summed over
 admissions.  ``serve.step`` spans a whole ``step()``.
+
+Four more keys are counts, not seconds (:data:`EXPERT_COUNTERS`):
+``expert_rows.<decode|prefill>``, the token rows routed to experts this
+chip holds, and ``experts_hit.<decode|prefill>``, the held experts with
+at least one row, each summed over MoE layers and steps.  The jitted
+steps return them beside the tokens and the engine reads them with the
+readback the step already does; models without experts return zeros.
 """
 from __future__ import annotations
 
@@ -58,6 +65,10 @@ from repro.models.attention import PageSpec
 from repro.runtime import steps as steps_lib
 from repro.runtime.pages import (OutOfPages, PagePool, init_serving_cache,
                                  pages_for, refresh_tables, write_prefill)
+
+# phase_seconds keys that count rows and experts (module docstring).
+EXPERT_COUNTERS = ("expert_rows.decode", "experts_hit.decode",
+                   "expert_rows.prefill", "experts_hit.prefill")
 
 
 @dataclasses.dataclass
@@ -122,8 +133,11 @@ class ContinuousBatchingEngine:
         # every key exists from the start.
         self.phase_seconds: Dict[str, float] = dict.fromkeys((
             "admission", "prefill", "grow", "eviction", "tables", "decode",
-            "decode.dispatch", "decode.emit", "queue_wait"), 0.0)
+            "decode.dispatch", "decode.emit", "queue_wait")
+            + EXPERT_COUNTERS, 0.0)
         self._nested_s = 0.0  # top-level phases inside the open one
+        # Expert counts of prefills not read back yet (re-admissions).
+        self._unread_counts: List[jax.Array] = []
 
     # -- warm-start ---------------------------------------------------------
 
@@ -215,22 +229,40 @@ class ContinuousBatchingEngine:
         page_ids = self.pool.owned_pages(slot)
         page_ids += self.pool.grow(slot, L)
         with self._phase("prefill"):
-            logits, dense = self._prefill_fn(L)(
+            logits, dense, counts = self._prefill_fn(L)(
                 self.params, {"tokens": jnp.asarray(ctx)[None, :]})
             self.cache = write_prefill(self.cache, dense, slot=slot,
                                        length=L, page_ids=page_ids,
                                        page_size=self.spec.page_size)
+            self._unread_counts.append(counts)
             # The argmax readback is the admission's one device sync.  A
             # re-admission reads nothing back: its prefill finishes on the
             # device inside the next decode's sync.
-            tok = seq.generated[-1] if readmit else int(
-                jnp.argmax(logits[0]))
+            if readmit:
+                tok = seq.generated[-1]
+            else:
+                tok = int(self._read_back(jnp.argmax(logits[0])))
         if not readmit:
             self._emit(seq, tok)
         self.slots[slot] = seq
         self.lengths[slot] = L
         self.next_token[slot] = tok
         self._tables_dirty = True
+
+    def _read_back(self, out, decode_counts=None):
+        """``out`` on the host, read in one transfer with the expert
+        counts of the unread prefills and of this decode step (if any),
+        which are added to their counters."""
+        prefills, self._unread_counts = self._unread_counts, []
+        out, prefills, decode_counts = jax.device_get(
+            (out, prefills, decode_counts))
+        read = [("prefill", c) for c in prefills]
+        if decode_counts is not None:
+            read.append(("decode", decode_counts))
+        for key, c in read:
+            self.phase_seconds["expert_rows." + key] += int(c[0])
+            self.phase_seconds["experts_hit." + key] += int(c[1])
+        return out
 
     def _emit(self, seq: _Seq, tok: int) -> None:
         now = time.perf_counter()
@@ -332,13 +364,13 @@ class ContinuousBatchingEngine:
                 self._tables_dirty = False
             with self._phase("decode"):
                 with self._phase("decode.dispatch"):
-                    toks, self.cache, _ = self._step(
+                    toks, self.cache, counts = self._step(
                         self.params, self.cache,
                         jnp.asarray(self.next_token)[:, None],
                         jnp.asarray(self.lengths, dtype=jnp.int32),
                         jnp.asarray(active_mask))
                 with jax.profiler.TraceAnnotation("serve.decode.sync"):
-                    toks = np.asarray(toks)[:, 0]
+                    toks = self._read_back(toks, counts)[:, 0]
                 with self._phase("decode.emit"):
                     for slot, seq in enumerate(self.slots):
                         if seq is None or not active_mask[slot]:

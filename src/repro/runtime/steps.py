@@ -26,6 +26,17 @@ def model_for(cfg):
 
 def forward(cfg, params, batch: Dict[str, Any], *, cache=None, positions=None,
             logits_mode="all"):
+    """``(logits, new_cache, aux_loss)``: :func:`forward_counted` without
+    the expert counts."""
+    return forward_counted(cfg, params, batch, cache=cache,
+                           positions=positions, logits_mode=logits_mode)[:3]
+
+
+def forward_counted(cfg, params, batch: Dict[str, Any], *, cache=None,
+                    positions=None, logits_mode="all"):
+    """``(logits, new_cache, aux_loss, expert_counts)``: the counts are
+    the MoE layers' int32 ``(rows routed to held experts, held experts
+    hit)``, zeros without experts."""
     if cfg.encoder_decoder:
         return EncoderDecoderModel.apply(
             params, cfg, batch["tokens"], feats=batch.get("modality_feats"),
@@ -118,7 +129,8 @@ def make_train_step(cfg, optimizer, *, microbatches: int = 1,
 # ---------------------------------------------------------------------------
 
 def make_prefill_step(cfg, capacity: int):
-    """Prefill: forward the prompt, return last-position logits + cache."""
+    """Prefill: forward the prompt, return last-position logits, cache
+    and expert counts (:func:`forward_counted`)."""
     model = model_for(cfg)
 
     def prefill_step(params, batch):
@@ -126,9 +138,9 @@ def make_prefill_step(cfg, capacity: int):
         cache = model.init_cache(cfg, b, capacity)
         # unembed only the last position: skips a (b, s, V) matmul + its
         # HBM round-trip (EXPERIMENTS.md §Perf, prefill iteration 1)
-        logits, cache, _ = forward(cfg, params, batch, cache=cache,
-                                   logits_mode="last")
-        return logits[:, -1], cache
+        logits, cache, _, counts = forward_counted(
+            cfg, params, batch, cache=cache, logits_mode="last")
+        return logits[:, -1], cache, counts
 
     return prefill_step
 
@@ -208,22 +220,24 @@ def make_paged_serve_step(cfg):
     """One continuous-batching decode step over the paged serving cache.
 
     (params, cache, tokens(S,1), lengths(S,), active(S,)) ->
-    (next_tokens(S,1), cache, lengths') — greedy argmax decode; inactive
-    slots are frozen (state merged back, length unchanged, token row is
-    garbage the scheduler ignores).  The signature is shape-stable in
-    everything but the cache pytree, so the whole churning batch re-enters
-    ONE compiled step; batch composition changes only flow through the
-    block tables / lengths *values*.
+    (next_tokens(S,1), cache, expert_counts(2,)) — greedy argmax decode;
+    inactive slots are frozen (state merged back, token row is garbage
+    the scheduler ignores).  The signature is shape-stable in everything
+    but the cache pytree, so the whole churning batch re-enters ONE
+    compiled step; batch composition changes only flow through the block
+    tables / lengths *values*, which the scheduler keeps on the host.
+    The expert counts (:func:`forward_counted`) are of the active slots'
+    rows only: inactive slots route to no expert.
     """
 
     def paged_serve_step(params, cache, tokens, lengths, active):
         positions = jnp.where(active, lengths, -1).astype(jnp.int32)[:, None]
-        logits, new_cache, _ = forward(cfg, params, {"tokens": tokens},
-                                       cache=cache, positions=positions)
+        logits, new_cache, _, counts = forward_counted(
+            cfg, params, {"tokens": tokens}, cache=cache,
+            positions=positions)
         new_cache = _merge_inactive(new_cache, cache, active)
         tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
-        new_lengths = jnp.where(active, lengths + 1, lengths)
-        return tok[:, None], new_cache, new_lengths
+        return tok[:, None], new_cache, counts
 
     return paged_serve_step
 

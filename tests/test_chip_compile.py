@@ -197,3 +197,59 @@ def test_ssd_chunk_scan(one_chip):
 def test_transpose(one_chip):
     text = compile_for_chip(one_chip, transpose, ((1024, 3072), BF16))
     assert "tpu_custom_call" in text
+
+
+def _granite_period():
+    """granite-4.0-h-small at published widths cut as its benchmark cell
+    serves it: one period of 10 layers (the attention mixer at index 5),
+    9 of the 72 experts held."""
+    return dataclasses.replace(get_config("granite-4.0-h-small"),
+                               num_layers=10, experts_held=9)
+
+
+def test_granite_paged_decode_step(one_chip):
+    # The continuous-batching decode step of the cut Granite model over
+    # 48 slots and 1,792 pages of 16: paged flash_decode for the GQA
+    # layer, the recurrent SSD step for the nine Mamba-2 layers, and the
+    # held experts through grouped_gemm with runtime group sizes.
+    from repro.launch.serve import load_params
+    cfg, slots = _granite_period(), 48
+    spec = PageSpec(num_pages=1792, page_size=16, max_blocks=112)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: load_params(cfg)))
+    cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_serving_cache(cfg, slots, spec)))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    engine.reset_stats()
+    with use(backend="pallas", interpret=False):
+        text = jax.jit(make_paged_serve_step(cfg), donate_argnums=(1,)).lower(
+            params, cache, jax.ShapeDtypeStruct((slots, 1), jnp.int32,
+                                                sharding=one_chip),
+            rows, rows.update(dtype=jnp.bool_)).compile().as_text()
+    assert "tpu_custom_call" in text
+    stats = engine.stats()
+    assert stats["grouped_gemm"]["launches"] >= 3
+    assert stats["flash_decode"]["launches"] >= 1
+
+
+def test_granite_prefill(one_chip):
+    # One prompt of 320 tokens (two chunks of 256, the second ragged):
+    # ssd_chunk over 128 head groups, causal flash attention at the
+    # published score scale, and the held experts through grouped_gemm.
+    from repro.launch.serve import load_params
+    from repro.runtime.steps import make_prefill_step
+    cfg, length = _granite_period(), 320
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: load_params(cfg)))
+    tokens = jax.ShapeDtypeStruct((1, length), jnp.int32, sharding=one_chip)
+    engine.reset_stats()
+    with use(backend="pallas", interpret=False):
+        text = jax.jit(make_prefill_step(cfg, length)).lower(
+            params, {"tokens": tokens}).compile().as_text()
+    assert "tpu_custom_call" in text
+    stats = engine.stats()
+    for fam in ("ssd_chunk", "grouped_gemm", "flash_attention"):
+        assert stats[fam]["launches"] >= 1, fam

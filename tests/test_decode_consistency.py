@@ -32,13 +32,13 @@ def test_prefill_decode_matches_full(arch, overrides):
         n_mod = cfg.num_modality_tokens
         feats = jax.random.normal(jax.random.PRNGKey(2),
                                   (b, n_mod, cfg.modality_dim))
-    full, _, _ = LanguageModel.apply(params, cfg, tokens,
+    full, *_ = LanguageModel.apply(params, cfg, tokens,
                                      modality_feats=feats)
     cache = LanguageModel.init_cache(cfg, b, capacity=s + n_mod)
-    pre, cache, _ = LanguageModel.apply(
+    pre, cache, *_ = LanguageModel.apply(
         params, cfg, tokens[:, :-1], positions=jnp.arange(s - 1 + n_mod),
         cache=cache, modality_feats=feats)
-    dec, cache, _ = LanguageModel.apply(
+    dec, cache, *_ = LanguageModel.apply(
         params, cfg, tokens[:, -1:], positions=jnp.array([s - 1 + n_mod]),
         cache=cache)
     assert float(jnp.max(jnp.abs(full[:, :-1] - pre))) < 2e-4
@@ -52,15 +52,15 @@ def test_multi_token_decode_chain():
     b, s = 2, 12
     tokens = jax.random.randint(jax.random.PRNGKey(1), (b, s), 0,
                                 cfg.vocab_size)
-    full, _, _ = LanguageModel.apply(params, cfg, tokens)
+    full, *_ = LanguageModel.apply(params, cfg, tokens)
     cache = LanguageModel.init_cache(cfg, b, capacity=s)
     prefix = 4
-    _, cache, _ = LanguageModel.apply(params, cfg, tokens[:, :prefix],
+    _, cache, *_ = LanguageModel.apply(params, cfg, tokens[:, :prefix],
                                       positions=jnp.arange(prefix),
                                       cache=cache)
     outs = []
     for t in range(prefix, s):
-        logit, cache, _ = LanguageModel.apply(
+        logit, cache, *_ = LanguageModel.apply(
             params, cfg, tokens[:, t:t + 1], positions=jnp.array([t]),
             cache=cache)
         outs.append(logit)
@@ -77,10 +77,10 @@ def test_ring_buffer_window_cache():
     b, s = 1, 40  # longer than window
     tokens = jax.random.randint(jax.random.PRNGKey(1), (b, s), 0,
                                 cfg.vocab_size)
-    full, _, _ = LanguageModel.apply(params, cfg, tokens)
+    full, *_ = LanguageModel.apply(params, cfg, tokens)
     cache = LanguageModel.init_cache(cfg, b, capacity=s)  # local capped at window
-    _, cache, _ = LanguageModel.apply(params, cfg, tokens[:, :-1],
+    _, cache, *_ = LanguageModel.apply(params, cfg, tokens[:, :-1],
                                       positions=jnp.arange(s - 1), cache=cache)
-    dec, _, _ = LanguageModel.apply(params, cfg, tokens[:, -1:],
+    dec, *_ = LanguageModel.apply(params, cfg, tokens[:, -1:],
                                     positions=jnp.array([s - 1]), cache=cache)
     assert float(jnp.max(jnp.abs(full[:, -1:] - dec))) < 2e-4

@@ -22,7 +22,9 @@ from repro.runtime.batching import (ContinuousBatchingEngine, Request,
                                     poisson_trace)
 
 KEYS = {"admission", "prefill", "grow", "eviction", "tables", "decode",
-        "decode.dispatch", "decode.emit", "queue_wait"}
+        "decode.dispatch", "decode.emit", "queue_wait",
+        "expert_rows.decode", "experts_hit.decode", "expert_rows.prefill",
+        "experts_hit.prefill"}
 TOP = ("admission", "prefill", "grow", "eviction", "tables", "decode")
 
 

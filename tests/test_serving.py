@@ -18,13 +18,16 @@ from repro.models.attention import PageSpec
 from repro.runtime.batching import (ContinuousBatchingEngine, Request,
                                     poisson_trace)
 
-# One arch per stateful block family (MoE archs excluded: expert routing
-# is batch-composition-dependent, so per-sequence token identity is not
-# a property they promise).
+# One arch per stateful block family.  Serving routes experts dropless,
+# so a MoE token's output does not depend on the batch and per-sequence
+# token identity holds for MoE archs too.
 CASES = [
     ("qwen3-0.6b", {}),        # GQA + qk-norm + RoPE (paged KV)
     ("recurrentgemma-9b", {}),  # RG-LRU + local ring + paged KV
     ("mamba2-130m", {"ssm_chunk": 4, "d_model": 48, "ssm_head_dim": 8}),
+    # Mamba-2 + NoPE GQA hybrid, 4 of 8 experts held, top-3, shared expert
+    ("granite-4.0-h-small", {"num_layers": 10, "num_experts": 8,
+                             "experts_held": 4, "num_experts_per_tok": 3}),
 ]
 
 

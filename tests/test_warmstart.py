@@ -482,5 +482,7 @@ def test_continuous_serving_warm_step(tmp_path):
     ph = warm["metrics"]["phase_seconds"]
     assert set(ph) == {"admission", "prefill", "grow", "eviction", "tables",
                        "decode", "decode.dispatch", "decode.emit",
-                       "queue_wait"}
+                       "queue_wait", "expert_rows.decode",
+                       "experts_hit.decode", "expert_rows.prefill",
+                       "experts_hit.prefill"}
     assert all(v >= 0 for v in ph.values())
