@@ -45,7 +45,8 @@ from .machine import MachineModel, DEFAULT_MACHINE
 # schedules, so blocking is the schedule layer's only upstream.
 from .schedule import (LANES, DecodeTileSchedule,  # noqa: F401
                        FlashTileSchedule, GroupedTileSchedule, TileSchedule,
-                       ceil_div, flash_bwd_vmem_need, flash_tile_schedule,
+                       ceil_div, decode_vmem_need, flash_bwd_vmem_need,
+                       flash_tile_schedule,
                        flash_vmem_need, flatten_regions,
                        grouped_bwd_vmem_need, matmul_vmem_need,
                        plan_launches, round_up, sublanes, vmem_fits)
@@ -697,33 +698,41 @@ def plan_flash(desc: FlashDescriptor,
     return FlashPlan(desc, *best, fused=fused)
 
 
+# A paged decode grid step stages at least this many times the machine's
+# per-step overhead in KV bytes, so the walk's time follows the live KV
+# rather than its row count (DESIGN.md §12).
+DECODE_STEP_COVER = 4
+
+
 @dataclasses.dataclass(frozen=True)
 class FlashDecodePlan:
     """Plan of one paged decode-attention step (DESIGN.md §12).
 
-    The page size *is* the k-block (the pool layout fixed it at cache
-    construction), so the only planning freedom is the schedule itself;
-    like the grouped family, the plan is always ``fused`` — the ragged
-    page walk happens inside ONE ``pallas_call`` riding runtime tables,
-    and the non-fused alternative is the model-level XLA gather path
-    that never enters the engine."""
+    The page size is fixed at cache construction; the plan's one choice
+    is ``pages_per_step``, the block-table entries one grid step stages
+    (:func:`decode_pages_per_step`).  Like the grouped family, the plan
+    is always ``fused`` — the ragged block walk happens inside ONE
+    ``pallas_call`` riding runtime tables, and the non-fused alternative
+    is the model-level XLA gather path that never enters the engine."""
 
     desc: FlashDecodeDescriptor
+    pages_per_step: int = 1
     fused: bool = True
     plan_source: str = "model"  # see BlockingPlan.plan_source
 
     def tile_schedule(self) -> DecodeTileSchedule:
-        """The runtime-table schedule this step walks (one row per live
-        KV page, plus the per-slot dummy floor)."""
+        """The runtime-table schedule this step walks (one row per block
+        of ``pages_per_step`` live pages, plus the per-slot floor)."""
         d = self.desc
         return DecodeTileSchedule(num_seqs=d.num_seqs, pages=d.pages,
                                   page_size=d.page_size,
-                                  max_blocks=d.max_blocks)
+                                  max_blocks=d.max_blocks,
+                                  pages_per_step=self.pages_per_step)
 
     def predicted_seconds(self, machine: MachineModel = DEFAULT_MACHINE
                           ) -> float:
         """Napkin-math step time: every walked tile issues a full
-        (h, page_size, hd) MAC pair; traffic streams each live page once
+        (h, block, hd) MAC pair; traffic streams each live page once
         plus the q/out rows and the prefetch tables."""
         d = self.desc
         steps = self.tile_schedule().max_tiles
@@ -733,12 +742,37 @@ class FlashDecodePlan:
                 + machine.launch_overhead_s)
 
 
+def decode_pages_per_step(desc: FlashDecodeDescriptor,
+                          machine: MachineModel = DEFAULT_MACHINE) -> int:
+    """Pages one decode grid step stages: the smallest power of two whose
+    K+V bytes take :data:`DECODE_STEP_COVER` times ``step_overhead_s`` to
+    read at ``hbm_bw``, capped at the block-table width and at what the
+    step's double-buffered pages and score tile leave of VMEM."""
+    isz = jnp.dtype(desc.dtype).itemsize
+    page_bytes = 2 * desc.page_size * desc.num_kv_heads * desc.head_dim * isz
+    want = DECODE_STEP_COVER * machine.step_overhead_s * machine.hbm_bw
+    pps = 1
+    while pps * page_bytes < want and pps < desc.max_blocks:
+        pps *= 2
+    pps = min(pps, desc.max_blocks)
+
+    def fits(n):
+        return vmem_fits(decode_vmem_need(
+            n, page_size=desc.page_size, num_heads=desc.num_heads,
+            num_kv_heads=desc.num_kv_heads, head_dim=desc.head_dim,
+            isz=isz), machine.vmem_bytes)
+
+    while pps > 1 and not fits(pps):
+        pps //= 2
+    return pps
+
+
 def plan_flash_decode(desc: FlashDecodeDescriptor,
                       machine: MachineModel = DEFAULT_MACHINE
                       ) -> FlashDecodePlan:
-    """Single-lowering planner: the pool geometry fixed every knob at
-    cache construction, so the plan only packages the schedule."""
-    return FlashDecodePlan(desc)
+    """Single-lowering planner: the pool geometry fixed the page at cache
+    construction; the plan sizes the walk's step from the page bytes."""
+    return FlashDecodePlan(desc, decode_pages_per_step(desc, machine))
 
 
 @dataclasses.dataclass(frozen=True)

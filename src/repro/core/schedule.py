@@ -174,6 +174,23 @@ def flash_bwd_vmem_need(sq: int, sk: int, d: int, *, isz: int, bq: int,
     return vmem_need(blocks, scratch)
 
 
+def decode_vmem_need(pages_per_step: int, *, page_size: int, num_heads: int,
+                     num_kv_heads: int, head_dim: int, isz: int) -> int:
+    """Scoped VMEM of the paged decode walk: the query and output rows,
+    two buffers of ``pages_per_step`` K and V pages each, the staged
+    block contracted as ``(pages_per_step * page_size * num_kv_heads,
+    head_dim)`` K and V matrices, the running max / denominator /
+    accumulator and the f32 score and probability tiles."""
+    n = pages_per_step * page_size
+    cols = n * num_kv_heads
+    pages = 2 * 2 * n * tile_bytes(num_kv_heads, head_dim, isz)
+    scratch = (pages + 2 * tile_bytes(cols, head_dim, isz)
+               + 2 * tile_bytes(num_heads, 1, 4)
+               + tile_bytes(num_heads, head_dim, 4)
+               + 2 * tile_bytes(num_heads, cols, 4))
+    return vmem_need([(num_heads, head_dim, isz)] * 2, scratch)
+
+
 def grouped_bwd_vmem_need(t: int, k: int, n: int, *, experts: int, isz: int,
                           acc: Tuple[int, int], with_db: bool = False) -> int:
     """Scoped VMEM of the fused grouped backward: x, dy, one expert panel,
@@ -512,101 +529,128 @@ class DecodeTileSchedule:
     batch churns with admissions/evictions), so it gets the
     :class:`GroupedTileSchedule` treatment, not the trace-time
     :class:`FlashTileSchedule` one: the geometry (pool size, page size,
-    slot count, the static ``max_tiles`` bound) is trace-time, the tables
-    are jnp data computed from ``(block_tables, lengths)`` each step and
-    shipped to the kernel as a scalar-prefetch operand.  Batch churn
-    never retraces — the kernel is shape-specialized, the batch
+    slot count, ``pages_per_step``, the static ``max_tiles`` bound) is
+    trace-time, the tables are jnp data computed from ``lengths`` each
+    step and shipped to the kernel as a scalar-prefetch operand.  Batch
+    churn never retraces — the kernel is shape-specialized, the batch
     composition is data.
 
-    Each table row is ``(seq, page, k_len, first, last)``: the decode
-    kernel's grid step ``t`` attends query row ``seq`` against pool page
-    ``page``, of which the first ``k_len`` slots are live (the tail
-    predicate), with ``first``/``last`` bracketing the sequence's
-    contiguous page walk for the online-softmax carry exactly as in the
-    flash schedule.  A sequence always owns at least one table row — an
-    empty (length-0 / inactive) slot gets a single fully-masked row so
-    its carry still initializes and drains (to zeros) without branching.
+    The walk's unit is a *block*: ``pages_per_step`` consecutive
+    block-table entries of one sequence, one grid step each.  Each table
+    row is ``(seq, page, k_len, first, last)``: grid step ``t`` attends
+    query row ``seq`` against the block whose first block-table index is
+    ``page``, of which the first ``k_len`` tokens are live (so the first
+    ``ceil(k_len / page_size)`` pages are the ones the kernel stages),
+    with ``first``/``last`` bracketing the sequence's contiguous walk for
+    the online-softmax carry exactly as in the flash schedule.  A
+    sequence always owns at least one row — an empty (length-0 /
+    inactive) slot gets a single row with ``k_len`` 0 so its carry still
+    initializes and drains (to zeros) without branching.  Rows past the
+    live total are inert (``k_len`` 0, no flags) and repeat the last
+    sequence's query row, which is the last live row's, so the kernel
+    stages and writes nothing for them.
     """
 
     num_seqs: int    # decode slots (pool block-table rows)
     pages: int       # pool size in pages
     page_size: int   # KV slots per page
     max_blocks: int  # block-table width: max pages one sequence may own
+    pages_per_step: int = 1  # block-table entries one grid step walks
 
     def __post_init__(self):
         assert self.num_seqs > 0 and self.pages > 0
         assert self.page_size > 0 and self.max_blocks > 0
+        assert 0 < self.pages_per_step <= self.max_blocks
+
+    @property
+    def block_len(self) -> int:
+        """KV tokens one grid step covers."""
+        return self.pages_per_step * self.page_size
 
     @property
     def max_tiles(self) -> int:
-        """Static tile bound: live pages are exclusively owned so at most
-        ``pages`` compute tiles exist pool-wide (never more than
-        ``num_seqs * max_blocks``), plus one dummy tile per sequence for
-        the ≥1-row floor."""
-        return min(self.num_seqs * self.max_blocks, self.pages) \
-            + self.num_seqs
+        """Static row bound.  A sequence walks at most
+        ``ceil(max_blocks / pages_per_step)`` blocks.  Pool-wide, live
+        pages are exclusively owned and each sequence has at most one
+        partial block (or one empty row), so there are at most
+        ``pages // pages_per_step`` full blocks plus one row a slot."""
+        pps, S = self.pages_per_step, self.num_seqs
+        return min(S * ceil_div(self.max_blocks, pps),
+                   self.pages // pps + S)
 
     @property
     def max_len(self) -> int:
         """Longest sequence the block tables can map."""
         return self.max_blocks * self.page_size
 
-    def tables(self, block_tables: jax.Array,
-               lengths: jax.Array) -> jax.Array:
+    def live_rows(self, lengths) -> int:
+        """Rows of the walk that carry live tokens, for host-side
+        ``lengths`` (no device work)."""
+        lengths = np.asarray(lengths, np.int64)
+        return int((-(-lengths // self.block_len)).sum())
+
+    def tables(self, lengths: jax.Array) -> jax.Array:
         """Runtime tile table: ``(max_tiles, 5)`` int32 from this step's
-        ``block_tables`` (num_seqs, max_blocks) and ``lengths``
-        (num_seqs,).  All shapes static, values dynamic — traceable under
-        ``jit``, so admissions/evictions/growth never recompile."""
-        P, S = self.page_size, self.num_seqs
+        ``lengths`` (num_seqs,).  All shapes static, values dynamic —
+        traceable under ``jit``, so admissions/evictions/growth never
+        recompile.  The kernel reads the page ids from the block tables
+        itself."""
+        span, S = self.block_len, self.num_seqs
         lengths = lengths.astype(jnp.int32)
-        # ceil(len/P) live pages per sequence, floored at one (dummy) tile
-        # so every slot's carry initializes and drains.
-        nblocks = jnp.maximum((lengths + P - 1) // P, 1)       # (S,)
+        # ceil(len/span) blocks per sequence, floored at one row so every
+        # slot's carry initializes and drains.
+        nblocks = jnp.maximum((lengths + span - 1) // span, 1)  # (S,)
         bstart = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                  jnp.cumsum(nblocks)])        # (S+1,)
+                                  jnp.cumsum(nblocks)])         # (S+1,)
         g = jnp.arange(self.max_tiles, dtype=jnp.int32)
-        seq = jnp.clip(jnp.searchsorted(bstart, g, side="right") - 1,
-                       0, S - 1)
+        # One (max_tiles, S+1) compare, not a binary search: the default
+        # lowers to a while loop of tiny device ops.
+        seq = jnp.clip(jnp.searchsorted(bstart, g, side="right",
+                                        method="compare_all") - 1, 0, S - 1)
         local = g - bstart[seq]
         active = g < bstart[-1]
-        lcl = jnp.clip(local, 0, self.max_blocks - 1)
-        page = jnp.clip(block_tables[seq, lcl], 0, self.pages - 1)
-        k_len = jnp.clip(lengths[seq] - local * P, 0, P)
+        k_len = jnp.where(active,
+                          jnp.clip(lengths[seq] - local * span, 0, span), 0)
         first = active & (local == 0)
         last = active & (local == nblocks[seq] - 1)
-        page = jnp.where(active, page, 0)
-        k_len = jnp.where(active, k_len, 0)
+        page = jnp.where(active, local * self.pages_per_step, 0)
         return jnp.stack([seq, page, k_len,
                           first.astype(jnp.int32), last.astype(jnp.int32)],
                          axis=1).astype(jnp.int32)
 
     def validate_tables(self, table, block_tables, lengths) -> bool:
         """Property check on one concrete table (tests): every sequence's
-        live pages visited exactly once, in block-table order, with
-        correct tail lengths and carry flags; inactive tail rows inert."""
+        live pages visited exactly once, in block-table order and
+        ``pages_per_step`` at a time, with correct live lengths and carry
+        flags; rows past the live total inert and on the last live row's
+        query row."""
         table = np.asarray(table)
         bt = np.asarray(block_tables)
         lengths = np.asarray(lengths, dtype=np.int64)
-        P = self.page_size
+        P, pps, span = self.page_size, self.pages_per_step, self.block_len
         assert table.shape == (self.max_tiles, 5), table.shape
         assert table.dtype == np.int32, table.dtype
-        nblocks = np.maximum(-(-lengths // P), 1)
+        nblocks = np.maximum(-(-lengths // span), 1)
         total = int(nblocks.sum())
         assert total <= self.max_tiles, (total, self.max_tiles)
-        visited = {}  # seq -> list of (page, k_len)
+        visited = {}  # seq -> list of (page ids, k_len)
         open_seq = None
         for i, (seq, page, k_len, first, last) in enumerate(table):
-            if i >= total:  # inactive tail: inert rows, legal indices only
+            assert 0 <= seq < self.num_seqs
+            assert 0 <= page and page % pps == 0, table[i]
+            if i >= total:  # past the live total: inert
                 assert first == 0 and last == 0 and k_len == 0, table[i]
-                assert 0 <= seq < self.num_seqs and 0 <= page < self.pages
+                assert seq == table[total - 1, 0], table[i]
                 continue
-            assert 0 <= seq < self.num_seqs and 0 <= page < self.pages
             if first:
                 assert open_seq is None, "carry re-opened before drain"
                 open_seq = seq
                 visited.setdefault(int(seq), [])
             assert open_seq == seq, "row outside the open carry"
-            visited[int(seq)].append((int(page), int(k_len)))
+            live = -(-int(k_len) // P)
+            assert page + live <= self.max_blocks, table[i]
+            visited[int(seq)].append(
+                ([int(bt[seq, page + j]) for j in range(live)], int(k_len)))
             if last:
                 open_seq = None
         assert open_seq is None, "carry never drained"
@@ -615,15 +659,13 @@ class DecodeTileSchedule:
             n, length = int(nblocks[s]), int(lengths[s])
             assert len(walk) == n, (s, walk, n)
             # pages follow the block table; each live page exactly once
-            pages_seen = [p for p, _ in walk]
-            if length > 0:
-                expect = [int(bt[s, j]) for j in range(n)]
-                assert pages_seen == expect, (s, pages_seen, expect)
-                assert len(set(pages_seen)) == n, "page visited twice"
-            # k_len: P per full page, the ragged tail on the last one
-            assert sum(kl for _, kl in walk) == length, (s, walk, length)
+            pages_seen = [p for ids, _ in walk for p in ids]
+            expect = [int(bt[s, j]) for j in range(-(-length // P))]
+            assert pages_seen == expect, (s, pages_seen, expect)
+            assert len(set(pages_seen)) == len(expect), "page visited twice"
+            # k_len: a whole block each, the ragged tail on the last one
             for j, (_, kl) in enumerate(walk):
-                want = min(max(length - j * P, 0), P)
+                want = min(max(length - j * span, 0), span)
                 assert kl == want, (s, j, kl, want)
         return True
 

@@ -32,7 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schedule import (DecodeTileSchedule, FlashTileSchedule,
-                                 flash_bwd_vmem_need, flash_vmem_need,
+                                 decode_vmem_need, flash_bwd_vmem_need,
+                                 flash_vmem_need,
                                  ownership_mask, pack_table,
                                  predicated_store, vmem_limit)
 
@@ -300,91 +301,132 @@ def build_fused_flash_kernel(*, schedule: FlashTileSchedule,
 
 # ---------------------------------------------------------------------------
 # Paged decode lowering (DESIGN.md §12): one launch walks the runtime
-# DecodeTileSchedule — one grid step = one live KV page of one sequence,
-# pulled from the pool by a table-driven BlockSpec index map
+# DecodeTileSchedule — one grid step = one block of up to pages_per_step
+# live KV pages of one sequence, copied from the pool in HBM by hand,
+# the next row's pages in flight while this row computes
 # ---------------------------------------------------------------------------
 
-def _decode_flash_kernel(tbl_ref, layer_ref, *refs, page_size,
-                         num_kv_heads, rep, scale, kv_quant=False):
+def _decode_flash_kernel(tbl_ref, layer_ref, bt_ref, *refs, page_size,
+                         pages_per_step, max_blocks, pages, num_kv_heads,
+                         rep, scale, kv_quant=False):
     """One grid step of the paged decode walk.
 
     ``tbl_ref`` rows are ``(seq, page, k_len, first, last)``
     (:class:`~repro.core.schedule.DecodeTileSchedule`): the BlockSpec
-    index maps already pulled query row ``seq`` and page ``page`` of
-    layer ``layer_ref[0]``'s pool into VMEM, so the body only masks the
-    page tail (``k_len``), runs the per-head online-softmax update, and
-    drains the carry into the owned output row at ``last`` — the same
-    m/l/acc discipline as the fused flash walk, batched over heads
-    instead of query rows.
+    index maps pulled query row ``seq`` into VMEM, and the body copies
+    the row's live pages — block-table entries ``page`` onward of
+    sequence ``seq`` (``bt_ref``, flattened), ``ceil(k_len / P)`` of
+    them — of layer ``layer_ref[0]``'s pool from HBM into one of two
+    VMEM buffers.  Step ``t`` starts row ``t + 1``'s copies into the
+    other buffer before it waits for its own, so the next block's pages
+    stream in while this one computes.  A row with ``k_len`` 0 (an empty
+    slot's row, or any row past the live total) copies and computes
+    nothing; the ``first``/``last`` flags still reset and drain the
+    carry — the same m/l/acc discipline as the fused flash walk, batched
+    over heads instead of query rows.
 
-    The page is contracted as one ``(P * hkv, hd)`` matrix: column
-    ``c`` of the ``(h, P * hkv)`` score tile is position ``c // hkv`` of
-    KV head ``c % hkv``, and each query head keeps only its own KV
-    head's columns (GQA by mask).  Both contractions are then plain 2-D
-    GEMMs, which is what Mosaic lowers.  The other heads' columns are
-    ``hkv``x the score work, but one such step is a single MXU pass: on a
-    v5e at 8/16 heads of 128 a static loop of per-KV-head dots took 1.7x
-    as long per decode call, and both were far from the page-read bound.
+    The block is contracted as one ``(pps * P * hkv, hd)`` matrix:
+    column ``c`` of the ``(h, pps * P * hkv)`` score tile is position
+    ``c // hkv`` of KV head ``c % hkv``, and each query head keeps only
+    its own KV head's columns (GQA by mask).  Both contractions are then
+    plain 2-D GEMMs, which is what Mosaic lowers.  The other heads'
+    columns are ``hkv``x the score work; on a v5e at 8/16 heads of 128 a
+    static loop of per-KV-head dots took 1.7x as long per page.
 
     ``kv_quant`` (DESIGN.md §13): the pools are int8 with per-token f32
-    scale rows riding as two extra ``(1, P * hkv)`` operands (expanded
-    per column) on the same table-driven index map.  The scales are
-    *separable by page position*, so dequant never touches the KV tiles:
-    the K scales multiply the score columns (``q . (k*s) == (q . k) * s``)
-    and the V scales fold into P before the PV contraction
-    (``sum_p p . (v*s) == sum_p (p*s) . v``) — both lane-dim row
-    broadcasts, no 3-D elementwise dequant."""
-    idx = 0
-    q_ref = refs[idx]; idx += 1
-    k_ref = refs[idx]; idx += 1
-    v_ref = refs[idx]; idx += 1
-    ks_ref = vs_ref = None
-    if kv_quant:
-        ks_ref = refs[idx]; idx += 1
-        vs_ref = refs[idx]; idx += 1
-    o_ref = refs[idx]; idx += 1
-    m_ref, l_ref, acc_ref = refs[idx], refs[idx + 1], refs[idx + 2]
+    scale rows (one per score column) copied beside each page.  The
+    scales are *separable by page position*, so dequant never touches
+    the KV tiles: the K scales multiply the score columns
+    (``q . (k*s) == (q . k) * s``) and the V scales fold into P before
+    the PV contraction (``sum_p p . (v*s) == sum_p (p*s) . v``) — both
+    lane-dim row broadcasts, no 3-D elementwise dequant."""
+    refs = list(refs)
+    q_ref, k_hbm, v_hbm = refs[:3]
+    ks_hbm, vs_hbm = (refs[3:5] if kv_quant else (None, None))
+    del refs[:5 if kv_quant else 3]
+    o_ref, kbuf, vbuf = refs[:3]
+    ksbuf, vsbuf = (refs[3:5] if kv_quant else (None, None))
+    sem, m_ref, l_ref, acc_ref = refs[5 if kv_quant else 3:]
 
     t = pl.program_id(0)
+    slot = t % 2
+    layer = layer_ref[0]
+    cols = pages_per_step * page_size * num_kv_heads
+
+    def copies(row, buf, j):
+        """The copies of page ``j`` of row ``row`` into buffer ``buf``."""
+        entry = tbl_ref[row, 0] * max_blocks + tbl_ref[row, 1] + j
+        page = jnp.clip(bt_ref[entry], 0, pages - 1)
+        out = [pltpu.make_async_copy(k_hbm.at[layer, page],
+                                     kbuf.at[buf, j], sem.at[buf]),
+               pltpu.make_async_copy(v_hbm.at[layer, page],
+                                     vbuf.at[buf, j], sem.at[buf])]
+        if kv_quant:
+            out += [pltpu.make_async_copy(ks_hbm.at[page],
+                                          ksbuf.at[buf, j], sem.at[buf]),
+                    pltpu.make_async_copy(vs_hbm.at[page],
+                                          vsbuf.at[buf, j], sem.at[buf])]
+        return out
+
+    def each_page(row, buf, act):
+        """``act`` on the copies of every live page of ``row``."""
+        def body(j, carry):
+            for c in copies(row, buf, j):
+                act(c)
+            return carry
+        live = (tbl_ref[row, 2] + page_size - 1) // page_size
+        jax.lax.fori_loop(0, live, body, 0)
+
+    @pl.when(t == 0)
+    def _first_row():
+        each_page(0, 0, lambda c: c.start())
+
+    @pl.when(t + 1 < pl.num_programs(0))
+    def _prefetch_next_row():
+        each_page(t + 1, 1 - slot, lambda c: c.start())
+
+    each_page(t, slot, lambda c: c.wait())
     k_len = tbl_ref[t, 2]
-    cols = page_size * num_kv_heads
 
     @pl.when(tbl_ref[t, 3] == 1)
     def _init():
         _carry_init(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0]                                       # (h, hd)
-    # int8 wire values are exact in the wide dtype.
-    k = k_ref[0].astype(q.dtype).reshape(cols, -1)     # (P * hkv, hd)
-    v = v_ref[0].astype(q.dtype).reshape(cols, -1)
-    # Dead page slots may hold stale sequences' values — `where`, never
-    # multiply (§IV-B); zeroed v also keeps a fully-masked (empty-slot)
-    # tile draining exact zeros.
-    pos = jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0) // num_kv_heads
-    v = jnp.where(pos < k_len, v, 0)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if kv_quant:
-        s = s * ks_ref[...]  # (1, P * hkv) over (h, P * hkv)
-    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    live = (col // num_kv_heads < k_len) & (col % num_kv_heads == head // rep)
-    s = jnp.where(live, s, NEG_INF)
+    @pl.when(k_len > 0)
+    def _step():
+        q = q_ref[0]                                   # (h, hd)
+        # int8 wire values are exact in the wide dtype.
+        k = kbuf[slot].astype(q.dtype).reshape(cols, -1)   # (pps*P*hkv, hd)
+        v = vbuf[slot].astype(q.dtype).reshape(cols, -1)
+        # Pages past the live ones hold an earlier row's values, or none
+        # yet — `where`, never multiply (§IV-B).
+        pos = (jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
+               // num_kv_heads)
+        v = jnp.where(pos < k_len, v, 0)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if kv_quant:
+            s = s * ksbuf[slot].reshape(1, cols)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        live = ((col // num_kv_heads < k_len)
+                & (col % num_kv_heads == head // rep))
+        s = jnp.where(live, s, NEG_INF)
 
-    # Per-head online-softmax update — the m/l algebra of
-    # `_online_softmax_update`, with the V scales folded into P.
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    pv = p
-    if kv_quant:
-        pv = p * vs_ref[...]
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        # Per-head online-softmax update — the m/l algebra of
+        # `_online_softmax_update`, with the V scales folded into P.
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if kv_quant:
+            p = p * jnp.where(pos.reshape(1, cols) < k_len,
+                              vsbuf[slot].reshape(1, cols), 0)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
     @pl.when(tbl_ref[t, 4] == 1)
     def _store():
@@ -398,55 +440,55 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
                               interpret: bool = False):
     """Generate ONE pallas_call executing a whole paged decode step.
 
-    Returns ``f(table, layer, q:(S,h,hd), k_pool:(L,pages,P,hkv,hd),
-    v_pool) -> (S,h,hd)`` where ``table`` is the runtime ``(max_tiles,
-    5)`` int32 tile table (:meth:`DecodeTileSchedule.tables`) and
-    ``layer`` a ``(1,)`` int32 index into the pools' leading layer dim.
-    Both are *scalar-prefetch operands*: the batch composition and the
-    layer are data, so the kernel compiles once per pool geometry and
-    neither the churning batch nor the layer scan retraces.  The
-    BlockSpec index maps read them — grid step ``t`` stages exactly
-    query row ``table[t, 0]`` and page ``table[t, 1]`` of layer
-    ``layer[0]``, straight from the whole stack of pools the decode
-    step carries through its layer scan, so no layer's pool is ever
-    sliced out or copied (DESIGN.md §12).  The table stays the first
-    operand: the benchmark's trace tells the kernel apart by it."""
-    S, P = schedule.num_seqs, schedule.page_size
+    Returns ``f(table, layer, block_tables, q:(S,h,hd),
+    k_pool:(L,pages,P,hkv,hd), v_pool) -> (S,h,hd)`` where ``table`` is
+    the runtime ``(max_tiles, 5)`` int32 tile table
+    (:meth:`DecodeTileSchedule.tables`), ``layer`` a ``(1,)`` int32
+    index into the pools' leading layer dim and ``block_tables`` the
+    ``(S * max_blocks,)`` int32 page ids, flattened.  All three are
+    *scalar-prefetch operands*: the batch composition and the layer are
+    data, so the kernel compiles once per pool geometry and neither the
+    churning batch nor the layer scan retraces.  The pools stay in HBM
+    (``memory_space=ANY``) and the body copies each row's live pages of
+    layer ``layer[0]`` straight from the whole stack the decode step
+    carries through its layer scan, so no layer's pool is ever sliced
+    out or copied whole (DESIGN.md §12).  With ``kv_quant`` two more
+    operands ``(pages, 1, P * hkv)`` hold the walked layer's per-column
+    dequant scales.  The table stays the first operand: the benchmark's
+    trace tells the kernel apart by it."""
+    S, P, pps = schedule.num_seqs, schedule.page_size, schedule.pages_per_step
     h, hkv, hd = num_heads, num_kv_heads, head_dim
     kv_dtype = kv_dtype or dtype
-    body = functools.partial(_decode_flash_kernel, page_size=P,
-                             num_kv_heads=hkv, rep=h // hkv,
-                             scale=hd ** -0.5, kv_quant=kv_quant)
+    body = functools.partial(
+        _decode_flash_kernel, page_size=P, pages_per_step=pps,
+        max_blocks=schedule.max_blocks, pages=schedule.pages,
+        num_kv_heads=hkv, rep=h // hkv, scale=hd ** -0.5, kv_quant=kv_quant)
 
-    def page(t, tbl, layer):
-        return (layer[0], tbl[t, 1], 0, 0, 0)
+    def row(t, tbl, layer, bt):
+        return (tbl[t, 0], 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, h, hd), lambda t, tbl, layer: (tbl[t, 0], 0, 0)),
-        pl.BlockSpec((pl.squeezed, 1, P, hkv, hd), page),
-        pl.BlockSpec((pl.squeezed, 1, P, hkv, hd), page),
-    ]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, h, hd), row), hbm, hbm]
+    scratch = [pltpu.VMEM((2, pps, P, hkv, hd), kv_dtype),
+               pltpu.VMEM((2, pps, P, hkv, hd), kv_dtype)]
     if kv_quant:
-        # per-token dequant scales of the walked page, one per score
-        # column (DESIGN.md §13); the caller passes the layer's rows
-        in_specs += [
-            pl.BlockSpec((1, P * hkv),
-                         lambda t, tbl, layer: (tbl[t, 1], 0)),
-            pl.BlockSpec((1, P * hkv),
-                         lambda t, tbl, layer: (tbl[t, 1], 0)),
-        ]
+        in_specs += [hbm, hbm]
+        scratch += [pltpu.VMEM((2, pps, 1, P * hkv), jnp.float32)] * 2
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),     # one per page buffer
+        pltpu.VMEM((h, 1), jnp.float32),   # running max
+        pltpu.VMEM((h, 1), jnp.float32),   # running denom
+        pltpu.VMEM((h, hd), jnp.float32),  # output accumulator
+    ]
+    need = decode_vmem_need(pps, page_size=P, num_heads=h, num_kv_heads=hkv,
+                            head_dim=hd, isz=jnp.dtype(dtype).itemsize)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # the runtime tile table, the layer
+        num_scalar_prefetch=3,  # the tile table, the layer, block tables
         grid=(schedule.max_tiles,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, hd),
-                               lambda t, tbl, layer: (tbl[t, 0], 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),   # running max
-            pltpu.VMEM((h, 1), jnp.float32),   # running denom
-            pltpu.VMEM((h, hd), jnp.float32),  # output accumulator
-        ],
+        out_specs=pl.BlockSpec((1, h, hd), row),
+        scratch_shapes=scratch,
     )
 
     return pl.pallas_call(
@@ -455,8 +497,10 @@ def build_decode_flash_kernel(*, schedule: DecodeTileSchedule,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, h, hd), dtype),
         compiler_params=pltpu.CompilerParams(
-            # one sequential dimension: the carry threads the page walk
+            # one sequential dimension: the carry and the page buffers
+            # thread the walk
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit(need),
         ),
         interpret=interpret,
     )

@@ -106,7 +106,7 @@ engine.register_family("flash_attention_bwd", planner=plan_flash_bwd,
 
 def execute_decode(desc: FlashDecodeDescriptor, plan: FlashDecodePlan,
                    q, k_pool, v_pool, block_tables, lengths, *,
-                   k_scale=None, v_scale=None, layer=None,
+                   k_scale=None, v_scale=None, layer=None, walk=None,
                    interpret: bool = False):
     """Engine executor: run one planned paged decode-attention step.
 
@@ -118,8 +118,10 @@ def execute_decode(desc: FlashDecodeDescriptor, plan: FlashDecodePlan,
     whole stack ``(L, pages, P, hkv, hd)`` with ``layer`` the index to
     read; a single layer runs as a stack of one.  KV-int8 pools
     (DESIGN.md §13) ride the same launch: the walked layer's per-token
-    scale rows ``(pages, page_size)`` join as two extra table-indexed
-    operands.
+    scale rows ``(pages, page_size)`` join as two extra operands whose
+    rows the kernel copies beside their pages.  ``walk``, when given, is
+    the tile table :func:`decode_walk` built for this plan outside the
+    caller's layer loop; without it the table is built here.
     """
     engine.count_launches("flash_decode", 1, fused=True)
     kv_quant = k_scale is not None
@@ -129,31 +131,54 @@ def execute_decode(desc: FlashDecodeDescriptor, plan: FlashDecodePlan,
             k_scale, v_scale = k_scale[None], v_scale[None]
     layer = jnp.reshape(jnp.asarray(layer, jnp.int32), (1,))
     schedule = plan.tile_schedule()
-    key = desc.cache_key() + ("decode", canonical_dtype(k_pool.dtype),
-                              kv_quant, interpret)
+    key = desc.cache_key() + ("decode", plan.pages_per_step,
+                              canonical_dtype(k_pool.dtype), kv_quant,
+                              interpret)
     kernel = engine.build_cached(key, lambda: build_decode_flash_kernel(
         schedule=schedule, num_heads=desc.num_heads,
         num_kv_heads=desc.num_kv_heads, head_dim=desc.head_dim,
         dtype=q.dtype, kv_dtype=k_pool.dtype, kv_quant=kv_quant,
         interpret=interpret))
-    table = schedule.tables(block_tables, lengths)
+    if walk is None:
+        walk = schedule.tables(lengths)
+    elif walk.shape != (schedule.max_tiles, 5):
+        raise ValueError(f"walk {walk.shape} was built for another plan "
+                         f"than this call's ({schedule.max_tiles}, 5)")
+    operands = [walk, layer,
+                jnp.reshape(block_tables.astype(jnp.int32), (-1,)),
+                q, k_pool, v_pool]
     if kv_quant:
-        # One scale per score column (position-major, KV head minor).
+        # The walked layer's scales, one per score column (position-
+        # major, KV head minor), a row a page.
         def per_column(sc):
             return jnp.repeat(sc[layer[0]].astype(jnp.float32),
-                              desc.num_kv_heads, axis=1)
-        return kernel(table, layer, q, k_pool, v_pool, per_column(k_scale),
-                      per_column(v_scale))
-    return kernel(table, layer, q, k_pool, v_pool)
+                              desc.num_kv_heads, axis=1)[:, None, :]
+        operands += [per_column(k_scale), per_column(v_scale)]
+    return kernel(*operands)
 
 
 engine.register_family("flash_decode", planner=plan_flash_decode,
                        execute=execute_decode)
 
 
+def decode_walk(q, k_pool, block_tables, lengths) -> jax.Array:
+    """The ``(max_tiles, 5)`` tile table that ``flash_decode`` walks for
+    these lengths (``q`` may be a ``ShapeDtypeStruct``: only its shape
+    and dtype pick the plan).
+
+    It depends on the lengths alone, so a decode step that applies many
+    layers builds it once and passes it to each layer's
+    :func:`paged_decode_attention` as ``walk``.  Built inside a layer
+    scan, it would be rebuilt in every layer: XLA does not hoist a loop
+    invariant whose result outgrows its inputs.
+    """
+    desc = FlashDecodeDescriptor.from_operands(q, k_pool, block_tables)
+    return engine.plan_for(desc).tile_schedule().tables(lengths)
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            k_scale=None, v_scale=None,
-                           layer=None) -> jax.Array:
+                           layer=None, walk=None) -> jax.Array:
     """One decode step against a paged KV pool (DESIGN.md §12).
 
     q: (S, h, hd) — one query row per decode slot; k_pool/v_pool:
@@ -166,11 +191,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
     With int8 pools, ``k_scale``/``v_scale`` are the per-token dequant
     rows ``(pages, page_size)`` f32 (``(L, pages, page_size)`` with
     ``layer``; DESIGN.md §13) — same launch count, the scales fold into
-    the score/PV algebra in-kernel.
+    the score/PV algebra in-kernel.  ``walk``: :func:`decode_walk`'s
+    table for these lengths, built once for many layers (else here).
     """
     desc = FlashDecodeDescriptor.from_operands(q, k_pool, block_tables)
     return engine.dispatch(desc, q, k_pool, v_pool, block_tables, lengths,
-                           k_scale=k_scale, v_scale=v_scale, layer=layer)
+                           k_scale=k_scale, v_scale=v_scale, layer=layer,
+                           walk=walk)
 
 
 def _flat_desc(causal, qf, kf) -> FlashDescriptor:

@@ -235,7 +235,8 @@ def main():
         configure(**engine_kw)
 
     if args.continuous:
-        from repro.runtime.batching import EXPERT_COUNTERS, poisson_trace
+        from repro.runtime.batching import (DECODE_TILE_COUNTERS,
+                                            EXPERT_COUNTERS, poisson_trace)
         reqs = poisson_trace(num_requests=6, rate=0.5,
                              prompt_lens=args.prompt_len, max_new=args.gen,
                              vocab_size=cfg.vocab_size, seed=args.seed)
@@ -260,7 +261,12 @@ def main():
         if ph:
             print("phases: " + " ".join(
                 f"{k}={ph[k]*1e3:.1f}ms" for k in sorted(ph)
-                if k not in EXPERT_COUNTERS))
+                if k not in EXPERT_COUNTERS + DECODE_TILE_COUNTERS))
+            live, walked = (int(ph[k]) for k in DECODE_TILE_COUNTERS)
+            if walked:
+                print(f"decode tiles: decode_tiles.live={live} "
+                      f"decode_tiles.walked={walked} "
+                      f"live_share={live / walked:.3f}")
             if cfg.num_experts:
                 print("experts: " + " ".join(
                     f"{k}={int(ph[k])}" for k in EXPERT_COUNTERS))

@@ -90,13 +90,16 @@ class PagedKVCache(NamedTuple):
     layer being applied.  :func:`_paged_decode` then writes the new row
     at ``[layer, page, offset]`` in place and the decode kernel reads
     that layer's pages straight from the stack, so no layer's pool is
-    ever sliced out or copied back (DESIGN.md §12)."""
+    ever sliced out or copied back (DESIGN.md §12).  ``walk`` is likewise
+    set only there: the ``flash_decode`` tile table of the step, built
+    once before the scan by :func:`paged_walk`."""
     k: jax.Array       # (num_pages, page_size, h_kv, hd)
     v: jax.Array       # (num_pages, page_size, h_kv, hd)
     tables: jax.Array  # (num_slots, max_blocks) int32 page ids
     k_scale: Optional[jax.Array] = None  # (num_pages, page_size) f32
     v_scale: Optional[jax.Array] = None
     layer: Optional[jax.Array] = None    # int32 scalar, stacked pools only
+    walk: Optional[jax.Array] = None     # (max_tiles, 5) int32, ditto
 
 
 def init_paged_kv_cache(num_slots, spec: PageSpec, n_kv, head_dim,
@@ -266,6 +269,30 @@ def _attention_seq(q, k, v, q_pos, k_pos, window, softcap):
 # Paged decode (continuous batching, DESIGN.md §12)
 # ---------------------------------------------------------------------------
 
+def _slot_positions(pos2d, S):
+    """(S,) decode positions from ``(S|1, 1)`` per-slot positions."""
+    return pos2d[:, 0] if pos2d.shape[0] == S else \
+        jnp.broadcast_to(pos2d[:, 0], (S,))
+
+
+def paged_walk(cfg, cache: PagedKVCache, positions):
+    """The ``flash_decode`` tile table of one decode step over ``cache``
+    at per-slot ``positions`` ``(S, 1)``, or None where
+    :func:`_paged_decode` takes the XLA path.  A decode step that scans
+    many layers builds it once and sets it as each layer's
+    ``cache.walk``, since every layer reads the same lengths."""
+    if get_config().backend != "pallas" or cfg.attn_logit_softcap:
+        return None
+    from repro.kernels.flash_attention import decode_walk
+    S = cache.tables.shape[0]
+    pos = _slot_positions(
+        positions if positions.ndim == 2 else positions[None, :], S)
+    q = jax.ShapeDtypeStruct((S, cfg.num_heads, cfg.head_dim),
+                             jnp.dtype(cfg.dtype))
+    return decode_walk(q, cache.k, cache.tables,
+                       jnp.where(pos >= 0, pos + 1, 0))
+
+
 def _paged_decode(cfg, cache: PagedKVCache, q, k, v, pos2d, dt, g):
     """One decode step against the paged KV pool.
 
@@ -289,8 +316,7 @@ def _paged_decode(cfg, cache: PagedKVCache, q, k, v, pos2d, dt, g):
     layer = cache.layer
     pages, P, hkv, hd = cache.k.shape[-4:]
     B = cache.tables.shape[1]
-    pos = pos2d[:, 0] if pos2d.shape[0] == S else \
-        jnp.broadcast_to(pos2d[:, 0], (S,))
+    pos = _slot_positions(pos2d, S)
     active = pos >= 0
     safe = jnp.clip(pos, 0)
     blk = jnp.take_along_axis(cache.tables, (safe // P)[:, None], axis=1)[:, 0]
@@ -327,7 +353,8 @@ def _paged_decode(cfg, cache: PagedKVCache, q, k, v, pos2d, dt, g):
         from repro.kernels.flash_attention import paged_decode_attention
         out = paged_decode_attention(q[:, 0], k_new, v_new, cache.tables,
                                      lengths, k_scale=ks_new,
-                                     v_scale=vs_new, layer=layer)[:, None]
+                                     v_scale=vs_new, layer=layer,
+                                     walk=cache.walk)[:, None]
         return new_cache, out
     # XLA fallback: gather the block-table pages into a contiguous view
     # (gathered column j holds absolute position j) and mask j >= length

@@ -18,7 +18,7 @@ from jax.ad_checkpoint import checkpoint_name
 from repro.models import common
 from repro.models.attention import (PagedKVCache, attention_apply,
                                     attention_init, init_kv_cache,
-                                    init_paged_kv_cache)
+                                    init_paged_kv_cache, paged_walk)
 from repro.models.mlp import mlp_apply, mlp_init
 from repro.models.moe import moe_init, moe_layer
 from repro.models.rglru import init_recurrent_state, rglru_apply, rglru_init
@@ -196,18 +196,24 @@ def _group_apply(group_params, cfg, x, positions, group_cache, enc_out):
 
 
 def _pools_out(group_cache):
-    """Split a stacked group cache for the layer scan: ``(xs, pools)``.
+    """Split a stacked group cache for the layer scan: ``(xs, pools,
+    tables)``.
 
-    Each :class:`PagedKVCache` block leaves only its block tables in
-    ``xs``; its stacked pools (and int8 scales) go to ``pools``, which
-    the scan *carries*.  A pool in ``xs``/``ys`` would be sliced out per
-    layer, written back per layer and copied whole after the loop; as a
-    carry it aliases the donated cache and is updated in place
-    (DESIGN.md §12).  Every other leaf keeps its ``xs`` path."""
+    Each :class:`PagedKVCache` block leaves ``xs``: its stacked pools
+    (and int8 scales) go to ``pools``, which the scan *carries*.  A pool
+    in ``xs``/``ys`` would be sliced out per layer, written back per
+    layer and copied whole after the loop; as a carry it aliases the
+    donated cache and is updated in place (DESIGN.md §12).  Its block
+    tables, alike in every layer (one allocator maps a slot's pages for
+    all of them: ``runtime/pages.py``), go to ``tables`` as the first
+    layer's ``(S, max_blocks)``, which the scan body closes over, so what
+    a step derives from them is derived once and not per layer.  Every
+    other leaf keeps its ``xs`` path."""
     pools = {k: c._replace(tables=None) for k, c in group_cache.items()
              if isinstance(c, PagedKVCache)}
-    xs = {k: c.tables if k in pools else c for k, c in group_cache.items()}
-    return xs, pools
+    tables = {k: group_cache[k].tables[0] for k in pools}
+    xs = {k: c for k, c in group_cache.items() if k not in pools}
+    return xs, pools, tables
 
 
 def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
@@ -221,14 +227,20 @@ def stack_apply(params, cfg, x, positions, *, cache=None, enc_out=None):
     if groups:
         gcache, pools = None, {}
         if cache is not None:
-            gcache, pools = _pools_out(cache["groups"])
+            gcache, pools, tables = _pools_out(cache["groups"])
+            # flash_decode's walk depends on the lengths alone: built
+            # here once, not by every layer of the scan.
+            walks = {k: paged_walk(cfg, p._replace(tables=tables[k]),
+                                   positions)
+                     for k, p in pools.items()}
 
         def body(carry, xs):
             h, aux, pools = carry
             if cache is not None:
                 gp, gc, layer = xs
-                gc = {k: pools[k]._replace(tables=c, layer=layer)
-                      if k in pools else c for k, c in gc.items()}
+                gc = dict(gc, **{k: p._replace(tables=tables[k], layer=layer,
+                                               walk=walks[k])
+                                 for k, p in pools.items()})
             else:
                 gp, gc = xs, None
             # Name the (bf16) carry so the remat policy saves exactly this

@@ -47,6 +47,14 @@ chip holds, and ``experts_hit.<decode|prefill>``, the held experts with
 at least one row, each summed over MoE layers and steps.  The jitted
 steps return them beside the tokens and the engine reads them with the
 readback the step already does; models without experts return zeros.
+
+Two more count the paged decode walk (:data:`DECODE_TILE_COUNTERS`,
+DESIGN.md §12), summed over decode steps for one attention layer (every
+layer walks the same table): ``decode_tiles.walked``, the rows of the
+``flash_decode`` grid, and ``decode_tiles.live``, the rows that carry
+live KV.  The host computes both from the lengths it already holds and
+the walk the planner gives the pool's geometry; they stay zero for a
+model without paged attention layers.
 """
 from __future__ import annotations
 
@@ -61,7 +69,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import engine
-from repro.models.attention import PageSpec
+from repro.core.config import get_config
+from repro.core.descriptor import FlashDecodeDescriptor
+from repro.models.attention import PagedKVCache, PageSpec
 from repro.runtime import steps as steps_lib
 from repro.runtime.pages import (OutOfPages, PagePool, init_serving_cache,
                                  pages_for, refresh_tables, write_prefill)
@@ -69,6 +79,8 @@ from repro.runtime.pages import (OutOfPages, PagePool, init_serving_cache,
 # phase_seconds keys that count rows and experts (module docstring).
 EXPERT_COUNTERS = ("expert_rows.decode", "experts_hit.decode",
                    "expert_rows.prefill", "experts_hit.prefill")
+# phase_seconds keys that count rows of the paged decode walk.
+DECODE_TILE_COUNTERS = ("decode_tiles.live", "decode_tiles.walked")
 
 
 @dataclasses.dataclass
@@ -134,7 +146,8 @@ class ContinuousBatchingEngine:
         self.phase_seconds: Dict[str, float] = dict.fromkeys((
             "admission", "prefill", "grow", "eviction", "tables", "decode",
             "decode.dispatch", "decode.emit", "queue_wait")
-            + EXPERT_COUNTERS, 0.0)
+            + EXPERT_COUNTERS + DECODE_TILE_COUNTERS, 0.0)
+        self._walk = _decode_walk(cfg, self.cache, num_slots)
         self._nested_s = 0.0  # top-level phases inside the open one
         # Expert counts of prefills not read back yet (re-admissions).
         self._unread_counts: List[jax.Array] = []
@@ -160,7 +173,6 @@ class ContinuousBatchingEngine:
         zero kernel builds, zero plan-cache misses and zero new traces —
         provable via ``engine.stats()``.  Returns a summary dict.
         """
-        from repro.core.config import get_config
         t0 = time.perf_counter()
         kernels: Dict[str, int] = {}
         if manifest is not None or get_config().warm_start:
@@ -364,6 +376,14 @@ class ContinuousBatchingEngine:
                 self._tables_dirty = False
             with self._phase("decode"):
                 with self._phase("decode.dispatch"):
+                    if self._walk is not None:
+                        # the kernel's lengths: each live slot's context
+                        # and the row this step writes
+                        self.phase_seconds["decode_tiles.live"] += \
+                            self._walk.live_rows(
+                                self.lengths[active_mask] + 1)
+                        self.phase_seconds["decode_tiles.walked"] += \
+                            self._walk.max_tiles
                     toks, self.cache, counts = self._step(
                         self.params, self.cache,
                         jnp.asarray(self.next_token)[:, None],
@@ -432,6 +452,22 @@ class ContinuousBatchingEngine:
             },
             "engine_stats": stats1,
         }
+
+
+def _decode_walk(cfg, cache, num_slots: int):
+    """The ``flash_decode`` schedule the decode step walks for the paged
+    attention layers of ``cache``, or None if it has none."""
+    paged = [x for x in jax.tree.leaves(
+        cache, is_leaf=lambda x: isinstance(x, PagedKVCache))
+        if isinstance(x, PagedKVCache)]
+    if not paged:
+        return None
+    pages, page_size, hkv, hd = paged[0].k.shape[-4:]
+    desc = FlashDecodeDescriptor(
+        num_seqs=num_slots, pages=pages, page_size=page_size,
+        max_blocks=paged[0].tables.shape[-1], num_heads=cfg.num_heads,
+        num_kv_heads=hkv, head_dim=hd, dtype=cfg.dtype)
+    return engine.plan_for(desc).tile_schedule()
 
 
 def poisson_trace(*, num_requests: int, rate: float, prompt_lens,

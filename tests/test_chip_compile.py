@@ -21,6 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core import GemmDescriptor, engine, plan_gemm, use
+from repro.core.blocking import plan_flash_decode
+from repro.core.descriptor import FlashDecodeDescriptor
 from repro.kernels.flash_attention import (flash_attention,
                                            paged_decode_attention)
 from repro.kernels.gemm import gemm
@@ -113,32 +115,54 @@ def test_causal_flash_attention(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_flash_decode(one_chip):
-    # 8 decode slots, 16 query / 8 KV heads of 128, 1024 pages of 16.
-    pool = ((1024, 16, 8, 128), BF16)
-    text = compile_for_chip(one_chip, paged_decode_attention,
-                            ((8, 16, 128), BF16), pool, pool,
-                            ((8, 64), I32), ((8,), I32))
+@pytest.mark.parametrize("slots,pages,max_blocks,heads,kv_heads,layers", [
+    (8, 1024, 64, 16, 8, 1),
+    (24, 1792, 112, 16, 8, 28),   # qwen3-0.6b.chat's pool
+    (24, 2688, 168, 16, 8, 28),   # qwen3-0.6b on a pool half as large again
+    (8, 1792, 264, 16, 2, 36),    # qwen2.5-3b.prefill-backlog's pool
+    (48, 1792, 112, 32, 8, 1),    # granite-4.0-h-small.chat-high-rate's
+])
+def test_flash_decode(one_chip, slots, pages, max_blocks, heads, kv_heads,
+                      layers):
+    # The paged decode walk over a stack of pools of 16-token pages, 128
+    # wide heads, at the planner's pages_per_step.  The benchmark's trace
+    # finds the kernel by its first operand, the (rows, 5) tile table.
+    pool = ((layers, pages, 16, kv_heads, 128), BF16)
+    text = compile_for_chip(
+        one_chip, lambda q, k, v, bt, n, layer: paged_decode_attention(
+            q, k, v, bt, n, layer=layer),
+        ((slots, heads, 128), BF16), pool, pool, ((slots, max_blocks), I32),
+        ((slots,), I32), ((), I32))
     assert "tpu_custom_call" in text
+    desc = FlashDecodeDescriptor(num_seqs=slots, pages=pages, page_size=16,
+                                 max_blocks=max_blocks, num_heads=heads,
+                                 num_kv_heads=kv_heads, head_dim=128,
+                                 dtype="bfloat16")
+    rows = plan_flash_decode(desc).tile_schedule().max_tiles
+    (call,) = [line for line in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert re.search(r"operand_layout_constraints=\{s32\[(\d+),5\]",
+                     call).group(1) == str(rows)
 
 
 # One HLO instruction: its name, result type and opcode.
 _INSTR = re.compile(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (.*?) ([\w\-]+)\(")
 
 
-def test_paged_serve_step_keeps_pool_in_place(one_chip):
-    # The continuous-batching decode step at qwen3-0.6b widths over three
-    # scanned layers, 8 slots and the chat cell's 1,792 pages of 16 (a
-    # stack of pools too large for VMEM, as every served pool is: a
-    # smaller one may be staged in VMEM around the loop and copied back),
-    # compiled with the donation the engine jits it with.  The stacked
-    # pools ride the layer scan as its carry and are written in place,
-    # so the program holds no copy, slice or update-slice of a whole
-    # stack or of one layer's pool, and the donated pools alias the
-    # step's output.
-    layers, pages, slots = 3, 1792, 8
+_STEP = dict(layers=3, pages=1792, slots=8, max_blocks=64)
+
+
+@pytest.fixture(scope="module")
+def serve_step_text(one_chip):
+    """The continuous-batching decode step at qwen3-0.6b widths over
+    three scanned layers, 8 slots and the chat cell's 1,792 pages of 16
+    (a stack of pools too large for VMEM, as every served pool is: a
+    smaller one may be staged in VMEM around the loop and copied back),
+    compiled with the donation the engine jits it with."""
+    layers, pages, slots = _STEP["layers"], _STEP["pages"], _STEP["slots"]
     cfg = dataclasses.replace(get_config("qwen3-0.6b"), num_layers=layers)
-    spec = PageSpec(num_pages=pages, page_size=16, max_blocks=64)
+    spec = PageSpec(num_pages=pages, page_size=16,
+                    max_blocks=_STEP["max_blocks"])
 
     def chip(x):
         dt = jnp.bfloat16 if jnp.issubdtype(x.dtype, jnp.floating) \
@@ -150,10 +174,19 @@ def test_paged_serve_step_keeps_pool_in_place(one_chip):
         lambda: init_serving_cache(cfg, slots, spec)))
     rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     with use(backend="pallas", interpret=False):
-        text = jax.jit(make_paged_serve_step(cfg), donate_argnums=(1,)).lower(
+        return jax.jit(make_paged_serve_step(cfg), donate_argnums=(1,)).lower(
             params, cache, jax.ShapeDtypeStruct((slots, 1), jnp.int32,
                                                 sharding=one_chip),
             rows, rows.update(dtype=jnp.bool_)).compile().as_text()
+
+
+def test_paged_serve_step_keeps_pool_in_place(serve_step_text):
+    # The stacked pools ride the layer scan as its carry and are written
+    # in place, so the program holds no copy, slice or update-slice of a
+    # whole stack or of one layer's pool, and the donated pools alias
+    # the step's output.
+    text = serve_step_text
+    layers, pages = _STEP["layers"], _STEP["pages"]
     assert "tpu_custom_call" in text
 
     dims = (f"[{layers},{pages},16,8,128]", f"[{pages},16,8,128]")
@@ -176,6 +209,32 @@ def test_paged_serve_step_keeps_pool_in_place(one_chip):
     alias = re.search(r"input_output_alias=\{(.*?) \}", text).group(1)
     for n in pool_params:
         assert f": ({n}, {{}}" in alias, (n, alias)
+
+
+def test_paged_serve_step_builds_its_walk_once(serve_step_text):
+    # flash_decode's tile table depends on the slot lengths alone, so the
+    # step builds it once, before the layer loop: no instruction of the
+    # loop's body makes an s32[max_tiles, 5] table, which only enters it
+    # (XLA would not hoist the build, since the table outgrows the
+    # lengths it is made from).
+    plan = plan_flash_decode(FlashDecodeDescriptor(
+        num_seqs=_STEP["slots"], pages=_STEP["pages"], page_size=16,
+        max_blocks=_STEP["max_blocks"], num_heads=16, num_kv_heads=8,
+        head_dim=128, dtype="bfloat16"))
+    table = f"s32[{plan.tile_schedule().max_tiles},5]"
+    body = re.search(r"while\(.*?body=%?([\w.\-]+)", serve_step_text).group(1)
+    computations = re.split(r"\n(?=\S)", serve_step_text)
+    loop = [c for c in computations if c.startswith(f"%{body} ")]
+    assert len(loop) == 1
+
+    def made(text):
+        return [m.group(3) for m in map(_INSTR.match, text.splitlines())
+                if m is not None and m.group(2).startswith(table + "{")
+                and m.group(3) not in ("parameter", "get-tuple-element")]
+
+    assert not made(loop[0]), made(loop[0])
+    assert made(serve_step_text)
+    assert "flash_decode" in loop[0]
 
 
 def test_fused_grouped_gemm(one_chip):

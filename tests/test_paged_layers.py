@@ -8,16 +8,25 @@ oracle: a scan whose steps each take one layer's pool out of the stack
 and put it back.  Each layer's pool rows and the emitted tokens must
 agree over several steps, with an inactive slot and with slots on
 scattered pages, for wide and int8 pools, on the XLA path and the
-interpreted kernel.
+interpreted kernel.  The kernel's block walk (several pages a grid
+step) is held to the gather oracle, and its planner to the page bytes
+and per-step overhead it sizes the step by.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced_config
-from repro.core import use
-from repro.kernels.flash_attention import paged_decode_attention
+from repro.core import engine, use
+from repro.core.blocking import (FlashDecodePlan, decode_pages_per_step,
+                                 plan_flash_decode)
+from repro.core.descriptor import FlashDecodeDescriptor
+from repro.core.machine import TPU_V5E
+from repro.kernels.flash_attention import decode_walk, paged_decode_attention
+from repro.kernels.flash_attention.ref import ref_paged_decode_attention
 from repro.models import LanguageModel, blocks, lm
 from repro.models.attention import PagedKVCache, PageSpec
 from repro.runtime.pages import init_serving_cache
@@ -200,3 +209,90 @@ def test_flash_decode_reads_layer_of_stack(kv):
             np.testing.assert_array_equal(np.asarray(got, np.float32),
                                           np.asarray(want, np.float32),
                                           err_msg=f"layer {i}")
+
+
+def test_flash_decode_takes_a_walk_built_beforehand():
+    """A walk built once by ``decode_walk`` (as the decode step does
+    before its layer scan) gives the call's own result; a walk of
+    another geometry is refused, not walked."""
+    rng = np.random.default_rng(5)
+    S, pages, psize, maxb, h, hkv, hd = 3, 12, 4, 4, 4, 2, 16
+    q = jnp.asarray(rng.standard_normal((S, h, hd)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((pages, psize, hkv, hd)),
+                        jnp.float32) for _ in range(2))
+    tables = jnp.asarray(TABLES)
+    lengths = jnp.asarray([5, 16, 0], jnp.int32)
+    with use(backend="pallas"):
+        walk = decode_walk(jax.ShapeDtypeStruct(q.shape, q.dtype), k,
+                           tables, lengths)
+        got = paged_decode_attention(q, k, v, tables, lengths, walk=walk)
+        want = paged_decode_attention(q, k, v, tables, lengths)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        with pytest.raises(ValueError, match="another plan"):
+            paged_decode_attention(q, k, v, tables, lengths,
+                                   walk=jnp.concatenate([walk, walk]))
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+@pytest.mark.parametrize("pages_per_step", [2, 3, 4])
+def test_flash_decode_block_walk_matches_reference(kv, pages_per_step):
+    """The walk at several pages a grid step equals the gather oracle on
+    every layer of a stacked pool: a slot with a whole block and a
+    partial one, an empty slot, a partial only block, and a last page
+    holding one token."""
+    rng = np.random.default_rng(17)
+    L, S, pages, psize, maxb, h, hkv, hd = 2, 4, 24, 4, 6, 4, 2, 16
+    q = jnp.asarray(rng.standard_normal((S, h, hd)), jnp.float32)
+    tables = jnp.asarray(rng.permutation(pages)[:S * maxb].reshape(S, maxb),
+                         jnp.int32)
+    lengths = jnp.asarray([24, 0, 9, 13], jnp.int32)
+    shape = (L, pages, psize, hkv, hd)
+    if kv == "int8":
+        k, v = (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+                for _ in range(2))
+        ks, vs = (jnp.asarray(rng.uniform(0.001, 0.01, shape[:3]),
+                              jnp.float32) for _ in range(2))
+        kw, vw = (x.astype(jnp.float32) * s[..., None, None]
+                  for x, s in ((k, ks), (v, vs)))
+    else:
+        k, v = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                for _ in range(2))
+        ks = vs = None
+        kw, vw = k, v
+    desc = FlashDecodeDescriptor.from_operands(q, k, tables)
+    plan = FlashDecodePlan(desc, pages_per_step)
+    for i in range(L):
+        got = engine.dispatch(desc, q, k, v, tables, lengths, k_scale=ks,
+                              v_scale=vs, layer=jnp.int32(i), plan=plan)
+        want = ref_paged_decode_attention(q, kw[i], vw[i], tables, lengths)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-4, atol=1e-5,
+                                   err_msg=f"layer {i}")
+    assert not np.asarray(got)[1].any()  # the empty slot drains zeros
+
+
+def test_decode_pages_per_step_follows_page_bytes_and_overhead():
+    """The planner sizes a grid step by the KV bytes it moves against the
+    machine's per-step overhead, within the block-table width and VMEM."""
+    desc = FlashDecodeDescriptor(num_seqs=24, pages=1792, page_size=16,
+                                 max_blocks=256, num_heads=16,
+                                 num_kv_heads=8, head_dim=128,
+                                 dtype="bfloat16")
+    v5e = TPU_V5E
+    base = decode_pages_per_step(desc, v5e)
+    assert base == plan_flash_decode(desc, v5e).pages_per_step == 16
+    # 64 KiB pages: 16 of them take 1.28 us at 819 GB/s, 4 x 0.2 us needs
+    # 10 of them, the next power of two up
+    slow = dataclasses.replace(v5e, step_overhead_s=4 * v5e.step_overhead_s)
+    assert decode_pages_per_step(desc, slow) == 4 * base
+    # a quarter of the page bytes (2 KV heads): four times the pages
+    narrow = dataclasses.replace(desc, num_kv_heads=2)
+    assert decode_pages_per_step(narrow, v5e) == 4 * base
+    # pages that alone outweigh the overhead walk one at a time
+    wide = dataclasses.replace(desc, page_size=512)
+    assert decode_pages_per_step(wide, v5e) == 1
+    # never more than the block table holds, nor than VMEM holds
+    assert decode_pages_per_step(
+        dataclasses.replace(desc, max_blocks=3), v5e) == 3
+    small = dataclasses.replace(v5e, vmem_bytes=8 << 20)
+    assert decode_pages_per_step(desc, small) < base

@@ -222,33 +222,42 @@ from repro.models.attention import PageSpec
 from repro.runtime.pages import OutOfPages, PagePool, pages_for
 
 
-def _pool_for(lengths, page_size, extra_pages=0):
+def _pool_for(lengths, page_size, extra_pages=0, max_blocks=1):
     """A pool sized to hold ``lengths``, with every slot grown to its
     length — the allocator state one scheduler tick would produce."""
     need = [pages_for(L, page_size) for L in lengths]
     spec = PageSpec(num_pages=max(1, sum(need) + extra_pages),
                     page_size=page_size,
-                    max_blocks=max(1, max(need, default=1)))
+                    max_blocks=max(max_blocks, max(need, default=1)))
     pool = PagePool(spec, len(lengths))
     for i, L in enumerate(lengths):
         pool.grow(i, L)
     return pool, spec
 
 
-def _check_decode_tables(lengths, page_size, extra_pages):
-    """Rows visit each live page exactly once in block-table order, tail
-    k_lens are exact, carries bracket, inactive tail rows are inert —
-    and the allocator's invariants hold after building the state."""
-    pool, spec = _pool_for(lengths, page_size, extra_pages)
+def _check_decode_tables(lengths, page_size, extra_pages, pages_per_step):
+    """Rows visit each live page exactly once in block-table order,
+    ``pages_per_step`` at a time, live lengths are exact, carries
+    bracket, rows past the live total are inert — and the allocator's
+    invariants hold after building the state."""
+    pool, spec = _pool_for(lengths, page_size, extra_pages,
+                           max_blocks=pages_per_step)
     pool.check_invariants(list(lengths))
     sched = DecodeTileSchedule(num_seqs=len(lengths), pages=spec.num_pages,
                                page_size=page_size,
-                               max_blocks=spec.max_blocks)
+                               max_blocks=spec.max_blocks,
+                               pages_per_step=pages_per_step)
     import jax.numpy as jnp
-    table = np.asarray(sched.tables(jnp.asarray(pool.tables),
-                                    jnp.asarray(lengths, jnp.int32)))
+    table = np.asarray(sched.tables(jnp.asarray(lengths, jnp.int32)))
     assert table.dtype == np.int32
     sched.validate_tables(table, pool.tables, np.asarray(lengths))
+    # the static bound: at most ceil(max_blocks / pps) rows a slot, and
+    # pool-wide one partial block (or empty row) a slot beyond the
+    # pool's whole blocks
+    assert sched.max_tiles == min(
+        len(lengths) * ceil_div(spec.max_blocks, pages_per_step),
+        spec.num_pages // pages_per_step + len(lengths))
+    assert (table[:, 2] > 0).sum() == sched.live_rows(lengths)
 
 
 _DECODE_CASES = [
@@ -260,36 +269,83 @@ _DECODE_CASES = [
     ([100], 8, 5),           # long single sequence
 ]
 
+# Lengths on page and block boundaries (8-token pages, blocks of 2 and 8
+# pages), empty slots, and pools with every page live.
+_DECODE_BOUNDARY_CASES = _DECODE_CASES + [
+    ([8, 16, 0, 24], 8, 0),      # whole pages, every page live
+    ([64, 0, 128, 65], 8, 0),    # whole 8-page blocks, one page over
+    ([15, 17, 63, 1, 0], 8, 4),  # a token either side of a boundary
+]
+_PAGES_PER_STEP = [1, 2, 8]
+
+
+@pytest.mark.parametrize("pages_per_step", _PAGES_PER_STEP)
+@pytest.mark.parametrize("lengths,page_size,extra_pages",
+                         _DECODE_BOUNDARY_CASES)
+def test_decode_tables_boundaries(lengths, page_size, extra_pages,
+                                  pages_per_step):
+    _check_decode_tables(lengths, page_size, extra_pages, pages_per_step)
+
 
 if HAVE_HYPOTHESIS:
+    @pytest.mark.parametrize("pages_per_step", _PAGES_PER_STEP)
     @settings(max_examples=40, deadline=None)
     @given(lengths=st.lists(st.integers(0, 70), min_size=1, max_size=6),
            page_size=st.sampled_from([1, 4, 8, 16]),
            extra_pages=st.integers(0, 8))
-    def test_decode_tables_coverage(lengths, page_size, extra_pages):
-        _check_decode_tables(lengths, page_size, extra_pages)
+    def test_decode_tables_coverage(lengths, page_size, extra_pages,
+                                    pages_per_step):
+        _check_decode_tables(lengths, page_size, extra_pages,
+                             pages_per_step)
 else:
+    @pytest.mark.parametrize("pages_per_step", _PAGES_PER_STEP)
     @pytest.mark.parametrize("lengths,page_size,extra_pages", _DECODE_CASES)
-    def test_decode_tables_coverage(lengths, page_size, extra_pages):
-        _check_decode_tables(lengths, page_size, extra_pages)
+    def test_decode_tables_coverage(lengths, page_size, extra_pages,
+                                    pages_per_step):
+        _check_decode_tables(lengths, page_size, extra_pages,
+                             pages_per_step)
 
 
-def test_decode_schedule_static_bounds():
-    """max_tiles caps the walk pool-wide: live pages are exclusively
-    owned, so compute tiles can never exceed min(S*B, pages), and every
-    slot adds at most one dummy row."""
-    sched = DecodeTileSchedule(num_seqs=3, pages=5, page_size=4,
-                               max_blocks=4)
-    assert sched.max_tiles == 5 + 3
-    assert sched.max_len == 16
+@pytest.mark.parametrize("pages_per_step,pages,max_blocks,tiles,active", [
+    (1, 5, 4, 5 + 3, 2 + 3 + 1),  # a row a live page, a dummy row a slot
+    (2, 5, 4, 5 // 2 + 3, 1 + 2 + 1),
+    (8, 20, 8, 3 * 1, 1 + 1 + 1),  # one block a slot: no inert row
+])
+def test_decode_schedule_static_bounds(pages_per_step, pages, max_blocks,
+                                       tiles, active):
+    """max_tiles caps the walk: at most ceil(max_blocks / pps) blocks a
+    slot, and pool-wide, since live pages are exclusively owned, the
+    pool's whole blocks plus one partial (or dummy) row a slot."""
+    sched = DecodeTileSchedule(num_seqs=3, pages=pages, page_size=4,
+                               max_blocks=max_blocks,
+                               pages_per_step=pages_per_step)
+    assert sched.max_tiles == tiles
+    assert sched.max_len == 4 * max_blocks
     import jax.numpy as jnp
-    bt = jnp.asarray([[0, 1, 0, 0], [2, 3, 4, 0], [0, 0, 0, 0]], jnp.int32)
+    bt = np.zeros((3, max_blocks), np.int32)
+    bt[0, :2], bt[1, :3] = [0, 1], [2, 3, 4]
     lengths = np.asarray([8, 12, 0])
-    table = np.asarray(sched.tables(bt, jnp.asarray(lengths)))
-    sched.validate_tables(table, np.asarray(bt), lengths)
-    # 2 + 3 live pages + 1 dummy for the idle slot = 6 active rows
-    active = (table[:, 3] | table[:, 4] | (table[:, 2] > 0)).sum()
-    assert active == 6 <= sched.max_tiles
+    table = np.asarray(sched.tables(jnp.asarray(lengths)))
+    sched.validate_tables(table, bt, lengths)
+    rows = (table[:, 3] | table[:, 4] | (table[:, 2] > 0)).sum()
+    assert rows == active <= sched.max_tiles
+    # rows past the live total carry nothing and stay on the last live
+    # row's query row
+    inert = table[active:]
+    assert (inert[:, 2:] == 0).all() and (inert[:, 0] == 2).all()
+
+
+def test_decode_tables_lower_without_a_loop():
+    """The table is built in every decode step, so it must lower to
+    straight-line ops: a binary search would be a while loop of tiny
+    device ops (qwen3-0.6b.chat's geometry)."""
+    import jax
+    import jax.numpy as jnp
+    sched = DecodeTileSchedule(num_seqs=24, pages=1792, page_size=16,
+                               max_blocks=112, pages_per_step=16)
+    text = jax.jit(sched.tables).lower(
+        jax.ShapeDtypeStruct((24,), jnp.int32)).as_text()
+    assert "while" not in text
 
 
 def _check_pool_ops(ops, page_size, num_pages, num_slots):

@@ -2,11 +2,13 @@
 
 Every key of ``phase_seconds`` exists before the first step, the parts
 of ``decode`` fit inside it, ``queue_wait`` counts the wait of a request
-held behind another and of a re-admission from its eviction, and the
+held behind another and of a re-admission from its eviction, the
+``decode_tiles`` counts follow the batch's lengths, and the
 ``serve.*`` profiler spans nest on the host's trace under a caller's
 span.  Timings are compared only with the spans that hold them, never
 with fixed ratios.
 """
+import dataclasses
 import glob
 import os
 import time
@@ -16,6 +18,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, reduced_config
+from repro.core import use
+from repro.core.machine import TPU_V5E
 from repro.models import LanguageModel
 from repro.models.attention import PageSpec
 from repro.runtime.batching import (ContinuousBatchingEngine, Request,
@@ -24,7 +28,7 @@ from repro.runtime.batching import (ContinuousBatchingEngine, Request,
 KEYS = {"admission", "prefill", "grow", "eviction", "tables", "decode",
         "decode.dispatch", "decode.emit", "queue_wait",
         "expert_rows.decode", "experts_hit.decode", "expert_rows.prefill",
-        "experts_hit.prefill"}
+        "experts_hit.prefill", "decode_tiles.live", "decode_tiles.walked"}
 TOP = ("admission", "prefill", "grow", "eviction", "tables", "decode")
 
 
@@ -89,6 +93,33 @@ def test_phases_fit_inside_their_parents(model):
         assert ph[k] > 0, k
     # The top-level phases never overlap, so they fit inside step().
     assert sum(ph[k] for k in TOP) <= inside
+
+
+@pytest.mark.parametrize("step_overhead_s,block,tiles", [
+    (None, 48, 2),   # the v5e model: a step stages all 6 pages a slot has
+    (0.0, 8, 12),    # no per-step overhead to cover: a page a step
+])
+def test_decode_tile_counts_follow_the_batch(model, step_overhead_s, block,
+                                             tiles):
+    """Each decode step adds the walk's grid rows to ``walked`` and, to
+    ``live``, one row for every ``block`` tokens or part of them that an
+    active slot attends to (its length after the step's write)."""
+    machine = (None if step_overhead_s is None else dataclasses.replace(
+        TPU_V5E, step_overhead_s=step_overhead_s))
+    with use(machine=machine):
+        eng = _engine(model, 2, PageSpec(24, 8, 6))
+    eng.submit(_request(0, n=8, max_new=6))
+    eng.submit(_request(1, n=20, max_new=6))
+    steps, live = 0, 0
+    while eng.queue or any(s is not None for s in eng.slots):
+        if eng.step():
+            steps += 1
+            # every slot still held decoded this step
+            live += sum(-(-int(eng.lengths[i]) // block)
+                        for i, s in enumerate(eng.slots) if s is not None)
+    ph = eng.phase_seconds
+    assert steps > 0 and ph["decode_tiles.walked"] == steps * tiles
+    assert ph["decode_tiles.live"] == live <= ph["decode_tiles.walked"]
 
 
 def test_queue_wait_of_a_request_held_behind_another(model):

@@ -484,5 +484,6 @@ def test_continuous_serving_warm_step(tmp_path):
                        "decode", "decode.dispatch", "decode.emit",
                        "queue_wait", "expert_rows.decode",
                        "experts_hit.decode", "expert_rows.prefill",
-                       "experts_hit.prefill"}
+                       "experts_hit.prefill", "decode_tiles.live",
+                       "decode_tiles.walked"}
     assert all(v >= 0 for v in ph.values())
